@@ -19,6 +19,24 @@ from .context import Context, free_port
 from .job import Container, Pod
 
 
+class UnsupportedLaunchError(ValueError):
+    """The requested process layout cannot work on this platform."""
+
+
+def children_platform(env=None) -> str:
+    """The platform the launched processes' JAX will select, worked out
+    WITHOUT initialising a backend here: a launcher that touched
+    ``jax.devices()`` would hold the chip and its children could not get
+    it.  ``JAX_PLATFORMS`` decides (first entry); unset, jax probes for
+    a TPU itself whenever libtpu is installed."""
+    import importlib.util
+    env = os.environ if env is None else env
+    plats = env.get("JAX_PLATFORMS", "").strip().lower()
+    if plats:
+        return plats.split(",")[0]
+    return "tpu" if importlib.util.find_spec("libtpu") else "cpu"
+
+
 class Master:
     """Multi-node rendezvous over TCPStore (ref ``controllers/master.py``:
     ``HTTPMaster:66``/``ETCDMaster:175`` sync_peers)."""
@@ -128,6 +146,18 @@ class CollectiveController(Controller):
         ctx = self.ctx
         a = ctx.args
         nprocs = ctx.nprocs()
+        if nprocs > 1 and children_platform() == "tpu":
+            # a chip belongs to one process; the children get no
+            # per-process chip assignment, so the second one could never
+            # reach the device (parallel/env.py: one process drives all
+            # local chips, single-controller SPMD)
+            raise UnsupportedLaunchError(
+                f"--nproc_per_node={nprocs} on a TPU host: a chip belongs "
+                "to one process at a time and the launcher assigns none "
+                "per child.  Supported form: one process per host "
+                "(omit --nproc_per_node) driving all local chips through "
+                "a mesh; set JAX_PLATFORMS=cpu to run several CPU "
+                "processes.")
 
         if a.nnodes > 1:
             if not a.master:

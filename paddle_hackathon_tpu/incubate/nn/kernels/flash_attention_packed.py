@@ -49,7 +49,10 @@ from .flash_attention import (_NEG_INF, _SUB, _dropout_keep, _interpret,
 _LANES = 128
 # The estimator under-counts the compiler's score/prob temporaries; 13 MB
 # keeps the worst (dkdv) kernel clear of the 16 MB scoped-vmem limit
-# (G=12 at 512^2 blocks estimated 14.6 MB but compiled to 16.56 MB).
+# (G=12 at 512^2 blocks estimated 14.6 MB but compiled to 16.56 MB on
+# the round-2 toolchain).  The plans this picks — (512, 512, G=6) for
+# gpt2-small, G=4 for H16/D128 — compile under jax 0.9.0 / libtpu 0.0.34
+# on the v5e (chip_smoke.py, PR 21).
 _VMEM_BUDGET = 13 * 2**20
 
 
@@ -270,6 +273,7 @@ def _fwd(qkv, heads, causal, sm_scale, dropout_p=0.0, seed=None,
             pltpu.VMEM((G, _SUB, bq), jnp.float32),    # l (transposed)
         ],
         interpret=_interpret(),
+        name="flash_packed_fwd",
     )(qkv, qkv, qkv, seed)
     return out, lse
 
@@ -502,6 +506,7 @@ def _bwd(heads, causal, sm_scale, dropout_p, res, do):
             pltpu.VMEM((G, bkv, D), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_packed_bwd_dkdv",
     )(qkv, qkv, qkv, do, lse, delta_t, seed)
 
     dqk = functools.partial(
@@ -528,6 +533,7 @@ def _bwd(heads, causal, sm_scale, dropout_p, res, do):
         out_shape=jax.ShapeDtypeStruct((b, sq, hd), qkv.dtype),
         scratch_shapes=[pltpu.VMEM((G, bq, D), jnp.float32)],
         interpret=_interpret(),
+        name="flash_packed_bwd_dq",
     )(qkv, qkv, qkv, do, lse, delta_t, seed)
 
     dqkv = jnp.concatenate([dq, dk, dv], axis=-1)   # (b, s, 3*H*D)

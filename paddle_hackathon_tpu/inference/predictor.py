@@ -144,11 +144,11 @@ class Predictor:
             config.pass_builder().apply(ctx)
         self._ctx = ctx
 
-        backend = "tpu" if config.use_gpu() else "cpu"
-        try:
-            devs = jax.devices(backend)
-        except RuntimeError:
-            devs = jax.devices()
+        # "gpu" = the accelerator = the platform this process's JAX was
+        # pointed at.  A process pointed at the chip that cannot reach it
+        # fails in JAX's backend init — no quiet "any device" fallback;
+        # JAX_PLATFORMS=cpu / disable_gpu() are the explicit CPU choices.
+        devs = jax.devices() if config.use_gpu() else jax.devices("cpu")
         self._device = devs[min(config.gpu_device_id(), len(devs) - 1)]
 
         if _shared is not None:  # Clone(): share weights + executable

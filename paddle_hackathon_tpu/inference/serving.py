@@ -145,8 +145,8 @@ def save_for_serving(model, path, quant=None):
     bench casts GPT-2 to bf16) and other ml_dtypes store as uint views
     with the logical dtype recorded per param in ``config.json``.
 
-    ``quant="int8"`` (or ``"fp8"``, falling back to int8 where the dtype
-    is missing) post-training-quantizes the attention/MLP projection
+    ``quant="int8"`` (or ``"fp8"`` = fp8-e4m3) post-training-quantizes
+    the attention/MLP projection
     weights at save time: the artifact stores int8 values plus f32
     per-output-channel ``<name>_scale`` entries (~halving weight bytes),
     and ``config.json`` records ``{"quant": {"scheme", "params"}}`` so
@@ -1582,7 +1582,7 @@ class ServingEngine:
         kc, vc = self._caches
         # partial-manual shard_map (pp manual, dp/mp auto) needs the
         # ambient mesh — same contract as _run_decode_program
-        from ..core.jaxcompat import set_mesh as _set_mesh
+        from jax import set_mesh as _set_mesh
         with _set_mesh(self._mesh):
             kc, vc, self._xbuf, nxt = self._prog("_pp_tick", vec)(
                 self._pp_stacked, kc, vc, self._xbuf, jnp.asarray(tokens),

@@ -33,10 +33,8 @@ _trace_state = _TraceState()
 
 def _trace_state_clean() -> bool:
     """True when no jax trace is active (safe to enter our own jit)."""
-    try:
-        return jax.core.trace_state_clean()
-    except AttributeError:  # older/newer jax: conservative probe
-        return not isinstance(jnp.zeros(()) + 0, jax.core.Tracer)
+    from jax._src import core as _core
+    return _core.trace_state_clean()
 
 
 class InputSpec:

@@ -1,7 +1,7 @@
 """BERT / ERNIE encoder family.
 
 Capability target: the ERNIE/BERT-base pretraining driver config
-(BASELINE.md, sharding_stage2) — the paddle analog is PaddleNLP
+(BASELINE.json, sharding_stage2) — the paddle analog is PaddleNLP
 BERT/ERNIE over the reference's ``nn.TransformerEncoder``
 (``python/paddle/nn/layer/transformer.py``) and fused attention
 (``operators/fused/fused_attention_op.cu``). ERNIE shares the BERT
@@ -132,14 +132,16 @@ class BertSelfAttention(SequenceParallelMixin, Layer):
     def _packed_flash_ok(self, qkv, s):
         from ..core import flags
         from ..core.tensor import Tensor
-        from ..incubate.nn.kernels import flash_attention_packed as _fap
+        from ..incubate.nn.functional import packed_flash_plan
         if self.use_flash is False or not flags.flag("use_fused_kernels"):
             return False
         if self.use_flash is None and \
                 s < flags.flag("flash_attention_min_seqlen"):
             return False
         dtype = qkv._value.dtype if isinstance(qkv, Tensor) else qkv.dtype
-        return _fap.supported(s, s, self.num_heads, self.head_dim, dtype)
+        # geometry AND mesh coverage (see GPTAttention._packed_flash_ok)
+        return packed_flash_plan(qkv.shape[0], s, self.num_heads,
+                                 self.head_dim, dtype) is not None
 
     def forward(self, x, attn_mask=None):
         b, s, h = x.shape

@@ -1,5 +1,5 @@
 """PP-YOLOE-style anchor-free detector — the conv-heavy static-graph
-driver config (BASELINE.md #5: "PP-YOLOE / PP-OCRv3-class detection model
+driver config (BASELINE.json #5: "PP-YOLOE / PP-OCRv3-class detection model
 via jit/static path").
 
 Capability reference: PaddleDetection's PP-YOLOE (CSPResNet backbone,

@@ -69,7 +69,7 @@ class SequenceParallelMixin:
         if _trace_state_clean():
             # eager call: the partial-manual shard_map inside needs the
             # ambient mesh at trace time (jit inside apply_op)
-            from ...core.jaxcompat import set_mesh as _set_mesh
+            from jax import set_mesh as _set_mesh
             with _set_mesh(mesh):
                 return apply_op(f"{mode}_attention_sp", f, [q, k, v])
         return apply_op(f"{mode}_attention_sp", f, [q, k, v])

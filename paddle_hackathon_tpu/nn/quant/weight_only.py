@@ -26,13 +26,10 @@ Three entry points:
 
 Scale/zero-point convention: symmetric absmax per OUTPUT channel (the
 axis the per-channel scale can commute out of the GEMM), no zero point.
-``scheme="fp8"`` resolves to fp8-e4m3 where the dtype exists and falls
-back to int8 otherwise, behind the same interface.
+``scheme="fp8"`` is shorthand for fp8-e4m3, behind the same interface.
 """
 
 from __future__ import annotations
-
-import warnings
 
 import jax.numpy as jnp
 
@@ -52,8 +49,7 @@ SCHEMES = ("int8", "fp8-e4m3")
 
 
 def resolve_scheme(scheme):
-    """Normalize a user-facing scheme name; fp8 falls back to int8 when
-    the dtype does not exist on this jax (same interface either way)."""
+    """Normalize a user-facing scheme name ("fp8" = "fp8-e4m3")."""
     if scheme is None:
         return None
     if scheme == "fp8":
@@ -62,11 +58,6 @@ def resolve_scheme(scheme):
         raise ValueError(
             f"unknown weight-only scheme {scheme!r}; expected one of "
             f"{SCHEMES} (or 'fp8')")
-    if scheme == "fp8-e4m3" and getattr(jnp, "float8_e4m3fn", None) is None:
-        warnings.warn("fp8-e4m3 is unavailable on this jax build; "
-                      "falling back to int8 weight-only quantization",
-                      stacklevel=2)
-        return "int8"
     return scheme
 
 

@@ -50,6 +50,7 @@ import collections
 import contextlib
 import inspect
 import os
+import re
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -58,6 +59,7 @@ from .sanitizers import make_lock
 
 __all__ = ["ProgramRegistry", "get_program_registry", "capture_signature",
            "diff_signatures", "signature_from_spec_key", "program_analysis",
+           "mosaic_kernels",
            "analysis_enabled", "observe_static_build",
            "observe_static_eviction", "COMPILES_LANE_TID",
            "HISTORY_PER_SITE"]
@@ -272,12 +274,45 @@ _MEM_KINDS = (("args", "argument_size_in_bytes"),
               ("generated", "generated_code_size_in_bytes"))
 
 
+_MOSAIC_CALL_RE = re.compile(r'custom_call_target="tpu_custom_call"')
+# XLA:TPU prints a Mosaic call as
+#   %quant_matmul.1 = ... custom-call(...), custom_call_target=
+#   "tpu_custom_call", ..., metadata={op_name="jit(tick)/while/body/
+#   quant_matmul/pallas_call" ...}
+# — the pallas_call's name= is the path component before "/pallas_call",
+# wrapped once per transform under autodiff:
+#   op_name="jit(train_step)/transpose(jvp(flash_packed_bwd_dq))/pallas_call"
+_KERNEL_SCOPE_RE = re.compile(r'op_name="[^"]*?([^/"]+)/pallas_call')
+_IDENT_RE = re.compile(r'[A-Za-z_]\w*')
+
+
+def mosaic_kernels(hlo_text: str) -> Dict[str, int]:
+    """``{kernel name: count}`` of the Mosaic (Pallas TPU) custom calls
+    in a COMPILED program's HLO text — evidence from the executable
+    itself that a kernel ran compiled: an interpreted or bypassed kernel
+    leaves no ``tpu_custom_call`` behind.  Names are the ``name=`` each
+    ``pl.pallas_call`` passes (``flash_packed_fwd``, ``paged_decode``,
+    ``quant_matmul``, ...); a call whose name the text does not carry
+    counts under ``"?"``."""
+    out: Dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if not _MOSAIC_CALL_RE.search(line):
+            continue
+        m = _KERNEL_SCOPE_RE.search(line)
+        idents = _IDENT_RE.findall(m.group(1)) if m else ()
+        name = idents[-1] if idents else "?"   # innermost = the kernel
+        out[name] = out.get(name, 0) + 1
+    return out
+
+
 def _harvest_analysis(fn, args, kwargs) -> Optional[dict]:
-    """Per-program ``memory_analysis()`` bytes and ``cost_analysis()``
-    flops via the AOT ``lower()`` handle (the ``parallel/planner.py``
-    harvesting shape).  Re-lowers and re-compiles once — the stated
-    cost of ``PHT_PROGRAM_ANALYSIS`` — and degrades to ``None`` on any
-    backend that lacks the analyses."""
+    """Per-program ``memory_analysis()`` bytes, ``cost_analysis()``
+    flops and the Mosaic kernel census via the AOT ``lower()`` handle
+    (the ``parallel/planner.py`` harvesting shape).  Re-lowers and
+    re-compiles once — the stated cost of ``PHT_PROGRAM_ANALYSIS``
+    (with the persistent compile cache on, the re-compile is a cache
+    hit) — and degrades to ``None`` on any backend that lacks the
+    analyses."""
     lower = getattr(fn, "lower", None)
     if lower is None:
         return None
@@ -286,6 +321,10 @@ def _harvest_analysis(fn, args, kwargs) -> Optional[dict]:
     except Exception:  # noqa: BLE001 — analysis is best-effort evidence
         return None
     out: Dict[str, Any] = {}
+    try:
+        out["mosaic_kernels"] = mosaic_kernels(compiled.as_text())
+    except Exception:  # noqa: BLE001
+        pass
     try:
         mem = compiled.memory_analysis()
         for kind, attr in _MEM_KINDS:

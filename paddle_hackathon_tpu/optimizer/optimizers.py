@@ -61,7 +61,8 @@ class Adam(Optimizer):
     ``moment_dtype='bfloat16'`` stores m/v in bf16 (compute stays f32) —
     an optax ``mu_dtype``-style TPU option the reference lacks: halves the
     optimizer state's HBM traffic and capacity on HBM-bound updates
-    (BASELINE.md GPT-3 1.3B row: +26%).  Default f32 matches the
+    (+26% on the GPT-3 1.3B row in round 3, old toolchain; not
+    re-measured on the current one).  Default f32 matches the
     reference's fused adam bit-for-bit behavior class.
     """
 

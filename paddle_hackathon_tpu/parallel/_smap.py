@@ -16,7 +16,7 @@ import contextlib
 import threading
 
 import jax
-from ..core.jaxcompat import set_mesh, shard_map
+from jax import set_mesh, shard_map
 
 # Axes already bound manual by an enclosing shard_map region (Shardy
 # forbids re-binding them in a nested shard_map). Collective programs
@@ -89,9 +89,6 @@ def run_shard_map(fn, mesh, in_specs, out_specs, manual_axes, args,
             except KeyError:
                 pass   # concurrently evicted; we still hold the program
         if jitted is None:
-            # mesh passed EXPLICITLY: the old-jax compat path must not
-            # fall back to the repo-global parallel.api.get_mesh(),
-            # which may be None or a different mesh than the caller's
             sm = shard_map(fn, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs, axis_names=manual,
                            check_vma=False)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.jaxcompat import set_mesh as _set_mesh
+from jax import set_mesh as _set_mesh
 from ..core.tensor import Tensor
 from ..nn.layer import Layer
 from ..observability import metrics as _obs
@@ -656,7 +656,11 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
     else:
         opt_state = {k: _init_slots(k, v) for k, v in params.items()}
         observe_opt_state_bytes("sharded_step", opt_state)
-    step_no = jnp.zeros((), jnp.int32)
+    # placed like the jitted step returns it: an unplaced counter made
+    # call 2 see a different argument sharding and compile the whole
+    # step a second time (the program observatory's first finding)
+    step_no = jax.device_put(jnp.zeros((), jnp.int32),
+                             NamedSharding(mesh, P()))
 
     if pp_degree > 1:
         loss_fn = _make_pipeline_loss(

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from ..core.jaxcompat import shard_map
+from jax import shard_map
 
 from . import api as _mesh_api
 

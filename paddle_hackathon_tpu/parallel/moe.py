@@ -335,9 +335,7 @@ def moe_all_to_all(x, mesh, axis: str = "ep", split_axis: int = 0,
     whole point: the hand-written a2a pair IS a reshard, which is why
     the einsum formulation needs no explicit collective.  Programs that
     schedule collectives manually (full-manual 'ep' regions) use this
-    helper; the ``MoELayer`` forward itself stays on the GSPMD lowering
-    (partial-manual shard_map is unsupported on pre-0.6 jax —
-    ``core/jaxcompat.py``)."""
+    helper; the ``MoELayer`` forward itself stays on the GSPMD lowering."""
     from jax.sharding import PartitionSpec as P
 
     from ._smap import run_shard_map

@@ -34,7 +34,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..core.jaxcompat import set_mesh as _set_mesh
+from jax import set_mesh as _set_mesh
 
 __all__ = ["plan_sharding", "score_plan", "collective_bytes_from_hlo",
            "plan_mesh", "enumerate_meshes", "MeshPlan"]
@@ -477,7 +477,6 @@ def score_plan(model, mesh, rule, sample_args, zero_stage=0, labels=None,
 
 
 ICI_BW_RING = 2 * 4.5e10   # one v5e ICI torus axis, both directions (B/s)
-PEAK_FLOPS_BF16 = 197e12   # v5e MXU peak (public spec)
 
 
 def enumerate_meshes(n_devices, n_layers=None, batch=None, moe=False):
@@ -536,8 +535,7 @@ def enumerate_meshes(n_devices, n_layers=None, batch=None, moe=False):
 
 def plan_mesh(model, n_devices, sample_args, labels=None, loss_fn=None,
               hbm_bytes=15.0e9, rule=None, zero_stages=(0, 3),
-              candidates=None, peak_flops=PEAK_FLOPS_BF16,
-              bw_ring=ICI_BW_RING):
+              candidates=None, peak_flops=None, bw_ring=ICI_BW_RING):
     """Planner v2 (VERDICT r4 missing #7): recommend the MESH, not just
     the TP rule — the role of the reference's full-program planner/mapper
     (``auto_parallel/planner.py``, ``mapper.py``), TPU-first mechanism:
@@ -551,11 +549,25 @@ def plan_mesh(model, n_devices, sample_args, labels=None, loss_fn=None,
     the cost model (the ``score_plan`` methodology, widened from
     rule-choice to mesh-choice).
 
+    ``peak_flops`` defaults to the visible device's bf16 peak
+    (``cost_model.device_peak_flops``, keyed by ``device_kind``); on a
+    device the table does not know — planning for a chip from a CPU host
+    — pass the target's peak: there is no default denominator.
+
     Returns a ``MeshPlan`` with ``.mesh_dims``, ``.zero_stage``,
     ``.rule`` (auto TP rule when the choice includes 'mp'), and
     ``.table`` (every candidate's measurements — feasible or why not).
     """
     import jax as _jax
+
+    if peak_flops is None:
+        from ..cost_model.cost_model import device_peak_flops
+        peak_flops = device_peak_flops()
+        if peak_flops is None:
+            raise ValueError(
+                "plan_mesh: no peak FLOP/s known for device kind "
+                f"{_jax.devices()[0].device_kind!r}; pass peak_flops= for "
+                "the chip being planned for (v5e bf16: 197e12)")
 
     from .api import create_mesh, get_mesh, set_mesh
 

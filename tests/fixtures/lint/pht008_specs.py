@@ -9,7 +9,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from paddle_hackathon_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 from paddle_hackathon_tpu.parallel._smap import run_shard_map
 from paddle_hackathon_tpu.parallel.api import create_mesh
 
@@ -63,7 +63,7 @@ def manual_axis_drift(x):
                          args=(x,), cache_key=("manual",))
 
 
-def jaxcompat_axis_drift(x):
+def shard_map_axis_drift(x):
     def body(xl):
         return xl
     sm = shard_map(body, mesh=mesh2, in_specs=(P("sp"),),  # expect: PHT008

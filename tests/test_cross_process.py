@@ -33,6 +33,17 @@ pytestmark = pytest.mark.skipif(
 
 _REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
+
+@pytest.fixture(autouse=True)
+def _no_leaked_mesh():
+    """The single-process references create pp meshes through
+    ``create_mesh``, which also sets the package-global mesh; this file
+    sorts first in tier-1, so a leaked 'pp' mesh turns every later
+    ``ServingEngine`` construction into a pipeline engine."""
+    yield
+    from paddle_hackathon_tpu import parallel
+    parallel.set_mesh(None)
+
 _WORKER = """
     import os
     flags = " ".join(f for f in os.environ.get("XLA_FLAGS", "").split()

@@ -44,8 +44,29 @@ class _GilBoundDataset(io.Dataset):
         return np.asarray([acc, i], np.float32)
 
 
+class _BackendProbeDataset(io.Dataset):
+    """Each sample reports how many JAX backends its worker process has
+    initialised by the time the sample is built."""
+
+    def __len__(self):
+        return 8
+
+    def __getitem__(self, i):
+        from jax._src import xla_bridge
+        return np.asarray([len(xla_bridge._backends)], np.float32)
+
+
 def _run_epoch(loader):
     return [b for b in loader]
+
+
+def test_proc_workers_never_initialise_a_backend():
+    """A chip belongs to one process — the parent.  Workers import the
+    package (to unpickle the dataset) but must never touch a device."""
+    loader = io.DataLoader(_BackendProbeDataset(), batch_size=4,
+                           num_workers=2, use_process_workers=True)
+    rows = np.concatenate([np.asarray(b.numpy()) for b in loader])
+    assert rows.shape == (8, 1) and (rows == 0).all()
 
 
 def test_proc_workers_order_and_values():

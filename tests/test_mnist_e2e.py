@@ -1,5 +1,5 @@
 """End-to-end MNIST-style LeNet dygraph training — driver config #1
-(BASELINE.md smoke: 'MNIST LeNet dygraph runs end-to-end').
+(BASELINE.json smoke: 'MNIST LeNet dygraph runs end-to-end').
 
 Uses a synthetic 10-class digit-like dataset (zero-egress environment: no
 download), exercising the full eager stack: DataLoader → conv/pool/linear →

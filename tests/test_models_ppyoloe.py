@@ -1,4 +1,4 @@
-"""PP-YOLOE detector tests (BASELINE.md driver config #5: conv-heavy
+"""PP-YOLOE detector tests (BASELINE.json driver config #5: conv-heavy
 static-graph model; ref PaddleDetection PP-YOLOE, built on the reference's
 vision ops — yolo ops / nms in python/paddle/vision/ops.py)."""
 

@@ -1,6 +1,6 @@
-"""bf16 Adam-moment storage (optax mu_dtype-style TPU option; BASELINE.md
-GPT-3 1.3B +26% row).  Default stays f32 = reference-parity; these tests
-pin the option's convergence parity so the perf claim is honest.
+"""bf16 Adam-moment storage (optax mu_dtype-style TPU option; +26% on the
+GPT-3 1.3B row in round 3, old toolchain).  Default stays f32 =
+reference-parity; these tests pin the option's convergence parity.
 """
 
 import jax

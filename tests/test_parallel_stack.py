@@ -15,7 +15,6 @@ import paddle_hackathon_tpu as paddle
 from paddle_hackathon_tpu import parallel
 from paddle_hackathon_tpu.core.tensor import Tensor
 
-from conftest import requires_partial_manual  # noqa: E402 — shared jax>=0.6 gate
 
 from paddle_hackathon_tpu.parallel import collective as C
 
@@ -220,7 +219,6 @@ class TestMPLayers:
 
 
 class TestPipeline:
-    @requires_partial_manual
     def test_pipeline_matches_sequential(self):
         """4-stage pipelined apply == sequentially applying all stages."""
         mesh = parallel.create_mesh({"pp": 4, "dp": 2})
@@ -299,7 +297,6 @@ class TestSequenceParallel:
         mk = lambda: jnp.asarray(rng.randn(b, s, h, d).astype(np.float32))
         return mk(), mk(), mk()
 
-    @requires_partial_manual
     def test_ring_attention_matches_plain(self):
         mesh = parallel.create_mesh({"sp": 4, "dp": 2})
         try:
@@ -602,10 +599,7 @@ class TestRingFlash:
                                  * 0.3)
         return mk(), mk(), mk()
 
-    # non-causal ring flash lowers an axis_index that old jax turns into
-    # an unpartitionable PartitionId even full-manual — same gate class
-    @pytest.mark.parametrize("causal", [
-        True, pytest.param(False, marks=requires_partial_manual)])
+    @pytest.mark.parametrize("causal", [True, False])
     def test_ring_flash_matches_plain(self, causal):
         mesh = parallel.create_mesh({"sp": 4}, devices=jax.devices()[:4])
         try:
@@ -700,7 +694,6 @@ class TestRingAttentionMemoryProof:
 
 
 class TestPipelineDecodeApply:
-    @requires_partial_manual
     def test_matches_sequential_with_state(self):
         """The masked sequential decode schedule == plain layer-by-layer
         application, INCLUDING the per-layer cache state each stage

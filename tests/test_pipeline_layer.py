@@ -23,8 +23,6 @@ from paddle_hackathon_tpu.models import (BertForPretraining, bert_config,
 from paddle_hackathon_tpu.parallel import (LayerDesc, PipelineLayer,
                                            SharedLayerDesc)
 
-from conftest import requires_partial_manual  # noqa: E402 — shared jax>=0.6 gate
-
 
 def _tiny_cfg(**kw):
     base = dict(num_layers=4, hidden_size=64, num_heads=4, vocab_size=128,
@@ -110,7 +108,6 @@ _PP_BASELINE = {}
     # mixin detects the already-manual axis)
     ({"pp": 2, "sp": 2, "mp": 2}, 0),
 ])
-@requires_partial_manual
 def test_bert_pipeline_matches_single_device(mesh_dims, zero):
     """BERT (never hand-wired for pp) pipelines via the generic desc path
     and matches the single-device loss trajectory."""
@@ -155,7 +152,6 @@ def test_shared_desc_builds_one_module():
 
 
 
-@requires_partial_manual
 def test_pipeline_layer_moe_aux_flows():
     """A desc-built pipeline whose blocks carry an l_aux side channel
     (MoE) feeds the pipeline aux accumulator — the aux term must reach

@@ -251,7 +251,21 @@ class _FakeMem:
     generated_code_size_in_bytes = 512
 
 
+# two Mosaic custom calls as XLA:TPU prints them (v5e, jax 0.9.0), next
+# to a custom call that is not Mosaic's
+_FAKE_HLO = """
+  %quant_matmul.1 = bf16[16,2304]{1,0} custom-call(%pad.0, %w_q.1), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={}}, metadata={op_name="jit(tick)/quant_matmul/pallas_call" stack_frame_id=6}, backend_config={"custom_call_config":{"body":"TUzvUg"}}
+  %quant_matmul.2 = bf16[16,768]{1,0} custom-call(%pad.1, %w_q.2), custom_call_target="tpu_custom_call", metadata={op_name="jit(tick)/while/body/quant_matmul/pallas_call"}
+  %paged_decode.3 = bf16[8,1,12,64]{3,2,1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call", metadata={op_name="jit(tick)/paged_decode/pallas_call"}
+  %transpose_jvp_flash_packed_bwd_dq__.1 = bf16[1,1024,128]{2,1,0} custom-call(%q, %do), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/transpose(jvp(flash_packed_bwd_dq))/pallas_call" stack_frame_id=176}
+  %topk = f32[8,4]{1,0} custom-call(%x), custom_call_target="TopK"
+"""
+
+
 class _FakeCompiled:
+    def as_text(self):
+        return _FAKE_HLO
+
     def memory_analysis(self):
         return _FakeMem()
 
@@ -281,7 +295,12 @@ def test_analysis_harvest_gauges_and_rows():
     s = get_program_registry().snapshot()["sites"][site]
     assert s["analysis"] == {"args_bytes": 1024, "outputs_bytes": 256,
                              "temp_bytes": 4096, "generated_bytes": 512,
-                             "flops": 99.0}
+                             "flops": 99.0,
+                             # the kernel census: which Pallas kernels the
+                             # EXECUTABLE holds (chip_smoke.py's evidence)
+                             "mosaic_kernels": {"quant_matmul": 2,
+                                                "paged_decode": 1,
+                                                "flash_packed_bwd_dq": 1}}
     assert reg.total("program_hbm_bytes", site=site, kind="temp") == 4096
     assert reg.total("program_flops", site=site) == 99.0
 

@@ -94,16 +94,14 @@ def test_kernel_matches_ref_f32_reassociation_tolerance():
 
 
 def test_kernel_fp8_bias_and_3d():
-    fp8 = getattr(jnp, "float8_e4m3fn", None)
     rng = np.random.RandomState(2)
     s = jnp.asarray(rng.rand(256) * 0.01 + 1e-4, jnp.float32)
     b = jnp.asarray(rng.randn(256), jnp.float32)
-    if fp8 is not None:
-        x = jnp.asarray(rng.randn(4, 128), jnp.bfloat16)
-        w = jnp.asarray(rng.randn(128, 256), fp8)
-        np.testing.assert_array_equal(
-            np.asarray(_kernel(x, w, s), np.float32),
-            np.asarray(qm.quant_matmul_ref(x, w, s), np.float32))
+    x = jnp.asarray(rng.randn(4, 128), jnp.bfloat16)
+    w = jnp.asarray(rng.randn(128, 256), jnp.float8_e4m3fn)
+    np.testing.assert_array_equal(
+        np.asarray(_kernel(x, w, s), np.float32),
+        np.asarray(qm.quant_matmul_ref(x, w, s), np.float32))
     # 3-D activations (B, S, K) flatten through the same kernel; bias is
     # added identically on both paths
     x3 = jnp.asarray(rng.randn(2, 3, 128), jnp.bfloat16)
@@ -171,10 +169,7 @@ def test_quantize_weights_predicate_and_manifest():
 
 
 def test_fp8_scheme_resolution():
-    if getattr(jnp, "float8_e4m3fn", None) is None:
-        assert wo.resolve_scheme("fp8") == "int8"   # documented fallback
-    else:
-        assert wo.resolve_scheme("fp8") == "fp8-e4m3"
+    assert wo.resolve_scheme("fp8") == "fp8-e4m3"
     with pytest.raises(ValueError):
         wo.resolve_scheme("int4")
 
@@ -323,9 +318,7 @@ def test_quantized_artifact_roundtrip_dtypes(quant_artifact):
 
 
 def test_fp8_artifact_roundtrip(tmp_path):
-    fp8 = getattr(jnp, "float8_e4m3fn", None)
-    if fp8 is None:
-        pytest.skip("fp8-e4m3 dtype not available on this jax")
+    fp8 = jnp.float8_e4m3fn
     import json
 
     from paddle_hackathon_tpu.inference.serving import (load_for_serving,

@@ -753,41 +753,6 @@ def test_dataloader_prefetch_epoch_under_race_sanitizer():
         assert sum(1 for _ in loader) == 6   # second epoch, fresh iter
 
 
-# ----------------------------------------------- jaxcompat bridge canary
-def test_jaxcompat_bridges_survive_reseed():
-    """core/jaxcompat.py has been WIPED by a re-seed before (PR 2 had to
-    rebuild it; MEMORY/ROADMAP both warn).  Import the bridge symbols
-    tier-1 so a wipe fails HERE, loudly, instead of as a downstream XLA
-    abort in the pp/sp stacks."""
-    import contextlib
-    import jax
-
-    from paddle_hackathon_tpu.core import jaxcompat
-
-    assert callable(jaxcompat.shard_map)
-    assert callable(jaxcompat.set_mesh)
-    # jax.export registered on old jax (jit.save depends on it)
-    assert hasattr(jax, "export")
-    if not hasattr(jax, "set_mesh"):
-        # old-jax half of the bridge: set_mesh(None) is a no-op context,
-        # and partial-manual shard_map REFUSES with a Python error
-        # instead of letting XLA's C++ CHECK abort the interpreter
-        ctx = jaxcompat.set_mesh(None)
-        assert isinstance(ctx, contextlib.nullcontext) or hasattr(
-            ctx, "__enter__")
-        import numpy as _np
-        from jax.sharding import PartitionSpec as P
-        devs = jax.devices()
-        if len(devs) >= 4:
-            mesh = jax.sharding.Mesh(
-                _np.asarray(devs[:4]).reshape(2, 2), ("a", "b"))
-            with pytest.raises(NotImplementedError,
-                               match="partial-manual"):
-                jaxcompat.shard_map(lambda x: x, mesh=mesh,
-                                    in_specs=P(), out_specs=P(),
-                                    axis_names={"a"})
-
-
 @pytest.mark.slow
 def test_trainer_and_dense_tick_run_clean_under_donation_sanitizer(
         monkeypatch):

@@ -8,7 +8,7 @@ import sys
 
 import pytest
 
-from paddle_hackathon_tpu.core.jaxcompat import set_mesh as _set_mesh
+from jax import set_mesh as _set_mesh
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 

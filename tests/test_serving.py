@@ -21,7 +21,6 @@ from paddle_hackathon_tpu.inference import ServingEngine
 from paddle_hackathon_tpu.models.gpt import (GPTConfig, GPTForCausalLM,
                                              param_sharding_spec)
 
-from conftest import requires_partial_manual  # noqa: E402 — shared jax>=0.6 gate
 
 
 
@@ -326,7 +325,6 @@ class TestPipelineInterleaved:
         finally:
             parallel.set_mesh(None)
 
-    @requires_partial_manual
     def test_pp2_dp2_composes(self):
         """pp x dp mesh: the tick's manual axis is pp; dp rides GSPMD."""
         m, prompts, refs = self._setup()
@@ -341,7 +339,6 @@ class TestPipelineInterleaved:
         finally:
             parallel.set_mesh(None)
 
-    @requires_partial_manual
     def test_pp2_mp2_composes(self):
         """pp x mp: stage slabs TP-sharded by the rule; GSPMD inserts the
         in-tick mp collectives inside the manual-pp region (the engine

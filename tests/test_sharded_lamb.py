@@ -15,7 +15,6 @@ import paddle_hackathon_tpu as paddle
 from paddle_hackathon_tpu import parallel
 from paddle_hackathon_tpu.models import GPTForCausalLM, gpt_config
 
-from conftest import requires_partial_manual  # noqa: E402 — shared jax>=0.6 gate
 
 
 
@@ -59,7 +58,6 @@ def test_zero3_matches_single_device(optimizer):
                                    err_msg=k)
 
 
-@requires_partial_manual
 def test_pp_stacked_lamb_keeps_per_layer_trust_ratio():
     """pp stacks block params into (L, ...) arrays; the update must vmap
     the trust ratio over L — a stack-wide norm is a different optimizer."""

@@ -54,18 +54,11 @@ def _run(step, state, ids, labels, n, start=0):
     return state, out
 
 
-# the pp<->flat params need partial-manual shard_map (pp manual + dp/mp
-# auto), which this container's jax<0.6 cannot run
-from conftest import requires_partial_manual as _pp  # noqa: E402
-
-
 @pytest.mark.parametrize("mesh_a,zero_a,mesh_b,zero_b", [
     ({"dp": 4, "mp": 2}, 0, {"dp": 4, "mp": 2}, 0),         # same mesh
     ({"dp": 4, "mp": 2}, 1, {"dp": 2, "sharding": 2, "mp": 2}, 3),  # reshard
-    pytest.param({"pp": 2, "dp": 2, "mp": 2}, 0, {"dp": 4, "mp": 2}, 0,
-                 marks=_pp),  # pp -> flat
-    pytest.param({"dp": 4, "mp": 2}, 0, {"pp": 2, "dp": 2, "mp": 2}, 0,
-                 marks=_pp),  # flat -> pp
+    ({"pp": 2, "dp": 2, "mp": 2}, 0, {"dp": 4, "mp": 2}, 0),  # pp -> flat
+    ({"dp": 4, "mp": 2}, 0, {"pp": 2, "dp": 2, "mp": 2}, 0),  # flat -> pp
 ])
 def test_resume_matches_uninterrupted(tmp_path, mesh_a, zero_a, mesh_b,
                                       zero_b, request):

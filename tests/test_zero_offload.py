@@ -37,7 +37,6 @@ import paddle_hackathon_tpu as paddle
 from paddle_hackathon_tpu import hapi, io, nn, parallel
 from paddle_hackathon_tpu import optimizer as optim
 
-from conftest import requires_partial_manual  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -494,7 +493,6 @@ def test_offload_clean_under_donation_sanitizer():
         _run_sharded(2, zero_offload=True, grad_overlap=True)
 
 
-@requires_partial_manual
 @pytest.mark.slow
 def test_zero_pp_superstep_loss_matches_unsharded_pp():
     """The composed ZeRO x pp program trains: pp microbatch grad

@@ -13,11 +13,7 @@ Chains, in order:
    session.  Runs when ``--durations`` is given or the default log
    exists; otherwise SKIPPED with the command to produce one (a lint-only
    change doesn't need a suite run, so a missing log is not a failure).
-3. **jaxcompat canary** — imports the bridge symbols in a subprocess
-   (``core/jaxcompat.py`` has been wiped by a re-seed before; a broken
-   bridge must fail the pre-PR check loudly, not as a downstream XLA
-   abort).
-4. **fault drills** — deterministic ``PHT_FAULTS`` drills against
+3. **fault drills** — deterministic ``PHT_FAULTS`` drills against
    host-only stubs (no tick program compiles).  The fleet
    dispatch-failover drill — an injected ``fleet.dispatch`` fault
    plus a submit-time replica death must re-dispatch cleanly (retry
@@ -43,17 +39,7 @@ import sys
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_DURATIONS = "/tmp/durations.log"
 
-_CANARY = (
-    "from paddle_hackathon_tpu.core import jaxcompat\n"
-    "import jax\n"
-    "assert callable(jaxcompat.shard_map), 'jaxcompat.shard_map gone'\n"
-    "assert callable(jaxcompat.set_mesh), 'jaxcompat.set_mesh gone'\n"
-    "assert hasattr(jax, 'export'), 'jax.export bridge gone'\n"
-    "print('jaxcompat bridge symbols present')\n"
-)
-
-
-# ``PHT_FAULTS`` fault drills run as step 4: (name, env-spec, script).
+# ``PHT_FAULTS`` fault drills run as step 3: (name, env-spec, script).
 # Each script runs in a fresh interpreter with the spec armed through
 # the environment (the same delivery the crash drills use), against
 # host-only stubs — no tick program compiles, so the step stays cheap.
@@ -538,9 +524,6 @@ def main(argv=None) -> int:
     ap.add_argument("--stats", action="store_true",
                     help="pass --stats through to pht-lint (per-rule "
                          "counts + per-pass wall time)")
-    ap.add_argument("--skip-canary", action="store_true",
-                    help="skip the jaxcompat import canary (it imports "
-                         "jax: ~10s)")
     args = ap.parse_args(argv)
 
     results = []
@@ -566,14 +549,6 @@ def main(argv=None) -> int:
         print("== test-budget: SKIPPED — to include it:\n"
               "   python -m pytest tests/ -q -m 'not slow' --durations=0 "
               "-p no:cacheprovider | tee /tmp/durations.log")
-
-    if args.skip_canary:
-        results.append(("jaxcompat-canary", "SKIP (--skip-canary)"))
-    else:
-        _run_step("jaxcompat-canary",
-                  [sys.executable, "-c", _CANARY], results,
-                  display="python -c '<import the jaxcompat bridge "
-                          "symbols>'")
 
     for name, spec, script in _DRILLS:
         _run_step(name, [sys.executable, "-c", script], results,

@@ -109,7 +109,14 @@ def main():
         cfg.num_heads * seqlen * seqlen * (cfg.hidden_size // cfg.num_heads)
     fl_step = 3 * fl_fwd  # fwd + 2x bwd
 
-    peak = 394e12  # v5e bf16
+    from paddle_hackathon_tpu.cost_model.cost_model import device_peak_flops
+    peak = device_peak_flops()   # bf16 peak by device_kind
+    if peak is None:
+        raise RuntimeError(
+            f"no peak FLOP/s known for device kind "
+            f"{jax.devices()[0].device_kind!r}: the MFU columns would be "
+            "ratios against a made-up denominator (add the kind to "
+            "cost_model._PEAK_FLOPS_BY_KIND or set PHT_PEAK_FLOPS)")
     tok_s = tok / t_step
     print(f"fwd      {t_fwd*1e3:8.2f} ms  ({fl_fwd/t_fwd/1e12:6.1f} TF/s, "
           f"{fl_fwd/t_fwd/peak*100:5.1f}% MFU)")
