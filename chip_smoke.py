@@ -13,6 +13,11 @@ the full width of gpt2-small (12 layers, hidden 768, 12 heads, vocab
             batch 32 x seq 1024, bf16 params: several steps on one
             repeated batch, every loss finite and the last below the
             first;
+  trace     three more steps under ``profiler.Profiler``: one
+            ``.xplane.pb`` holds the device's ops, the ``train_step``
+            annotations and the program's ``train.dispatch`` /
+            ``train.rebind`` spans, and no idle gap of the device over
+            1 ms is left without a span of the program's;
   server    ``inference.ServingEngine(cache_mode="paged")`` answering
             requests of different prompt lengths submitted while others
             are in flight — bf16, then the same requests through the
@@ -46,6 +51,7 @@ from __future__ import annotations
 import json
 import math
 import os
+import shutil
 import sys
 import tempfile
 import time
@@ -486,6 +492,92 @@ def phase_trainer(cfg_kw=None, batch=32, seqlen=1024, steps=6,
 
 
 # ---------------------------------------------------------------------------
+# trace: the program's spans and the device's ops on one clock
+# ---------------------------------------------------------------------------
+
+TRACE_SPANS = ("train_step", "train.dispatch", "train.rebind")
+SMOKE_WAIT = "smoke.wait"       # this script's own wait for a step's loss
+UNNAMED_GAP_NS = 1e6
+
+
+def phase_trace(cfg_kw=None, batch=32, seqlen=1024, steps=3, on_chip=False):
+    """A few trainer steps recorded by ``profiler.Profiler`` with the TPU
+    target: ONE ``.xplane.pb`` holds the device's ops, one ``train_step``
+    annotation a step and the program's ``train.dispatch`` /
+    ``train.rebind`` spans, so every idle gap of the device over 1 ms
+    falls under a span of the program's (or under this loop's own wait
+    for the loss, annotated as the benchmark annotates its own).  The
+    recipe an operator follows (docs/OBSERVABILITY.md, "The train step on
+    the device trace")."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import trace_reduce
+    from paddle_hackathon_tpu import parallel, profiler
+    from paddle_hackathon_tpu.models import param_sharding_spec
+
+    model, cfg = _gpt(cfg_kw)
+    cfg.max_position_embeddings = max(cfg.max_position_embeddings, seqlen)
+    mesh = parallel.create_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step, state = parallel.make_sharded_train_step(
+        model, mesh, rule=param_sharding_spec, learning_rate=1e-4,
+        param_dtype=jnp.bfloat16)
+    rng = np.random.RandomState(0)
+    ids = jnp.asarray(rng.randint(0, cfg.vocab_size, (batch, seqlen)),
+                      jnp.int32)
+    # the keys ahead of the recording: fold_in is a device program of this
+    # loop's, and its dispatch between two steps would be a gap of its own
+    keys = [jax.random.fold_in(jax.random.key(0), i)
+            for i in range(steps + 1)]
+    state, loss = step(state, ids, ids, keys[0])      # builds, untraced
+    jax.block_until_ready((loss, keys))
+    prof = profiler.Profiler(targets=[profiler.ProfilerTarget.TPU])
+    walls = []
+    try:
+        with prof:
+            for key in keys[1:]:
+                t0 = time.perf_counter()
+                state, loss = step(state, ids, ids, key)
+                with jax.profiler.TraceAnnotation(SMOKE_WAIT):
+                    loss.block_until_ready()   # a gap a step, to be named
+                walls.append(time.perf_counter() - t0)
+        planes = trace_reduce.load_planes(
+            trace_reduce.find_xplane(prof.device_trace_dir))
+    finally:
+        shutil.rmtree(prof.device_trace_dir, ignore_errors=True)
+        parallel.set_mesh(None)
+    host = [ev for pname, lines in planes.items()
+            if pname.startswith(trace_reduce.HOST_PLANE_PREFIX)
+            for evs in lines.values() for ev in evs
+            if ev[0] in TRACE_SPANS + (SMOKE_WAIT,)]
+    seen = {n: sum(ev[0] == n for ev in host) for n in TRACE_SPANS}
+    # beside the trainer phase's steady_step_seconds: what recording costs
+    facts = {"steps": steps, "host_spans": seen,
+             "recorded_step_seconds": round(sorted(walls)[len(walls) // 2],
+                                            4)}
+    if any(n != steps for n in seen.values()):
+        raise RuntimeError(f"trace: {steps} steps left {seen} in the "
+                           "profiler's file, one of each a step expected")
+    if not on_chip:
+        return facts
+    traced = trace_reduce.reduce(planes, TRACE_SPANS)
+    if traced is None:
+        raise RuntimeError(f"trace: no device op in {sorted(planes)}")
+    gap_list = trace_reduce.gaps(traced["ops"])
+    named = trace_reduce.name_gaps(
+        [g for g in gap_list if g[1] > UNNAMED_GAP_NS], host)
+    facts.update(device_ops=len(traced["ops"]), idle_gaps=len(gap_list),
+                 gaps_over_1ms=[[k, round(v / 1e6, 3)] for k, v in named],
+                 idle_share=round(
+                     1.0 - traced["busy_s"] / traced["window_s"], 5))
+    if any(k == "(no host span)" for k, _ in named):
+        raise RuntimeError(f"trace: an idle gap over 1 ms falls under no "
+                           f"span of the program's or of this loop's: "
+                           f"{facts}")
+    return facts
+
+
+# ---------------------------------------------------------------------------
 # server
 # ---------------------------------------------------------------------------
 
@@ -753,6 +845,8 @@ def main():
         facts["trainer"], state, _ = phase_trainer(on_chip=True)
         del state
         _say(f"trainer ok {facts['trainer']}")
+        facts["trace"] = phase_trace(on_chip=True)
+        _say(f"trace ok {facts['trace']}")
         facts["server_bf16"] = phase_server(on_chip=True)
         _say(f"server bf16 ok {facts['server_bf16']}")
         facts["server_int8"] = phase_server(quant="int8", on_chip=True)

@@ -17,6 +17,8 @@ import dataclasses
 import math
 from typing import Optional
 
+import jax
+
 from .. import ops
 from ..core.tensor import Tensor
 from ..nn import functional as F
@@ -304,12 +306,18 @@ class GPTBlock(Layer):
         self.dropout = Dropout(config.hidden_dropout_prob)
 
     def forward(self, x, cache=None, cache_pos=None, page_table=None):
-        attn_out = self.attn(self.ln_1(x), cache=cache, cache_pos=cache_pos,
-                             page_table=page_table)
-        if cache is not None:
-            attn_out, cache = attn_out
-        x = x + self.dropout(attn_out)
-        x = x + self.dropout(self.mlp(self.ln_2(x)))
+        # the scopes name the block's two halves (each with its LayerNorm
+        # and residual) in the compiled program's op metadata: the phase
+        # census of observability/programs.py reads them; no index, the
+        # blocks aggregate
+        with jax.named_scope("attn"):
+            attn_out = self.attn(self.ln_1(x), cache=cache,
+                                 cache_pos=cache_pos, page_table=page_table)
+            if cache is not None:
+                attn_out, cache = attn_out
+            x = x + self.dropout(attn_out)
+        with jax.named_scope("mlp"):
+            x = x + self.dropout(self.mlp(self.ln_2(x)))
         return x if cache is None else (x, cache)
 
 
@@ -375,8 +383,9 @@ class GPTModel(Layer):
                     0, max_pos - 1)
                 position_ids = ops.reshape(position_ids, [1, s])
             pos_emb = self.wpe(position_ids)
-        x = self.wte(input_ids) + pos_emb
-        x = self.drop(x)
+        with jax.named_scope("embed"):
+            x = self.wte(input_ids) + pos_emb
+            x = self.drop(x)
         new_caches = []
         for i, block in enumerate(self.blocks):
             if caches is None:
@@ -385,7 +394,8 @@ class GPTModel(Layer):
                 x, c = block(x, cache=caches[i], cache_pos=cache_pos,
                              page_table=page_table)
                 new_caches.append(c)
-        x = self.ln_f(x)
+        with jax.named_scope("ln_f"):
+            x = self.ln_f(x)
         return x if caches is None else (x, new_caches)
 
     def gen_empty_caches(self, batch_size, dtype="float32"):
@@ -411,7 +421,9 @@ class GPTForCausalLM(Layer):
                           cache_pos=cache_pos, page_table=page_table)
         if caches is not None:
             hidden, caches = hidden
-        logits = ops.matmul(hidden, self.gpt.wte.weight, transpose_y=True)
+        with jax.named_scope("lm_head"):
+            logits = ops.matmul(hidden, self.gpt.wte.weight,
+                                transpose_y=True)
         return logits if caches is None else (logits, caches)
 
     def generate(self, input_ids, max_new_tokens=32, temperature=1.0,

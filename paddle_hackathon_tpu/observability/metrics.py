@@ -750,7 +750,9 @@ def instrument_jit(fn, site: str, registry: Optional[MetricRegistry] = None,
     counted (the old fallback recorded only the first call).  Each
     detected build also reports into the process-wide
     :class:`programs.ProgramRegistry` — site label, build index,
-    compile wall, signature, retrace cause, and (when
+    compile wall and where it went by jax's own build events
+    (``trace_s``, ``lower_s``, ``backend_compile_s``, ``cache_hit``:
+    ``programs.read_build_clock``), signature, retrace cause, and (when
     ``PHT_PROGRAM_ANALYSIS`` is armed) the AOT memory/cost harvest.
     The raw jitted function stays on ``wrapped._jit_fn`` (AOT
     lowering / HLO inspection)."""
@@ -776,6 +778,7 @@ def instrument_jit(fn, site: str, registry: Optional[MetricRegistry] = None,
         if not reg.enabled:
             return fn(*a, **k)
         n0 = cache_size()
+        _programs.start_build_clock()
         t0 = time.perf_counter()
         out = fn(*a, **k)
         n1 = cache_size()
@@ -789,13 +792,15 @@ def instrument_jit(fn, site: str, registry: Optional[MetricRegistry] = None,
             grew = prog.is_new_signature(site, sig)
         if grew:
             wall = time.perf_counter() - t0
+            clock = _programs.read_build_clock()
             builds.inc()
             seconds.observe(wall)
             prog.record_build(
                 site, args=a, kwargs=k, fn=fn, signature=sig,
                 compile_s=wall, t_end_ns=time.perf_counter_ns(),
                 registry=reg, labels=labels,
-                donated=getattr(fn, "_pht_donate_argnums", None))
+                donated=getattr(fn, "_pht_donate_argnums", None),
+                build_clock=clock)
         return out
 
     wrapped._jit_fn = fn

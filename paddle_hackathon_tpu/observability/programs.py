@@ -9,7 +9,9 @@ calls never touch it — with:
 
 - the site label, 1-based build index and compile wall time
   (also exported as the ``jit_compile_seconds{site}`` histogram, next
-  to the existing ``jit_builds_total``);
+  to the existing ``jit_builds_total``), and where that wall went by
+  jax's own build events: ``trace_s``, ``lower_s``,
+  ``backend_compile_s`` and ``cache_hit`` (:func:`read_build_clock`);
 - an abstract **call signature**: per-arg aval shape/dtype/weak_type,
   sharding spec when known, static-arg fingerprints and the donation
   map.  Signature capture is host-metadata-only (aval walks — never a
@@ -21,15 +23,23 @@ calls never touch it — with:
   and retained in a bounded per-site history;
 - a compile span on the dedicated "compiles" chrome-trace lane
   (:data:`COMPILES_LANE_TID`; ``profiler/cross_stack.merge_traces``
-  carries the lane through per rank);
-- opt-in (``PHT_PROGRAM_ANALYSIS=1``, or :func:`program_analysis`;
-  always on in ``bench.py``) per-program ``memory_analysis()`` bytes
+  carries the lane through per rank), with the build clock's numbers
+  as attributes;
+- opt-in (``PHT_PROGRAM_ANALYSIS=1``, or :func:`program_analysis`,
+  which ``bench.py``, ``chip_smoke.py`` and the benchmark's drivers
+  put around their warm-up) per-program ``memory_analysis()`` bytes
   and ``cost_analysis()`` flops harvested through the AOT ``lower()``
   handle the wrappers preserve — exported as
-  ``program_hbm_bytes{site,kind}`` / ``program_flops{site}`` gauges.
-  The deeper pass re-lowers and re-compiles the program once per
-  build (that is its cost contract — never pay it in a serving hot
-  loop without opting in).
+  ``program_hbm_bytes{site,kind}`` / ``program_flops{site}`` gauges —
+  plus two censuses of the compiled HLO text: the Mosaic kernels
+  (:func:`mosaic_kernels`) and the phase census (:func:`phase_census`:
+  every instruction that can run as a device op -> forward / backward
+  / clip / update and the ``jax.named_scope`` component it sits
+  under; the join key to a device trace's ``XLA Ops`` events).  The
+  deeper pass asks jax to lower and compile the program once more per
+  build; the build record times it (``analysis_s``,
+  ``analysis_split``) — read that before paying it in a serving hot
+  loop.
 
 Surfaces: ``/debug/programs`` (``observability/server.py``), the
 ``programs`` summary in ``/debug/requests`` (registered via
@@ -51,6 +61,7 @@ import contextlib
 import inspect
 import os
 import re
+import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -59,7 +70,9 @@ from .sanitizers import make_lock
 
 __all__ = ["ProgramRegistry", "get_program_registry", "capture_signature",
            "diff_signatures", "signature_from_spec_key", "program_analysis",
-           "mosaic_kernels",
+           "mosaic_kernels", "phase_census", "phase_counts",
+           "PHASE_COMPONENTS", "PHASES", "start_build_clock",
+           "read_build_clock", "BUILD_CLOCK_KEYS",
            "analysis_enabled", "observe_static_build",
            "observe_static_eviction", "COMPILES_LANE_TID",
            "HISTORY_PER_SITE"]
@@ -305,24 +318,180 @@ def mosaic_kernels(hlo_text: str) -> Dict[str, int]:
     return out
 
 
-def _harvest_analysis(fn, args, kwargs) -> Optional[dict]:
-    """Per-program ``memory_analysis()`` bytes, ``cost_analysis()``
-    flops and the Mosaic kernel census via the AOT ``lower()`` handle
-    (the ``parallel/planner.py`` harvesting shape).  Re-lowers and
-    re-compiles once — the stated cost of ``PHT_PROGRAM_ANALYSIS``
+# The train step's scopes (``parallel/api.py``, ``models/gpt.py``): the
+# names a component can take in the phase census.
+PHASE_COMPONENTS = ("embed", "attn", "mlp", "ln_f", "lm_head", "ce",
+                    "clip", "update")
+PHASES = ("fwd", "bwd", "clip", "update", "other")
+
+_COMPUTATION_RE = re.compile(r'^(?:ENTRY )?%?([^\s(]+) \(.*\{$')
+_INSTRUCTION_RE = re.compile(r'^\s+(?:ROOT )?%?(\S+) = (.*)$')
+# the opcode is the first lower-case word followed by "(" after the
+# result's type (a type's T(8,128) / S(1) tiles are upper-case)
+_OPCODE_RE = re.compile(r'(?:^| )([a-z][\w\-]*)\(')
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_CALLED_RE = re.compile(
+    r'\b(?:calls|body|condition|to_apply|true_computation|'
+    r'false_computation)=%?([^\s,)}]+)')
+_BRANCHES_RE = re.compile(r'branch_computations=\{([^}]*)\}')
+# jax wraps a scope's name once per transform: jvp(mlp),
+# transpose(jvp(mlp)); jit(clip) is jnp.clip, not the scope
+_WRAPPER_RE = re.compile(r'^(?:transpose|jvp|vmap|remat|checkpoint)'
+                         r'\((.*)\)$')
+_CONTROL_FLOW = ("while", "conditional", "call")
+
+
+def _scopes(op_name: str) -> List[str]:
+    """The name stack's elements with jax's transform wrappers taken
+    off: ``jit(step)/transpose(jvp(mlp))/dot_general`` ->
+    ``["jit(step)", "mlp", "dot_general"]``."""
+    out = []
+    for part in op_name.split("/"):
+        m = _WRAPPER_RE.match(part)
+        while m:
+            part = m.group(1)
+            m = _WRAPPER_RE.match(part)
+        out.append(part)
+    return out
+
+
+def _phase_of(op_name: str) -> Tuple[str, str]:
+    """``(phase, component)`` of one instruction's ``op_name``."""
+    scopes = _scopes(op_name)
+    component = next((s for s in scopes if s in PHASE_COMPONENTS), "")
+    if "transpose(jvp(" in op_name:
+        return "bwd", component
+    if "jvp(" in op_name:
+        return "fwd", component
+    if "clip" in scopes:
+        return "clip", component
+    if "update" in scopes:
+        return "update", component
+    return "other", component
+
+
+def phase_census(hlo_text: str) -> Dict[str, Tuple[str, str, bool]]:
+    """``{instruction name: (phase, component, mixed)}`` over the
+    instructions of a COMPILED program's HLO text that can run as device
+    ops: the entry computation and, through them, the bodies of its
+    ``while`` / ``conditional`` / ``call`` instructions -- not the
+    insides of fused computations.  The name is the instruction's own
+    (no leading ``%``): what a device trace's ``XLA Ops`` event carries
+    before `` = ``.
+
+    ``phase`` comes from the name stack jax wrote into ``op_name``:
+    ``bwd`` where it holds ``transpose(jvp(``, else ``fwd`` where it
+    holds ``jvp(``, else ``clip`` / ``update`` under those scopes, else
+    ``other`` (no metadata, or a program without autodiff);
+    ``component`` is the first of :data:`PHASE_COMPONENTS` on the stack,
+    else ``""``.  A fusion takes its own ``op_name``; where the
+    instructions fused into it belong to more than one phase it is
+    ``mixed``, and goes to the phase and component of the single
+    ``convolution`` / ``dot`` inside it if there is exactly one (the
+    matmul sets its time: a weight-gradient matmul that carries the
+    clip's sum of squares is backward), else keeps its own."""
+    comps: Dict[str, list] = {}
+    entry, cur = None, None
+    for line in hlo_text.splitlines():
+        if cur is None:
+            m = _COMPUTATION_RE.match(line)
+            if m:
+                cur = comps[m.group(1)] = []
+                if line.startswith("ENTRY "):
+                    entry = m.group(1)
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        m = _INSTRUCTION_RE.match(line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        op = _OPCODE_RE.search(rest)
+        called = _CALLED_RE.findall(rest)
+        for branches in _BRANCHES_RE.findall(rest):
+            called += [b.strip().lstrip("%") for b in branches.split(",")]
+        on = _OP_NAME_RE.search(rest)
+        cur.append((name, op.group(1) if op else "",
+                    on.group(1) if on else "", called))
+
+    def fused(comp, seen):
+        """``(phase, component, is_matmul)`` of every instruction with
+        metadata inside a fused computation, nested fusions included."""
+        out = []
+        for _, opcode, op_name, called in comps.get(comp, ()):
+            # an argument's op_name is its path (params['...']), no stack
+            if "/" in op_name:
+                out.append(_phase_of(op_name)
+                           + (opcode in ("convolution", "dot"),))
+            if opcode == "fusion":
+                for c in called:
+                    if c not in seen:
+                        seen.add(c)
+                        out += fused(c, seen)
+        return out
+
+    census: Dict[str, Tuple[str, str, bool]] = {}
+    todo, seen = [entry] if entry else [], {entry}
+    while todo:
+        for name, opcode, op_name, called in comps.get(todo.pop(), ()):
+            phase, component = _phase_of(op_name)
+            mixed = False
+            if opcode == "fusion":
+                inner = [x for c in called for x in fused(c, {c})]
+                mixed = len({x[0] for x in inner}) > 1
+                matmuls = [x for x in inner if x[2]]
+                if mixed and len(matmuls) == 1:
+                    phase, component = matmuls[0][:2]
+            elif opcode in _CONTROL_FLOW:
+                for c in called:
+                    if c not in seen:
+                        seen.add(c)
+                        todo.append(c)
+            census[name] = (phase, component, mixed)
+    return census
+
+
+def phase_counts(census: Dict[str, Tuple[str, str, bool]]) -> Dict[str, int]:
+    """Instructions per phase, and how many of them are mixed fusions:
+    what the registry's snapshots carry in place of the names."""
+    out: Dict[str, int] = {}
+    for phase, _, mixed in census.values():
+        out[phase] = out.get(phase, 0) + 1
+        if mixed:
+            out["mixed"] = out.get("mixed", 0) + 1
+    return out
+
+
+def _harvest_analysis(fn, args, kwargs) -> Tuple[Optional[dict],
+                                                Optional[dict]]:
+    """``(analysis, census)``: per-program ``memory_analysis()`` bytes,
+    ``cost_analysis()`` flops, the Mosaic kernel census and the phase
+    census (:func:`phase_census`; the analysis carries its per-phase
+    counts, the map itself goes to the registry) via the AOT ``lower()``
+    handle (the ``parallel/planner.py`` harvesting shape).  Re-lowers
+    and re-compiles once — the stated cost of ``PHT_PROGRAM_ANALYSIS``
     (with the persistent compile cache on, the re-compile is a cache
-    hit) — and degrades to ``None`` on any backend that lacks the
-    analyses."""
+    hit; the build record's ``analysis_s`` and ``analysis_split`` say
+    what it took) — and degrades to ``None`` on any backend that lacks
+    the analyses."""
     lower = getattr(fn, "lower", None)
     if lower is None:
-        return None
+        return None, None
     try:
         compiled = lower(*args, **(kwargs or {})).compile()
     except Exception:  # noqa: BLE001 — analysis is best-effort evidence
-        return None
+        return None, None
     out: Dict[str, Any] = {}
+    census = None
     try:
-        out["mosaic_kernels"] = mosaic_kernels(compiled.as_text())
+        text = compiled.as_text()
+        out["mosaic_kernels"] = mosaic_kernels(text)
+        t0 = time.perf_counter()
+        census = phase_census(text) or None
+        if census:
+            out["phases"] = phase_counts(census)
+            out["census_s"] = round(time.perf_counter() - t0, 6)
     except Exception:  # noqa: BLE001
         pass
     try:
@@ -342,7 +511,87 @@ def _harvest_analysis(fn, args, kwargs) -> Optional[dict]:
             out["flops"] = flops
     except Exception:  # noqa: BLE001
         pass
-    return out or None
+    return out or None, census
+
+
+# ---------------------------------------------------------------------------
+# The build clock: where a build's seconds went, by jax's own events
+# ---------------------------------------------------------------------------
+
+# jax.monitoring reports these on every build (dispatch.py's
+# JAXPR_TRACE_EVENT, JAXPR_TO_MLIR_MODULE_EVENT, BACKEND_COMPILE_EVENT),
+# each as a span on time.time().  The backend-compile span holds the
+# persistent cache's retrieval when that hit.
+_BUILD_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_compile_s",
+}
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+BUILD_CLOCK_KEYS = tuple(_BUILD_EVENTS.values())
+
+_build_tls = threading.local()
+_build_listening = False
+_build_listen_lock = make_lock("observability.programs.build_clock")
+
+
+def _on_build_span(event, start, end, **_):
+    kind = _BUILD_EVENTS.get(event)
+    if kind is None:
+        return
+    spans = getattr(_build_tls, "spans", None)
+    if spans is None:
+        spans = _build_tls.spans = []
+    spans.append((kind, start, end))
+
+
+def _on_build_duration(event, duration, **_):
+    if event == _CACHE_RETRIEVAL_EVENT:
+        _build_tls.cache_hit = True
+
+
+def _listen_to_builds() -> None:
+    global _build_listening
+    with _build_listen_lock:
+        if _build_listening:
+            return
+        import jax.monitoring as monitoring
+        monitoring.register_event_time_span_listener(_on_build_span)
+        monitoring.register_event_duration_secs_listener(_on_build_duration)
+        _build_listening = True
+
+
+def start_build_clock() -> None:
+    """Forget this thread's build events.  A wrapper calls it before a
+    call that may build and :func:`read_build_clock` after one that
+    did; a call that builds nothing raises no event, so it allocates
+    nothing here and takes no lock (the listeners are registered once
+    per process, at the first call)."""
+    if not _build_listening:
+        _listen_to_builds()
+    _build_tls.spans = None
+    _build_tls.cache_hit = False
+
+
+def read_build_clock() -> dict:
+    """``{"trace_s", "lower_s", "backend_compile_s", "cache_hit"}`` of
+    this thread's events since :func:`start_build_clock`.  The three
+    times are disjoint stretches of the wall clock: jax reports a trace
+    event for every jitted function it inlines and compiles the small
+    programs a trace runs eagerly, all inside the outer trace's span, so
+    a span that starts inside a counted one is that one's time already
+    and is left out."""
+    out = dict.fromkeys(BUILD_CLOCK_KEYS, 0.0)
+    covered = float("-inf")
+    spans = getattr(_build_tls, "spans", None) or ()
+    for kind, start, end in sorted(spans, key=lambda x: (x[1], -x[2])):
+        if start < covered:
+            continue
+        out[kind] += end - start
+        covered = end
+    out = {k: round(v, 6) for k, v in out.items()}
+    out["cache_hit"] = bool(getattr(_build_tls, "cache_hit", False))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -352,7 +601,7 @@ def _harvest_analysis(fn, args, kwargs) -> Optional[dict]:
 class _Site:
     __slots__ = ("kind", "builds", "evictions", "compile_seconds_total",
                  "signatures", "last_signature", "history", "last_ts",
-                 "analysis")
+                 "analysis", "phase_census")
 
     def __init__(self, kind: str, history: int):
         self.kind = kind
@@ -364,6 +613,7 @@ class _Site:
         self.history: collections.deque = collections.deque(maxlen=history)
         self.last_ts = 0.0
         self.analysis: Optional[dict] = None
+        self.phase_census: Optional[dict] = None
 
 
 class ProgramRegistry:
@@ -394,17 +644,30 @@ class ProgramRegistry:
                      compile_s: float = 0.0, t_end_ns: Optional[int] = None,
                      kind: str = "jit", registry=None,
                      labels: Optional[dict] = None,
-                     donated=None) -> dict:
+                     donated=None,
+                     build_clock: Optional[dict] = None) -> dict:
         """Record one trace+compile at ``site`` and return the build
         record.  Computes the signature (host metadata only) unless the
         caller already did, diffs it against the site's retained
         previous signature into a retrace cause, and emits the flight
         event / ``jit_compile_seconds`` observation / compile span —
-        plus the AOT memory/cost harvest when :func:`analysis_enabled`."""
+        plus the AOT memory/cost harvest when :func:`analysis_enabled`.
+        ``build_clock`` is the caller's :func:`read_build_clock` of the
+        call that built: ``trace_s``, ``lower_s``, ``backend_compile_s``
+        and ``cache_hit`` go into the record beside ``compile_s`` (the
+        call's whole wall).  The harvest is timed as ``analysis_s`` and
+        its own trace/lower/compile events as ``analysis_split``: they
+        are the analysis pass's cost, not the build's."""
         sig = tuple(signature) if signature is not None \
             else capture_signature(args, kwargs, fn=fn, donated=donated)
-        analysis = _harvest_analysis(fn, args, kwargs) \
-            if analysis_enabled() and fn is not None else None
+        analysis = census = None
+        timing = dict(build_clock or ())
+        if analysis_enabled() and fn is not None:
+            start_build_clock()
+            t0 = time.perf_counter()
+            analysis, census = _harvest_analysis(fn, args, kwargs)
+            timing["analysis_s"] = round(time.perf_counter() - t0, 6)
+            timing["analysis_split"] = read_build_clock()
         now = time.time()
         with self._lock:
             rec = self._sites.get(site)
@@ -420,8 +683,9 @@ class ProgramRegistry:
             rec.last_ts = now
             if analysis is not None:
                 rec.analysis = analysis
+                rec.phase_census = census
             record = {"build": n, "ts": now,
-                      "compile_s": round(float(compile_s), 6),
+                      "compile_s": round(float(compile_s), 6), **timing,
                       "cause": cause, "analysis": analysis}
             rec.history.append(record)
         self._emit(site, record, compile_s, t_end_ns, kind, registry, labels)
@@ -491,11 +755,23 @@ class ProgramRegistry:
         attrs = {"site": site, "build": record["build"], "lane": "compiles"}
         if record["cause"]:
             attrs["cause"] = record["cause"]
+        for k in BUILD_CLOCK_KEYS + ("analysis_s",):
+            if k in record:
+                attrs[k] = record[k]
         _tracing.add_span(f"compile:{site}",
                           int(t_end_ns - float(compile_s) * 1e9),
                           int(t_end_ns), _tid=COMPILES_LANE_TID, **attrs)
 
     # -- snapshots ---------------------------------------------------------
+
+    def phase_census(self, site: str) -> Optional[dict]:
+        """:func:`phase_census` of ``site``'s newest analysed build:
+        ``{instruction name: (phase, component, mixed)}``, or ``None``
+        where no build of the site was analysed.  The snapshots carry
+        its per-phase counts only (``analysis["phases"]``)."""
+        with self._lock:
+            rec = self._sites.get(site)
+            return rec.phase_census if rec is not None else None
 
     def snapshot(self) -> dict:
         """JSON-able registry dump — the ``/debug/programs`` body and
@@ -536,7 +812,10 @@ class ProgramRegistry:
                                s["compile_seconds_total"],
                            "causes": [f"build {h['build']}: {h['cause']}"
                                       for h in s["history"]
-                                      if h.get("cause")][-4:]}
+                                      if h.get("cause")][-4:],
+                           **({"phases": s["analysis"]["phases"]}
+                              if (s["analysis"] or {}).get("phases")
+                              else {})}
                     for name, s in snap["sites"].items()}}
 
     def introspect_requests(self) -> dict:

@@ -15,6 +15,16 @@ request/step"; spans can.  This module is the span half of the triad
   measured anyway (a device tick's wall clock times N slots at once:
   one call per slot lands each request's share on its own lane).
 
+An armed :class:`Span` is on two clocks at once: ``perf_counter_ns``
+for the chrome JSON and the flight ring, and — through a
+``jax.profiler.TraceAnnotation`` it holds open from start to end — the
+profiler's own, so that whenever a ``jax.profiler`` session records
+(``profiler.Profiler`` starts one) the span lands in the same
+``.xplane.pb`` as the device's ops and an idle gap on the device can be
+put down to what the host was doing.  :func:`add_span` is retroactive,
+the profiler's clock cannot be written after the fact, so those spans
+stay chrome-only.
+
 Cost model: tracing is DEFAULT-OFF.  Every entry point checks one
 module-level flag and returns a shared no-op when disabled, so the
 serving decode tick and the compiled fit loop keep their timings when
@@ -36,11 +46,15 @@ introspection server (``observability/server.py``) reads:
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import weakref
 from typing import Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
+from . import flight as _flight
 from .sanitizers import make_lock
 
 __all__ = ["span", "start_span", "end_span", "add_span", "Span",
@@ -85,14 +99,20 @@ class Span:
     passed at end merge over the start attrs (e.g. the committed token
     count is only known when the request finishes)."""
 
-    __slots__ = ("name", "attrs", "t0", "tid", "_open")
+    __slots__ = ("name", "attrs", "t0", "tid", "_open", "_annotation")
 
     def __init__(self, name: str, attrs: Optional[dict], tid=None):
         self.name = name
         self.attrs = attrs
-        self.t0 = time.perf_counter_ns()
         self.tid = tid if tid is not None else threading.get_ident()
         self._open = True
+        # the same span on the profiler's clock (no session recording:
+        # one atomic read); attrs given at end() stay chrome-only.  Held
+        # in an ExitStack, not by its own __enter__/__exit__: pht-lint
+        # resolves a dunder called by name to every class that has one
+        self._annotation = contextlib.ExitStack()
+        self._annotation.enter_context(TraceAnnotation(name, **(attrs or {})))
+        self.t0 = time.perf_counter_ns()
 
     def set_attrs(self, /, **attrs):
         if self.attrs is None:
@@ -106,8 +126,9 @@ class Span:
         self._open = False
         if attrs:
             self.set_attrs(**attrs)
-        _emit(self.name, self.t0, time.perf_counter_ns(), self.tid,
-              self.attrs)
+        t1 = time.perf_counter_ns()
+        self._annotation.close()
+        _emit(self.name, self.t0, t1, self.tid, self.attrs)
 
     def __enter__(self):
         return self
@@ -142,7 +163,6 @@ def _emit(name, t0_ns, t1_ns, tid, attrs):
     sink = _span_sink
     if sink is not None:
         sink(name, t0_ns, t1_ns, tid, attrs)
-    from . import flight as _flight
     # merge so the envelope keys win: a user attr named "name"/"dur_us"
     # must shadow, not TypeError, the traced hot path
     _flight.get_flight_recorder().record(
@@ -177,7 +197,9 @@ def add_span(name: str, t0_ns: int, t1_ns: int, /, _tid=None,
     """Emit an already-measured span (e.g. each slot's share of a device
     tick whose wall clock was timed for the tick histogram anyway).
     ``_tid`` overrides the chrome-trace lane — per-slot lanes keep one
-    request's prefill/decode/verify spans on one row."""
+    request's prefill/decode/verify spans on one row.  Chrome JSON and
+    flight ring only: a span that is already over cannot be put on the
+    profiler's clock, so it is not in the ``.xplane.pb``."""
     if not _enabled:
         return
     _emit(name, int(t0_ns), int(t1_ns),

@@ -12,6 +12,7 @@ c_identity/c_allreduce pairs). PP and SP are explicit shard_map programs
 
 from __future__ import annotations
 
+import itertools
 import math
 from typing import Callable, Dict, Optional, Sequence
 
@@ -24,6 +25,7 @@ from jax import set_mesh as _set_mesh
 from ..core.tensor import Tensor
 from ..nn.layer import Layer
 from ..observability import metrics as _obs
+from ..observability import tracing as _tracing
 
 _current_mesh: Optional[Mesh] = None
 
@@ -675,7 +677,8 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
                                          buffers={k: v for k, v in buffers.items()})
             from ..nn.functional.loss import fused_softmax_ce_rows
             lg = logits._value if isinstance(logits, Tensor) else logits
-            loss = jnp.mean(fused_softmax_ce_rows(lg, labels))
+            with jax.named_scope("ce"):
+                loss = jnp.mean(fused_softmax_ce_rows(lg, labels))
             # MoE load-balance aux (ref moe/grad_clip.py context + GShard):
             # MoELayer.forward left this trace's aux value on the layer
             aux = _collect_moe_aux(model)
@@ -762,48 +765,51 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
             # without grad_overlap the global clip norm is computed
             # BEFORE the ZeRO grad pins (on the replicated grads) so
             # sharded-vs-replicated runs clip by the bit-identical scale
-            gnorm = jnp.sqrt(sum(
-                jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree.leaves(grads)))
-            scale = grad_clip_norm / jnp.maximum(gnorm, grad_clip_norm)
-            grads = jax.tree.map(lambda g: g * scale.astype(g.dtype), grads)
+            with jax.named_scope("clip"):
+                gnorm = jnp.sqrt(sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree.leaves(grads)))
+                scale = grad_clip_norm / jnp.maximum(gnorm, grad_clip_norm)
+                grads = jax.tree.map(lambda g: g * scale.astype(g.dtype),
+                                     grads)
         t = step_no + 1
         new_params, new_opt = {}, {}
-        for k in params:
-            g, st = grads[k], opt_state[k]
-            st = dict(st)
-            master = st.pop("master", None)
-            if zero_on:
-                # ZeRO pins, per tensor: the pending dp grad psum fuses
-                # with the slice into a reduce-scatter; moments stay on
-                # their 1/dp slice in AND out (GSPMD cannot re-replicate
-                # them); the updated param casts to the compute dtype
-                # FIRST and then gathers back to its own sharding — an
-                # independent per-tensor all-gather the scheduler
-                # overlaps with the other params' update compute
-                msh = m_sh[k]
+        with jax.named_scope("update"):
+            for k in params:
+                g, st = grads[k], opt_state[k]
+                st = dict(st)
+                master = st.pop("master", None)
+                if zero_on:
+                    # ZeRO pins, per tensor: the pending dp grad psum fuses
+                    # with the slice into a reduce-scatter; moments stay on
+                    # their 1/dp slice in AND out (GSPMD cannot re-replicate
+                    # them); the updated param casts to the compute dtype
+                    # FIRST and then gathers back to its own sharding — an
+                    # independent per-tensor all-gather the scheduler
+                    # overlaps with the other params' update compute
+                    msh = m_sh[k]
 
-                def wsc(a, _m=msh):
-                    return jax.lax.with_sharding_constraint(a, _m)
+                    def wsc(a, _m=msh):
+                        return jax.lax.with_sharding_constraint(a, _m)
 
-                g = wsc(g)
-                st = {s: wsc(v) for s, v in st.items()}
-                p_upd = wsc(master) if master is not None \
-                    else wsc(params[k])
-            else:
-                p_upd = master if master is not None else params[k]
-            new_v, new_st = _apply_update(k, p_upd, g, st, lr, t)
-            if zero_on:
-                new_st = {s: wsc(v) for s, v in new_st.items()}
-            if master is not None:
-                # the f32 master never leaves its shard
-                new_st["master"] = wsc(new_v) if zero_on else new_v
-            nv = new_v.astype(params[k].dtype)
-            if zero_on:
-                nv = jax.lax.with_sharding_constraint(nv,
-                                                      param_shardings[k])
-            new_params[k] = nv
-            new_opt[k] = new_st
+                    g = wsc(g)
+                    st = {s: wsc(v) for s, v in st.items()}
+                    p_upd = wsc(master) if master is not None \
+                        else wsc(params[k])
+                else:
+                    p_upd = master if master is not None else params[k]
+                new_v, new_st = _apply_update(k, p_upd, g, st, lr, t)
+                if zero_on:
+                    new_st = {s: wsc(v) for s, v in new_st.items()}
+                if master is not None:
+                    # the f32 master never leaves its shard
+                    new_st["master"] = wsc(new_v) if zero_on else new_v
+                nv = new_v.astype(params[k].dtype)
+                if zero_on:
+                    nv = jax.lax.with_sharding_constraint(nv,
+                                                          param_shardings[k])
+                new_params[k] = nv
+                new_opt[k] = new_st
         return new_params, new_opt, step_no + 1, loss
 
     bspec = batch_spec(mesh)
@@ -855,6 +861,7 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
 
     state = {"params": params, "opt_state": opt_state, "step": step_no}
     param_tensors = dict(model.named_parameters())
+    host_steps = itertools.count()
 
     def step(state, ids, labels, rng, lr=None):
         # lr is a dynamic scalar: schedules (PipelineParallel.train_batch
@@ -868,24 +875,34 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
                     raise ValueError(
                         f"sequence length {leaf.shape[1]} must divide "
                         f"evenly over the 'sp' axis (degree {sp_degree})")
-        lr_now = jnp.float32(learning_rate if lr is None else lr)
-        fn = jitted if (hasattr(ids, "ndim") and hasattr(labels, "ndim")) \
-            else _get_jitted((ids, labels))
-        # partial-manual shard_map (the pp pipeline) requires the ambient
-        # mesh at trace time (_smap.run_shard_map); harmless otherwise
-        with _set_mesh(mesh):
-            new_params, new_opt, new_step, loss = fn(
-                state["params"], state["opt_state"], state["step"],
-                (ids, labels), rng, lr_now)
-        # The old param buffers were donated; rebind the live model's tensors
-        # to the updated arrays so the Layer stays usable (eval, jit.save,
-        # checkpointing) throughout training.  Stacked pp block params are
-        # NOT unstacked per step (that would gather across the pp axis every
-        # iteration) — call step.sync_model(state) before eval/save.
-        for k, v in new_params.items():
-            t = param_tensors.get(k)
-            if t is not None:
-                t._set_value(v)
+        # on the profiler's clock, for whoever records a session: one
+        # "train_step" event per call, numbered by the host's own count
+        # (the device's counter would be a read), and under it the two
+        # things this function does on the host
+        with jax.profiler.StepTraceAnnotation("train_step",
+                                              step_num=next(host_steps)):
+            lr_now = jnp.float32(learning_rate if lr is None else lr)
+            fn = jitted if (hasattr(ids, "ndim")
+                            and hasattr(labels, "ndim")) \
+                else _get_jitted((ids, labels))
+            # partial-manual shard_map (the pp pipeline) requires the
+            # ambient mesh at trace time (_smap.run_shard_map); harmless
+            # otherwise
+            with _tracing.span("train.dispatch"), _set_mesh(mesh):
+                new_params, new_opt, new_step, loss = fn(
+                    state["params"], state["opt_state"], state["step"],
+                    (ids, labels), rng, lr_now)
+            # The old param buffers were donated; rebind the live model's
+            # tensors to the updated arrays so the Layer stays usable
+            # (eval, jit.save, checkpointing) throughout training.  Stacked
+            # pp block params are NOT unstacked per step (that would gather
+            # across the pp axis every iteration) — call
+            # step.sync_model(state) before eval/save.
+            with _tracing.span("train.rebind"):
+                for k, v in new_params.items():
+                    t = param_tensors.get(k)
+                    if t is not None:
+                        t._set_value(v)
         return ({"params": new_params, "opt_state": new_opt,
                  "step": new_step}, loss)
 
@@ -923,13 +940,14 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
             if grad_clip_norm is not None:
                 # replicated-grads global clip — the bit-identical
                 # preamble of the resident (non-overlap) ZeRO step
-                gnorm = jnp.sqrt(sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in jax.tree.leaves(grads)))
-                scale = grad_clip_norm / jnp.maximum(gnorm,
-                                                     grad_clip_norm)
-                grads = jax.tree.map(
-                    lambda g: g * scale.astype(g.dtype), grads)
+                with jax.named_scope("clip"):
+                    gnorm = jnp.sqrt(sum(
+                        jnp.sum(jnp.square(g.astype(jnp.float32)))
+                        for g in jax.tree.leaves(grads)))
+                    scale = grad_clip_norm / jnp.maximum(gnorm,
+                                                         grad_clip_norm)
+                    grads = jax.tree.map(
+                        lambda g: g * scale.astype(g.dtype), grads)
             return loss, grads, step_no_ + 1
 
         def _offload_tensor_update(i, p, g, st, lr, t):
@@ -988,22 +1006,25 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
                             f"sequence length {leaf.shape[1]} must "
                             f"divide evenly over the 'sp' axis "
                             f"(degree {sp_degree})")
-            lr_now = jnp.float32(learning_rate if lr is None else lr)
-            fn = grads_jitted if (hasattr(ids, "ndim")
-                                  and hasattr(labels, "ndim")) \
-                else _get_grads_jitted((ids, labels))
-            with _set_mesh(mesh):
-                loss, grads, t = fn(state["params"], state["step"],
-                                    (ids, labels), rng, lr_now)
-            vals = [state["params"][k] for k in key_order]
-            gs = [grads[k] for k in key_order]
-            hst = [state["opt_state"][k] for k in key_order]
-            new_vals, new_hst = updater.apply(vals, gs, hst, lr_now, t)
-            new_params = dict(zip(key_order, new_vals))
-            for k, v in new_params.items():
-                tn = param_tensors.get(k)
-                if tn is not None:
-                    tn._set_value(v)
+            with jax.profiler.StepTraceAnnotation(
+                    "train_step", step_num=next(host_steps)):
+                lr_now = jnp.float32(learning_rate if lr is None else lr)
+                fn = grads_jitted if (hasattr(ids, "ndim")
+                                      and hasattr(labels, "ndim")) \
+                    else _get_grads_jitted((ids, labels))
+                with _tracing.span("train.dispatch"), _set_mesh(mesh):
+                    loss, grads, t = fn(state["params"], state["step"],
+                                        (ids, labels), rng, lr_now)
+                vals = [state["params"][k] for k in key_order]
+                gs = [grads[k] for k in key_order]
+                hst = [state["opt_state"][k] for k in key_order]
+                new_vals, new_hst = updater.apply(vals, gs, hst, lr_now, t)
+                new_params = dict(zip(key_order, new_vals))
+                with _tracing.span("train.rebind"):
+                    for k, v in new_params.items():
+                        tn = param_tensors.get(k)
+                        if tn is not None:
+                            tn._set_value(v)
             return ({"params": new_params,
                      "opt_state": dict(zip(key_order, new_hst)),
                      "step": t}, loss)

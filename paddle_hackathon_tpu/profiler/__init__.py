@@ -19,6 +19,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import tempfile
 import threading
 import time
 from enum import Enum
@@ -375,7 +376,8 @@ class Profiler:
             try:
                 import jax
                 self._device_dir = os.path.join(
-                    os.environ.get("PADDLE_PROFILER_DIR", "/tmp"),
+                    os.environ.get("PADDLE_PROFILER_DIR",
+                                   tempfile.gettempdir()),
                     f"xla_trace_{os.getpid()}_{self.step_num}")
                 jax.profiler.start_trace(self._device_dir)
                 self._device_active = True
@@ -460,6 +462,14 @@ class Profiler:
     @property
     def events(self):
         return list(self._events)
+
+    @property
+    def device_trace_dir(self):
+        """Where the last recording's ``jax.profiler`` session wrote its
+        ``.xplane.pb`` (device ops and the program's armed spans on one
+        clock), or ``None`` before the first recording with the TPU
+        target."""
+        return self._device_dir
 
     def benchmark_summary(self):
         return self._benchmark.summary()

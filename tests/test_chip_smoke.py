@@ -40,6 +40,13 @@ def test_trainer_phase_trains_and_compiles_once(one_chip):
     assert state_keys == {"params", "opt_state", "step"}
 
 
+def test_trace_phase_finds_the_programs_spans_in_the_profilers_file(cs):
+    facts = cs.phase_trace(TINY, batch=4, seqlen=64, steps=3)
+    assert facts.pop("recorded_step_seconds") > 0
+    assert facts == {"steps": 3, "host_spans": {
+        "train_step": 3, "train.dispatch": 3, "train.rebind": 3}}
+
+
 @pytest.mark.parametrize("quant", [None, "int8"])
 def test_server_phase_agrees_with_reference(cs, quant):
     facts = cs.phase_server(TINY, quant=quant, prompt_lens=(5, 17, 9, 12),

@@ -514,3 +514,231 @@ def test_donation_map_recorded_in_signature():
     sig = capture_signature((np.zeros((2,)),),
                             donated=w._pht_donate_argnums)
     assert sig[-1] == ("static", "donated", "(0,)")
+
+
+# ---------------------------------------------------------------------------
+# phase census + build clock (the train step names its own phases)
+# ---------------------------------------------------------------------------
+
+# a compiled step as XLA:TPU prints it (v5e, jax 0.9.0), cut to one
+# instruction of each kind the census tells apart
+_PHASE_HLO = """HloModule jit_train_step, is_scheduled=true
+
+%fused_computation.1 (param_0.1: bf16[8,64]) -> bf16[8,64] {
+  %param_0.1 = bf16[8,64]{1,0} parameter(0)
+  ROOT %tanh.1 = bf16[8,64]{1,0} tanh(%param_0.1), metadata={op_name="jit(train_step)/jvp(mlp)/tanh" stack_frame_id=7}
+}
+
+%bitcast_fusion.1 (bitcast_input.1: bf16[8,64]) -> bf16[8,64] {
+  %bitcast_input.1 = bf16[8,64]{1,0} parameter(0)
+  ROOT %bitcast.9 = bf16[8,64]{1,0} bitcast(%bitcast_input.1)
+}
+
+%fused_computation.2 (param_0.2: bf16[8,64], param_1.2: bf16[8,64]) -> (f32[], bf16[64,64,1]) {
+  %param_0.2 = bf16[8,64]{1,0:T(8,128)(2,1)} parameter(0)
+  %fusion.90 = bf16[8,64]{1,0} fusion(%param_0.2), kind=kLoop, calls=%bitcast_fusion.1
+  %param_1.2 = bf16[8,64]{1,0:T(8,128)(2,1)S(1)} parameter(1)
+  %fusion.91 = bf16[8,64]{1,0} fusion(%param_1.2), kind=kLoop, calls=%fused_computation.9, metadata={op_name="params[\\'gpt.w\\']"}
+  %convolution.2 = bf16[64,64,1]{1,0,2:T(8,128)(2,1)} convolution(%fusion.90, %fusion.91), window={size=8}, dim_labels=0fb_0io->bf0, metadata={op_name="jit(train_step)/transpose(jvp(attn))/dot_general" stack_frame_id=83}
+  %convert.2 = f32[64,64]{1,0} convert(%convolution.2), metadata={op_name="jit(train_step)/clip/convert_element_type"}
+  %reduce_sum.2 = f32[]{:T(128)} reduce(%convert.2, %constant.2), dimensions={0,1}, to_apply=%region_1.1, metadata={op_name="jit(train_step)/clip/reduce_sum"}
+  ROOT %tuple.2 = (f32[]{:T(128)}, bf16[64,64,1]{1,0,2:T(8,128)(2,1)}) tuple(%reduce_sum.2, %convolution.2)
+}
+
+%fused_computation.9 (param_0.9: bf16[8,64]) -> bf16[8,64] {
+  ROOT %param_0.9 = bf16[8,64]{1,0} parameter(0)
+}
+
+%region_1.1 (a.1: f32[], b.1: f32[]) -> f32[] {
+  %a.1 = f32[]{:T(128)} parameter(0), metadata={op_name="reduce_sum"}
+  %b.1 = f32[]{:T(128)} parameter(1), metadata={op_name="reduce_sum"}
+  ROOT %add.1 = f32[]{:T(128)} add(%a.1, %b.1), metadata={op_name="jit(train_step)/clip/reduce_sum"}
+}
+
+%fused_computation.3 (param_0.3: bf16[64], param_1.3: f32[64]) -> (bf16[64], f32[64]) {
+  %param_0.3 = bf16[64]{0} parameter(0)
+  %param_1.3 = f32[64]{0} parameter(1)
+  %mul.3 = f32[64]{0} multiply(%param_1.3, %param_1.3), metadata={op_name="jit(train_step)/clip/mul"}
+  %sub.3 = f32[64]{0} subtract(%param_1.3, %mul.3), metadata={op_name="jit(train_step)/update/sub"}
+  ROOT %tuple.3 = (bf16[64]{0}, f32[64]{0}) tuple(%param_0.3, %sub.3)
+}
+
+%fused_computation.4 (param_0.4: bf16[8,64]) -> bf16[8,64] {
+  %param_0.4 = bf16[8,64]{1,0} parameter(0)
+  %dot.4 = bf16[8,64]{1,0} dot(%param_0.4, %param_0.4), metadata={op_name="jit(train_step)/jvp(lm_head)/dot_general"}
+  %dot.5 = bf16[8,64]{1,0} dot(%dot.4, %param_0.4), metadata={op_name="jit(train_step)/transpose(jvp(lm_head))/dot_general"}
+  ROOT %add.4 = bf16[8,64]{1,0} add(%dot.4, %dot.5), metadata={op_name="jit(train_step)/jvp(ce)/add"}
+}
+
+%body.1 (arg.1: (s32[], bf16[8,64])) -> (s32[], bf16[8,64]) {
+  %arg.1 = (s32[], bf16[8,64]{1,0}) parameter(0)
+  %get-tuple-element.1 = bf16[8,64]{1,0} get-tuple-element(%arg.1), index=1
+  %fusion.20 = bf16[8,64]{1,0} fusion(%get-tuple-element.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(train_step)/jvp(mlp)/while/body/tanh"}
+  ROOT %tuple.20 = (s32[], bf16[8,64]{1,0}) tuple(%get-tuple-element.1, %fusion.20)
+}
+
+%cond.1 (arg.2: (s32[], bf16[8,64])) -> pred[] {
+  %arg.2 = (s32[], bf16[8,64]{1,0}) parameter(0)
+  ROOT %lt.1 = pred[] constant(true)
+}
+
+ENTRY %main.1 (params__w.1: bf16[8,64], opt__m.1: f32[64]) -> (bf16[8,64], f32[64]) {
+  %params__w.1 = bf16[8,64]{1,0:T(8,128)(2,1)} parameter(0), sharding={replicated}, metadata={op_name="params[\\'gpt.w\\']"}
+  %opt__m.1 = f32[64]{0:T(1024)} parameter(1), metadata={op_name="opt_state[\\'gpt.w\\'][\\'m\\']"}
+  %copy-start.1 = (bf16[8,64]{1,0:T(8,128)(2,1)S(1)}, bf16[8,64]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%params__w.1)
+  %fusion.1 = bf16[8,64]{1,0:T(8,128)(2,1)} fusion(%params__w.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(train_step)/jvp(mlp)/tanh" stack_frame_id=7}
+  %flash_packed_fwd.1 = bf16[8,64]{1,0} custom-call(%fusion.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/jvp(attn)/flash_packed_fwd/pallas_call"}
+  %clamp.1 = bf16[8,64]{1,0} clamp(%fusion.1, %fusion.1, %fusion.1), metadata={op_name="jit(train_step)/jvp(embed)/jit(clip)/max"}
+  %multiply_reduce_fusion.2 = (f32[]{:T(128)}, bf16[64,64,1]{1,0,2:T(8,128)(2,1)}) fusion(%fusion.1, %clamp.1), kind=kOutput, calls=%fused_computation.2, metadata={op_name="jit(train_step)/clip/reduce_sum" stack_frame_id=177}
+  %subtract_convert_fusion.3 = (bf16[64]{0}, f32[64]{0}) fusion(%params__w.1, %opt__m.1), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(train_step)/update/convert_element_type"}
+  %fusion.4 = bf16[8,64]{1,0} fusion(%fusion.1), kind=kOutput, calls=%fused_computation.4, metadata={op_name="jit(train_step)/jvp(ce)/add"}
+  %while.1 = (s32[], bf16[8,64]{1,0}) while(%tuple.0), condition=%cond.1, body=%body.1, metadata={op_name="jit(train_step)/jvp(mlp)/while"}
+  %sqrt.1 = f32[]{:T(128)} sqrt(%get-tuple-element.9), metadata={op_name="jit(train_step)/clip/sqrt"}
+  %add.9 = s32[]{:T(128)} add(%copy.9, %constant.9), metadata={op_name="jit(train_step)/add"}
+  ROOT %tuple.9 = (bf16[8,64]{1,0}, f32[64]{0}) tuple(%fusion.4, %opt__m.1)
+}
+"""
+
+
+def test_phase_census_on_a_hand_written_executable():
+    census = programs.phase_census(_PHASE_HLO)
+    assert {k: census[k] for k in census if "." in k and not k.startswith(
+        ("params", "opt", "tuple", "arg", "get-tuple"))} == {
+        "copy-start.1": ("other", "", False),           # no metadata
+        "fusion.1": ("fwd", "mlp", False),
+        "flash_packed_fwd.1": ("fwd", "attn", False),
+        # jit(clip) is jnp.clip, not the clip scope
+        "clamp.1": ("fwd", "embed", False),
+        # a weight gradient with the clip's sum of squares fused in: named
+        # by its root (clip), mixed, and given to its one matmul; the
+        # parameter path on a nested fusion is no phase
+        "multiply_reduce_fusion.2": ("bwd", "attn", True),
+        # clip's scaling fused into the update: mixed, no matmul, its own
+        "subtract_convert_fusion.3": ("update", "update", True),
+        # two matmuls of two phases: mixed, keeps its own
+        "fusion.4": ("fwd", "ce", True),
+        "while.1": ("fwd", "mlp", False),
+        "fusion.20": ("fwd", "mlp", False),             # the while's body
+        "lt.1": ("other", "", False),                   # and its condition
+        "sqrt.1": ("clip", "clip", False),
+        "add.9": ("other", "", False),                  # under no scope
+    }
+    # nothing from inside a fused computation or a reduce's region
+    assert not {"tanh.1", "convolution.2", "add.1", "dot.4"} & set(census)
+    assert programs.phase_counts(census) == {
+        "other": 10, "fwd": 6, "bwd": 1, "update": 1, "clip": 1, "mixed": 3}
+    assert programs.phase_census("no computation here") == {}
+
+
+@pytest.fixture(scope="module")
+def toy_train_step():
+    """One toy GPT step through ``make_sharded_train_step``, built under
+    the analysis pass and called twice."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_hackathon_tpu import parallel
+    from paddle_hackathon_tpu.models.gpt import (GPTConfig, GPTForCausalLM,
+                                                 param_sharding_spec)
+    site = "parallel.sharded_train_step"
+    paddle.seed(0)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=256, hidden_size=32, num_layers=1, num_heads=2,
+        max_position_embeddings=16, hidden_dropout_prob=0.0,
+        attention_dropout_prob=0.0))
+    mesh = parallel.create_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step, state = parallel.make_sharded_train_step(
+        model, mesh, rule=param_sharding_spec)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    before = get_program_registry().snapshot()["sites"].get(
+        site, {"builds": 0})["builds"]
+    with program_analysis():
+        t0 = time.perf_counter()
+        state, _ = step(state, ids, ids, jax.random.key(0))
+        wall = time.perf_counter() - t0
+        state, loss = step(state, ids, ids, jax.random.key(1))
+    assert np.isfinite(float(loss))
+    snap = get_program_registry().snapshot()["sites"][site]
+    return {"site": site, "wall": wall, "snap": snap,
+            "new": [h for h in snap["history"] if h["build"] > before]}
+
+
+def test_phase_census_of_a_compiled_toy_train_step(toy_train_step):
+    census = get_program_registry().phase_census(toy_train_step["site"])
+    phases = {p for p, _, _ in census.values()}
+    assert {"fwd", "bwd", "clip", "update"} <= phases
+    assert {c for _, c, _ in census.values()} - {""} \
+        == set(programs.PHASE_COMPONENTS)
+    # forward and backward of every scope of the model are told apart
+    for c in ("embed", "attn", "mlp", "ln_f", "lm_head", "ce"):
+        assert {("fwd", c), ("bwd", c)} <= {(p, k) for p, k, _
+                                            in census.values()}, c
+    # the snapshots carry counts, not names
+    counts = toy_train_step["snap"]["analysis"]["phases"]
+    assert counts == programs.phase_counts(census)
+    assert sum(counts[p] for p in programs.PHASES if p in counts) \
+        == len(census)
+    assert get_program_registry().bench_block()["sites"][
+        toy_train_step["site"]]["phases"] == counts
+    assert get_program_registry().phase_census("no.such.site") is None
+
+
+def test_build_record_says_where_the_seconds_went(toy_train_step):
+    (rec,) = toy_train_step["new"]       # the second call recorded nothing
+    parts = [rec[k] for k in programs.BUILD_CLOCK_KEYS]
+    assert all(v >= 0 for v in parts) and rec["trace_s"] > 0
+    assert rec["analysis_s"] >= 0 and rec["cache_hit"] is False
+    # disjoint stretches of the first call's wall clock; the analysis
+    # pass's own events went to it, not to the build
+    assert sum(parts) <= rec["compile_s"]
+    assert rec["compile_s"] + rec["analysis_s"] <= toy_train_step["wall"]
+    assert set(rec["analysis_split"]) == set(programs.BUILD_CLOCK_KEYS) \
+        | {"cache_hit"}
+    assert 0 <= rec["analysis"]["census_s"] <= rec["analysis_s"]
+
+
+def test_build_clock_counts_nested_spans_once():
+    programs.start_build_clock()
+    assert programs.read_build_clock() == {
+        "trace_s": 0.0, "lower_s": 0.0, "backend_compile_s": 0.0,
+        "cache_hit": False}
+    trace, lower, backend = programs._BUILD_EVENTS
+    # an outer trace 0..10 that inlines a jitted function (2..3) and
+    # compiles a small program eagerly (4..6), then lowers and compiles
+    for ev, t0, t1 in ((trace, 2.0, 3.0), (trace, 4.0, 4.5),
+                       (lower, 4.5, 5.0), (backend, 5.0, 6.0),
+                       (trace, 0.0, 10.0), (lower, 10.0, 13.0),
+                       (backend, 13.5, 20.0), ("/jax/other", 0.0, 99.0)):
+        programs._on_build_span(ev, t0, t1, fun_name="f")
+    programs._on_build_duration(programs._CACHE_RETRIEVAL_EVENT, 0.25)
+    assert programs.read_build_clock() == {
+        "trace_s": 10.0, "lower_s": 3.0, "backend_compile_s": 6.5,
+        "cache_hit": True}
+    programs.start_build_clock()
+    assert programs.read_build_clock()["trace_s"] == 0.0
+
+
+def test_instrumented_jit_build_record_and_compile_span_carry_the_clock():
+    import jax
+    import jax.numpy as jnp
+    spans = []
+    tracing.set_span_sink(
+        lambda name, t0, t1, tid, attrs: spans.append((name, attrs)))
+    tracing.enable_tracing()
+    try:
+        site = _site("clock")
+        w = instrument_jit(jax.jit(lambda x: jnp.tanh(x) * 2), site=site,
+                           registry=MetricRegistry())
+        w(jnp.ones((4,)))
+        w(jnp.ones((4,)))
+    finally:
+        tracing.disable_tracing()
+        tracing.set_span_sink(None)
+    (rec,) = get_program_registry().snapshot()["sites"][site]["history"]
+    assert rec["trace_s"] > 0 and rec["backend_compile_s"] > 0
+    assert sum(rec[k] for k in programs.BUILD_CLOCK_KEYS) <= rec["compile_s"]
+    assert "analysis_s" not in rec      # no analysis pass was asked for
+    (attrs,) = [a for n, a in spans if n == f"compile:{site}"]
+    assert {k: attrs[k] for k in programs.BUILD_CLOCK_KEYS} \
+        == {k: rec[k] for k in programs.BUILD_CLOCK_KEYS}

@@ -503,3 +503,85 @@ def test_nonfinite_watchdog(tmp_path, monkeypatch):
     snap = reg.snapshot()["metrics"]["train_nonfinite_total"]["series"]
     assert any(s["labels"].get("path") == "hapi_eager" and s["value"] >= 1
                for s in snap)
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _host_events(trace_dir):
+    """``{event name: count}`` over the host planes of the one
+    ``.xplane.pb`` a ``jax.profiler`` session wrote."""
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import trace_reduce
+    planes = trace_reduce.load_planes(trace_reduce.find_xplane(trace_dir))
+    out = {}
+    for pname, lines in planes.items():
+        if pname.startswith(trace_reduce.HOST_PLANE_PREFIX):
+            for events in lines.values():
+                for name, _, dur in events:
+                    assert dur >= 0
+                    out[name] = out.get(name, 0) + 1
+    return out
+
+
+def test_armed_span_and_train_step_are_in_the_profilers_trace(tmp_path):
+    """The program's spans and the step annotation land in the same
+    ``.xplane.pb`` as the device's ops would: read back from a CPU
+    session.  A disarmed span, and ``add_span``, write nothing there."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_hackathon_tpu import parallel
+    from paddle_hackathon_tpu.models.gpt import (GPTConfig, GPTForCausalLM,
+                                                 param_sharding_spec)
+    paddle.seed(0)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=128, hidden_size=32, num_layers=1, num_heads=2,
+        max_position_embeddings=8, hidden_dropout_prob=0.0,
+        attention_dropout_prob=0.0))
+    mesh = parallel.create_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step, state = parallel.make_sharded_train_step(
+        model, mesh, rule=param_sharding_spec)
+    ids = jnp.zeros((2, 8), jnp.int32)
+    state, _ = step(state, ids, ids, jax.random.key(0))     # compiles
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.span("test.disarmed", n=1):
+            pass
+        state, _ = step(state, ids, ids, jax.random.key(1))  # disarmed
+        tracing.enable_tracing()
+        with tracing.span("test.armed", n=2):
+            tracing.add_span("test.retro", 0, 10)
+        h = tracing.start_span("test.explicit")
+        for i in range(2):
+            state, loss = step(state, ids, ids, jax.random.key(2 + i))
+        tracing.end_span(h, steps=2)
+        loss.block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+        tracing.disable_tracing()
+    seen = _host_events(str(tmp_path))
+    # the step annotation is unconditional; the spans only when armed
+    assert seen.get("train_step") == 3
+    assert seen.get("train.dispatch") == 2 and seen.get("train.rebind") == 2
+    assert seen.get("test.armed") == 1 and seen.get("test.explicit") == 1
+    assert "test.disarmed" not in seen and "test.retro" not in seen
+    # and the armed spans went to the flight ring as before
+    names = [e["name"] for e in get_flight_recorder().events()
+             if e["kind"] == "span"]
+    assert names.count("train.dispatch") == 2 and "test.retro" in names
+
+
+def test_disarmed_span_is_the_shared_noop_and_enters_no_annotation():
+    assert not tracing.tracing_enabled()
+    sp = tracing.span("off", a=1)
+    assert sp is tracing.span("off2") and not hasattr(sp, "_annotation")
+    tracing.enable_tracing()
+    armed = tracing.start_span("on", a=1)
+    assert armed._annotation is not None
+    armed.end()
+    armed.end()       # closing twice leaves the annotation closed once
