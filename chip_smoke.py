@@ -270,12 +270,16 @@ def packed_flash_case(name, b, s, heads, d, causal, facts, dropout=0.0):
                                        seed)
         return jnp.sum(o.astype(jnp.float32) ** 2), o
     (val, out), grad = jax.jit(jax.value_and_grad(loss, has_aux=True))(qkv)
+    # what the plan runs of the score square (0.5 is the causal least)
+    plan = {"plan": fap._plan(s, s, heads, d, qkv.dtype),
+            "executed_score_share": fap.executed_score_share(
+                s, s, heads, d, qkv.dtype, causal)}
     if dropout:
         if not bool(jnp.isfinite(val)) or not bool(
                 jnp.all(jnp.isfinite(grad.astype(jnp.float32)))):
             raise RuntimeError(f"kernel {name}: non-finite with dropout")
         facts[name] = {"finite": True}
-        return
+        return plan
 
     def ref_loss(x):
         x5 = x.astype(jnp.float32).reshape(b, s, 3, heads, d)
@@ -286,6 +290,7 @@ def packed_flash_case(name, b, s, heads, d, causal, facts, dropout=0.0):
         jax.value_and_grad(ref_loss, has_aux=True))(qkv)
     _check(name + ".fwd", out, rout, facts)
     _check(name + ".bwd", grad, rgrad, facts)
+    return plan
 
 
 def bhd_flash_case(name, bh, s, d, causal, facts):
@@ -369,16 +374,19 @@ def phase_kernels(hidden=768, heads=12, seqlen=1024, batch=2, slots=8,
                   chunk=32, page=16, pages_per_slot=14, extra_shapes=True):
     """Compile every kernel family at the main path's shapes and compare
     with the references.  ``extra_shapes`` adds GPT-3 1.3B's attention
-    (H16/D128), ERNIE's (s512, non-causal, both layouts), a dropout
+    (H16/D128), gpt2-medium's (H16/D64, eight heads a cell), a four-block
+    row (s2048), ERNIE's (s512, non-causal, both layouts), a dropout
     variant and 1.3B's deepest GEMM."""
     import jax.numpy as jnp
     t_all = time.perf_counter()
-    facts, seconds = {}, {}
+    facts, seconds, plans = {}, {}, {}
 
     def run(case, name, *args, **kw):
         t0 = time.perf_counter()
-        case(name, *args, facts, **kw)
+        plan = case(name, *args, facts, **kw)
         seconds[name] = round(time.perf_counter() - t0, 2)
+        if plan:
+            plans[name] = plan
 
     d = hidden // heads
     run(packed_flash_case, "flash_packed", batch, seqlen, heads, d, True)
@@ -398,6 +406,9 @@ def phase_kernels(hidden=768, heads=12, seqlen=1024, batch=2, slots=8,
         run(packed_flash_case, "flash_packed_dropout", batch, seqlen, heads,
             d, True, dropout=0.1)
         run(packed_flash_case, "flash_packed_1p3b", 1, 1024, 16, 128, True)
+        run(packed_flash_case, "flash_packed_medium", 2, 1024, 16, 64, True)
+        # four kv blocks a row: strips on the diagonal, whole tiles under
+        run(packed_flash_case, "flash_packed_s2048", 1, 2048, 12, 64, True)
         run(packed_flash_case, "flash_packed_ernie", batch, 512, 12, 64,
             False)
         run(bhd_flash_case, "flash_bhd_ernie", 24, 512, 64, False)
@@ -410,7 +421,8 @@ def phase_kernels(hidden=768, heads=12, seqlen=1024, batch=2, slots=8,
             run(quant_matmul_case, f"quant_matmul_int8.m{m}.k8192.n2048",
                 m, 8192, 2048, jnp.int8)
     return {"seconds": round(time.perf_counter() - t_all, 2),
-            "cases": facts, "case_seconds": seconds}
+            "cases": facts, "case_seconds": seconds,
+            "packed_flash_plans": plans}
 
 
 # ---------------------------------------------------------------------------
@@ -487,6 +499,10 @@ def phase_trainer(cfg_kw=None, batch=32, seqlen=1024, steps=6,
                      "flash_packed_bwd_dq": cfg.num_layers},
             "train step")
         facts["mosaic_kernels"] = census
+    # what the packed flash kernels said of themselves as the step was
+    # traced (0.5 is the causal least)
+    facts["executed_score_share"] = site["history"][-1].get(
+        "kernel_facts", {}).get("executed_score_share")
     parallel.set_mesh(None)
     return facts, state, mesh
 
@@ -841,7 +857,8 @@ def main():
         facts["kernels"] = phase_kernels()
         _say(f"kernels ok in {facts['kernels']['seconds']} s "
              f"({len(facts['kernels']['cases'])} comparisons; seconds per "
-             f"case {facts['kernels']['case_seconds']})")
+             f"case {facts['kernels']['case_seconds']}; packed flash "
+             f"plans {facts['kernels']['packed_flash_plans']})")
         facts["trainer"], state, _ = phase_trainer(on_chip=True)
         del state
         _say(f"trainer ok {facts['trainer']}")

@@ -26,9 +26,21 @@ This kernel family keeps everything in the projection-native layout:
 Stats (lse) live transposed as (b, H, 8, s) sublane-broadcast rows — the
 running max/sum also live transposed in VMEM ((G, 8, block) instead of
 (G, block, 128)), which is what lets 512-edge blocks fit.  Causal masking
-uses diagonal-clamped index maps (masked cells skip compute AND their
-DMA).  Dropout reuses the positional-hash mask, keyed by the global head
-index so each head draws an independent mask.
+works at two grains.  Cells wholly above the diagonal skip compute AND
+their DMA (diagonal-clamped index maps).  A cell the diagonal crosses
+computes, in all three kernels, only the trapezoid its rows can see:
+c-row strips with static extents (``_diag_cell``), the ``where`` on each
+strip's one (c, c) diagonal sub-tile — (n + 1) / 2n of the cell's score
+area with n = block / c strips, where the whole-tile masked body (kept
+for plans with unequal blocks) computes all of it and masks half.
+``executed_score_share`` is that arithmetic for a whole call.  Dropout
+reuses the positional-hash mask, keyed by the global head index so each
+head draws an independent mask.
+
+Each kernel body is Python-unrolled over the heads of a cell and the
+strips of a diagonal cell, so ``_fwd`` and ``_bwd`` are jitted with
+everything but the arrays static: the layers of a model share one trace
+and one lowered function of each kernel.
 
 ``supported()`` gates callers: bf16/f16 only (f32 blocks blow the VMEM
 budget — those callers take the bhd path), D a sublane multiple, G*D a
@@ -48,11 +60,10 @@ from .flash_attention import (_NEG_INF, _SUB, _dropout_keep, _interpret,
 
 _LANES = 128
 # The estimator under-counts the compiler's score/prob temporaries; 13 MB
-# keeps the worst (dkdv) kernel clear of the 16 MB scoped-vmem limit
-# (G=12 at 512^2 blocks estimated 14.6 MB but compiled to 16.56 MB on
-# the round-2 toolchain).  The plans this picks — (512, 512, G=6) for
-# gpt2-small, G=4 for H16/D128 — compile under jax 0.9.0 / libtpu 0.0.34
-# on the v5e (chip_smoke.py, PR 21).
+# keeps the worst (dkdv) kernel clear of the 16 MB scoped-vmem limit.
+# The plans this picks — (512, 512, G=6) for gpt2-small, G=8 for H16/D64,
+# G=4 for H16/D128 — compile under jax 0.9.0 / libtpu 0.0.34 on the v5e
+# (chip_smoke.py; PR 21, and PR 27 with the strips).
 _VMEM_BUDGET = 13 * 2**20
 
 
@@ -60,19 +71,45 @@ def _tune_key(sq, skv, heads, dtype):
     return ("flash_packed_blocks", sq, skv, heads, jnp.dtype(dtype).itemsize)
 
 
-def _plan(sq, skv, heads, head_dim, dtype=jnp.bfloat16):
-    """Pick (block_q, block_kv, group) — block edges and heads-per-cell.
+def _strip_rows(block_q, block_kv):
+    """Rows of a diagonal cell's trapezoid strips, 0 for the whole tile.
 
-    Largest block edge wins (512 beat 256 by ~12% e2e on gpt2-small), then
-    the largest head group that keeps the worst-case (dkdv) cell inside
-    the scoped-VMEM budget: 4 double-buffered (b, G*D) input streams, two
-    (b, G*D) outputs, two (G, b, D) f32 accumulators, ~2 (b, b) f32
-    score/prob temporaries.  The autotune cache can override per shape."""
+    Strips need a square cell (then every diagonal-crossing cell is the
+    aligned ``qi == ki`` one) and are one lane tile tall: a strip's kv
+    extent is a lane dimension of its score tile, and 128 beat 256 in all
+    three kernels at both benchmark cells' shapes (v5e, jax 0.9.0,
+    PR 27; ms a call at b16 H16 D64 / b6 H16 D128, s1024, whole tile ->
+    256 -> 128: forward 1.565 -> 1.470 -> 1.413 / 0.499 -> 0.486 ->
+    0.479, dkdv 1.211 -> 1.034 -> 0.946 / 0.466 -> 0.400 -> 0.366, dq
+    0.934 -> 0.809 -> 0.753 / 0.392 -> 0.342 -> 0.319).  A 256-edge
+    cell would run two: its backward kernels gained as well, its
+    forward lost 9 % at s768 (b16 H16 D64) and the three together 3 %,
+    so under four strips a cell keeps its whole tile."""
+    if block_q == block_kv and block_q % _LANES == 0 \
+            and block_q >= 4 * _LANES:
+        return _LANES
+    return 0
+
+
+def _plan(sq, skv, heads, head_dim, dtype=jnp.bfloat16):
+    """Pick (block_q, block_kv, group, strip) — block edges, heads per
+    cell, and the rows of a diagonal cell's strips (``_strip_rows``).
+
+    Largest block edge wins, then the largest head group that keeps the
+    worst-case (dkdv) cell inside the scoped-VMEM budget: 4
+    double-buffered (b, G*D) input streams, two (b, G*D) outputs, two
+    (G, b, D) f32 accumulators, ~2 (b, b) f32 score/prob temporaries.
+    The strips' temporaries are (c, b) at most, but a cell under the
+    diagonal (and every cell of a non-causal call) still runs the whole
+    (b, b) tile, so the worst case — and with it every group this has
+    picked so far — stands.  The autotune cache can override
+    (block_q, block_kv, group) per shape; the strip always follows from
+    the blocks."""
     from ....core import autotune as _at
     cached = (_at.kernel_cache.get(_tune_key(sq, skv, heads, dtype))
               if _at.enabled() else None)
     if cached is not None:
-        return cached
+        return (*cached[:3], _strip_rows(*cached[:2]))
     isz = jnp.dtype(dtype).itemsize
 
     def est(b, g):
@@ -87,7 +124,7 @@ def _plan(sq, skv, heads, head_dim, dtype=jnp.bfloat16):
             continue
         for g in groups:
             if est(b, g) <= _VMEM_BUDGET:
-                return (b, b, g)
+                return (b, b, g, _strip_rows(b, b))
     return None
 
 
@@ -104,18 +141,142 @@ def supported(sq, skv, heads, head_dim, dtype) -> bool:
     return _plan(sq, skv, heads, head_dim, dtype) is not None
 
 
-def _causal_positions(qi, ki, bq, bkv, transposed=False):
-    if transposed:  # (block_kv, block_q) layouts (the dkdv kernel)
-        k_pos = ki * bkv + jax.lax.broadcasted_iota(
-            jnp.int32, (bkv, bq), 0)
-        q_pos = qi * bq + jax.lax.broadcasted_iota(
-            jnp.int32, (bkv, bq), 1)
-    else:
-        q_pos = qi * bq + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bkv), 0)
-        k_pos = ki * bkv + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bkv), 1)
+def executed_score_share(sq, skv, heads, head_dim, dtype, causal) -> float:
+    """Score elements each of the three kernels computes over
+    ``sq * skv``, from the plan: 1.0 non-causal; causal, every cell under
+    the diagonal whole, every diagonal-crossing cell whole or — with
+    strips of c rows in a b-edge cell — (b/c + 1) / (2 b/c) of it, cells
+    above the diagonal nothing.  0.5 is the causal least (what the
+    benchmark's roofline counts).  A static fact of a build, not a rate:
+    ``_statics`` tells it to the program observatory while a program that
+    holds the kernels is traced, and that build's record carries it
+    (``observability/programs.py note_kernel_fact``).  ValueError for a
+    shape no plan covers (``supported`` is False)."""
+    plan = _plan(sq, skv, heads, head_dim, dtype)
+    if plan is None:
+        raise ValueError(
+            f"no packed flash plan for sq={sq} skv={skv} heads={heads} "
+            f"head_dim={head_dim}: the kernels do not run this shape")
+    return _score_share(sq, skv, plan, causal)
+
+
+def _score_share(sq, skv, plan, causal):
+    if not causal:
+        return 1.0
+    bq, bkv, _, strip = plan
+    n = bq // strip if strip else 1
+    diag_share = (n + 1) / (2 * n)
+    done = 0.0
+    for qi in range(sq // bq):
+        last_q = qi * bq + bq - 1
+        for ki in range(skv // bkv):
+            if ki * bkv > last_q:
+                continue                    # above the diagonal: skipped
+            crosses = ki * bkv + bkv - 1 > last_q - bq
+            done += bq * bkv * (diag_share if crosses else 1.0)
+    return done / (sq * skv)
+
+
+def _positions(q0, k0, nq, nk, transposed=False):
+    """Global (q, k) positions of the (nq, nk) score tile whose first row
+    is q position ``q0`` and first column k position ``k0`` — or of its
+    (nk, nq) transpose (the dkdv kernel's layout)."""
+    shape = (nk, nq) if transposed else (nq, nk)
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                          1 if transposed else 0)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                          0 if transposed else 1)
     return q_pos, k_pos
+
+
+def _diag_cell(qi, ki, block_q, block_kv, strip, transposed=False):
+    """``(tiles, keeps)`` of a diagonal-crossing cell: the score tiles it
+    computes, static, and each tile's causal keep-mask.
+
+    A tile is ``(r0, rn, kn)`` — q rows ``[r0, r0 + rn)`` of the block
+    against its first ``kn`` kv columns — or, transposed (dkdv),
+    ``(k0, kc, q0)`` — kv rows ``[k0, k0 + kc)`` against the q columns
+    from ``q0`` to the block's end.  With ``strip`` = c (a square,
+    aligned cell: ``qi == ki``) the tiles are the block's c-row trapezoid
+    strips, each reaching exactly as far as its rows can see, and the
+    mask is the one (c, c) triangle every strip carries on the sub-tile
+    at its diagonal end; without, the one whole tile and its mask from
+    global positions."""
+    if not strip:
+        q_pos, k_pos = _positions(qi * block_q, ki * block_kv, block_q,
+                                  block_kv, transposed)
+        return [(0, block_kv if transposed else block_q,
+                 0 if transposed else block_kv)], [q_pos >= k_pos]
+    q_loc, k_loc = _positions(0, 0, strip, strip, transposed)
+    tri = q_loc >= k_loc
+    tiles = [(r * strip, strip, r * strip if transposed else (r + 1) * strip)
+             for r in range(block_q // strip)]
+    return tiles, [tri] * len(tiles)
+
+
+def _dot(a, b, b_dim):
+    """``a`` (rows, K) times ``b`` contracted over its dim ``b_dim``
+    (1: b is (cols, K), no transpose exists — current Mosaic takes (1,1)
+    bf16 contractions natively), accumulated in float32."""
+    return jax.lax.dot_general(a, b, (((1,), (b_dim,)), ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=_prec(a.dtype))
+
+
+def _lane_fold(x, combine):
+    """(rows, k * 128) -> (rows, 128): the lane tiles of ``x`` combined
+    elementwise (VPU work); ``x`` itself at any other width."""
+    if x.shape[1] % _LANES:
+        return x
+    return functools.reduce(combine, [x[:, i:i + _LANES] for i in
+                                      range(0, x.shape[1], _LANES)])
+
+
+def _row_reduce(parts, reduce):
+    """``reduce`` along the rows of each part, the parts' rows stacked
+    into one column.  Lane-folded parts (``_lane_fold``) are stacked
+    first and reduced across lanes ONCE, as one (rows, 128) array: a
+    diagonal cell's strips then cost the cross-lane unit what one tile
+    does (the reductions are a quarter of the forward: PERF.md §6).  One
+    part alone is reduced as it is."""
+    if len(parts) > 1 and all(p.shape[1] == _LANES for p in parts):
+        return reduce(jnp.concatenate(parts, axis=0), axis=1, keepdims=True)
+    cols = [reduce(p, axis=1, keepdims=True) for p in parts]
+    return cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=0)
+
+
+def _select_edge(keep, x, fill, leading=False):
+    """``where(keep, x, fill)`` on the ``keep.shape[1]`` columns at the
+    trailing (or leading) edge of ``x``; the rest of ``x`` passes
+    untouched.  A strip's split falls on a lane-tile boundary (strips are
+    128-multiples), so the select pass runs on the diagonal sub-tile
+    alone."""
+    w = keep.shape[1]
+    if w == x.shape[1]:
+        return jnp.where(keep, x, fill)
+    if leading:
+        return jnp.concatenate(
+            [jnp.where(keep, x[:, :w], fill), x[:, w:]], axis=1)
+    return jnp.concatenate(
+        [x[:, :-w], jnp.where(keep, x[:, -w:], fill)], axis=1)
+
+
+def _run_cells(causal, diag, full, body, diag_cell, whole):
+    """Dispatch a grid cell to its body: diagonal-crossing cells run
+    ``diag_cell()``'s tiles under their masks, cells under the diagonal
+    (and every cell of a non-causal call) the one whole tile unmasked,
+    cells above it nothing."""
+    if not causal:
+        body([whole], [None])
+        return
+
+    @pl.when(diag)
+    def _run_diag():
+        body(*diag_cell())
+
+    @pl.when(full)
+    def _run_full():
+        body([whole], [None])
 
 
 # ---------------------------------------------------------------------------
@@ -124,7 +285,7 @@ def _causal_positions(qi, ki, bq, bkv, transposed=False):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, sm_scale, causal, block_q,
-                block_kv, n_kv, group, heads, head_dim, dropout_p):
+                block_kv, strip, n_kv, group, heads, head_dim, dropout_p):
     bi = pl.program_id(0)
     gi = pl.program_id(1)
     qi = pl.program_id(2)
@@ -137,72 +298,79 @@ def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, o_ref, lse_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _body(masked):
-        # VPU passes over the (block_q, block_kv) tile are the kernel's
-        # critical path (the d=64 dots leave the MXU mostly idle), so the
-        # softmax touches the full tile as few times as possible:
-        # sm_scale is folded into the small (block, D) q slice (exact for
-        # power-of-two 1/sqrt(D)), and the causal mask + iotas exist only
-        # on diagonal-crossing cells (``masked``) — strictly-lower cells
-        # skip them entirely.  Diag cells mask BEFORE the running max (a
-        # raw-block max could be inflated by a masked outlier logit,
-        # underflowing every valid probability in the row).
+    def _body(tiles, keeps):
+        # VPU passes over the score tile are the kernel's critical path
+        # (time per tile is the same at D=64 and D=128: PERF.md §5), so
+        # the softmax touches as few score elements as few times as
+        # possible: sm_scale is folded into the small (rows, D) q slice
+        # (exact for power-of-two 1/sqrt(D)); a diagonal cell computes
+        # only the strips' trapezoid (``_diag_cell``); the causal select
+        # runs on diagonal sub-tiles only — cells under the diagonal skip
+        # it entirely.  Masking comes BEFORE the running max (a raw-block
+        # max could be inflated by a masked outlier logit, underflowing
+        # every valid probability in the row).
         qb = q_ref[0]                            # (block_q, G*D)
         kb = k_ref[0]                            # (block_kv, G*D)
         vb = v_ref[0]
-        if masked or dropout_p > 0.0:
-            q_pos, k_pos = _causal_positions(qi, ki, block_q, block_kv)
-        if masked:
-            causal_keep = q_pos >= k_pos         # bool; the i32 iotas die here
+        pos = [_positions(qi * block_q + r0, ki * block_kv, rn, kn)
+               if dropout_p > 0.0 else None for r0, rn, kn in tiles]
         for h in range(group):
-            q = (qb[:, h * D:(h + 1) * D] *
-                 jnp.asarray(sm_scale, qb.dtype))
-            k = kb[:, h * D:(h + 1) * D]
-            v = vb[:, h * D:(h + 1) * D]
-            # contract over d of BOTH operands directly — current Mosaic
-            # takes (1,1) bf16 contractions natively, no register transpose
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32,
-                                    precision=_prec(q.dtype))
-            if masked:
-                s = jnp.where(causal_keep, s, _NEG_INF)
-            # stats live transposed (8, block_q); work in (block_q, 1)
-            m_prev = jnp.swapaxes(m_ref[h], 0, 1)[:, :1]
-            l_prev = jnp.swapaxes(l_ref[h], 0, 1)[:, :1]
-            m_cur = jnp.max(s, axis=1, keepdims=True)
-            m_next = jnp.maximum(m_prev, m_cur)          # (block_q, 1)
-            alpha = jnp.exp(m_prev - m_next)
-            p = jnp.exp(s - m_next)
-            l_next = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-            if dropout_p > 0.0:
-                keep = _dropout_keep(seed_ref[0],
-                                     bi * heads + gi * group + h,
-                                     q_pos, k_pos, 1.0 - dropout_p)
-                p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
-            pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32,
-                                     precision=_prec(v.dtype))
-            acc_ref[h] = acc_ref[h] * alpha + pv
+            q_h = qb[:, h * D:(h + 1) * D] * jnp.asarray(sm_scale, qb.dtype)
+            k_h = kb[:, h * D:(h + 1) * D]
+            v_h = vb[:, h * D:(h + 1) * D]
+            # two phases a head, each over all the tiles: every strip's
+            # score matmul and folded row maximum, then every strip's
+            # exponential, folded row sum and PV matmul.  A strip run end
+            # to end by itself pays the whole matmul -> reduce -> exp ->
+            # matmul latency chain (0.3-0.4 us a strip, PERF.md §6).  A
+            # whole tile has nothing to fold: its ops are the ones it
+            # always ran, in their order
+            fold = _lane_fold if len(tiles) > 1 else (lambda x, _: x)
+            scores, m_cur = [], []
+            for (r0, rn, kn), keep in zip(tiles, keeps):
+                s = _dot(q_h[r0:r0 + rn], k_h[:kn], 1)
+                if keep is not None:
+                    s = _select_edge(keep, s, _NEG_INF)
+                scores.append(s)
+                m_cur.append(fold(s, jnp.maximum))
+            # stats live transposed (8, block_q); work in (rows, 1).  One
+            # transpose each way a head, whatever the tiles: the tiles'
+            # rows partition the block in order, so their columns of
+            # statistics are slices of, and concatenate back to, the
+            # block's (a narrow transpose costs as much as a wide one).
+            # Read AFTER the score matmuls: read before them, a 256-edge
+            # cell's forward was 12 % slower at s768 (PERF.md §6)
+            m_old = jnp.swapaxes(m_ref[h], 0, 1)[:, :1]
+            l_old = jnp.swapaxes(l_ref[h], 0, 1)[:, :1]
+            m_next = jnp.maximum(m_old, _row_reduce(m_cur, jnp.max))
+            alpha = jnp.exp(m_old - m_next)              # (block_q, 1)
+            l_cur, pv = [], []
+            for (r0, rn, kn), s, qk_pos in zip(tiles, scores, pos):
+                p = jnp.exp(s - m_next[r0:r0 + rn])
+                l_cur.append(fold(p, jnp.add))
+                if dropout_p > 0.0:
+                    drop_keep = _dropout_keep(seed_ref[0],
+                                              bi * heads + gi * group + h,
+                                              *qk_pos, 1.0 - dropout_p)
+                    p = jnp.where(drop_keep, p / (1.0 - dropout_p), 0.0)
+                pv.append(_dot(p.astype(v_h.dtype), v_h[:kn], 0))
+            l_next = l_old * alpha + _row_reduce(l_cur, jnp.sum)
+            acc_ref[h] = acc_ref[h] * alpha + (
+                pv[0] if len(pv) == 1 else jnp.concatenate(pv, axis=0))
             m_ref[h] = jnp.swapaxes(
                 jnp.broadcast_to(m_next, (block_q, _SUB)), 0, 1)
             l_ref[h] = jnp.swapaxes(
                 jnp.broadcast_to(l_next, (block_q, _SUB)), 0, 1)
 
-    if causal:
-        last_q = qi * block_q + block_q - 1
-        diag = (ki * block_kv <= last_q) & \
-            (ki * block_kv + block_kv - 1 > last_q - block_q)
-
-        @pl.when(diag)
-        def _run_diag():
-            _body(True)
-
-        @pl.when(ki * block_kv + block_kv - 1 <= last_q - block_q)
-        def _run_full():
-            _body(False)
-    else:
-        _body(False)
+    last_q = qi * block_q + block_q - 1
+    _run_cells(
+        causal,
+        diag=(ki * block_kv <= last_q) &
+        (ki * block_kv + block_kv - 1 > last_q - block_q),
+        full=ki * block_kv + block_kv - 1 <= last_q - block_q,
+        body=_body,
+        diag_cell=lambda: _diag_cell(qi, ki, block_q, block_kv, strip),
+        whole=(0, block_q, block_kv))
 
     @pl.when(ki == n_kv - 1)
     def _finish():
@@ -219,7 +387,7 @@ def _kv_idx_packed(causal, bq, bkv, n_kv, part, n_groups):
     """kv index map into the packed (b, s, 3*H*D) qkv array, in G*D-lane
     block units: ``part`` selects q (0), k (1) or v (2); the group grid
     index picks the lane block within the part; causal clamps to the
-    diagonal so masked cells elide their DMA."""
+    diagonal so cells above it elide their DMA."""
     if not causal:
         return lambda b, g, i, j: (b, j, part * n_groups + g)
 
@@ -229,24 +397,27 @@ def _kv_idx_packed(causal, bq, bkv, n_kv, part, n_groups):
     return idx
 
 
-def _fwd(qkv, heads, causal, sm_scale, dropout_p=0.0, seed=None,
-         _blocks=None):
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "causal", "sm_scale", "dropout_p", "plan", "interpret"))
+def _fwd(qkv, seed, *, heads, causal, sm_scale, dropout_p, plan, interpret):
+    """``(out, lse)``.  Jitted with everything but the arrays static, so
+    a model's layers — same shapes, same statics — share ONE trace of
+    the kernel body and one lowered function (the Python-unrolled body
+    is the dearest thing a step's trace holds: PERF.md §6, PR 27)."""
     from jax.experimental.pallas import tpu as pltpu
     b, sq, hd3 = qkv.shape
     hd = hd3 // 3
     D = hd // heads
     skv = sq
-    bq, bkv, G = _blocks or _plan(sq, skv, heads, D, qkv.dtype)
+    bq, bkv, G, strip = plan
     n_q, n_kv = sq // bq, skv // bkv
     n_g = heads // G
     gd = G * D
 
-    if seed is None:
-        seed = jnp.zeros((1,), jnp.int32)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
-        block_kv=bkv, n_kv=n_kv, group=G, heads=heads, head_dim=D,
-        dropout_p=dropout_p)
+        block_kv=bkv, strip=strip, n_kv=n_kv, group=G, heads=heads,
+        head_dim=D, dropout_p=dropout_p)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, n_g, n_q, n_kv),
@@ -272,7 +443,7 @@ def _fwd(qkv, heads, causal, sm_scale, dropout_p=0.0, seed=None,
             pltpu.VMEM((G, _SUB, bq), jnp.float32),    # m (transposed)
             pltpu.VMEM((G, _SUB, bq), jnp.float32),    # l (transposed)
         ],
-        interpret=_interpret(),
+        interpret=interpret,
         name="flash_packed_fwd",
     )(qkv, qkv, qkv, seed)
     return out, lse
@@ -280,8 +451,8 @@ def _fwd(qkv, heads, causal, sm_scale, dropout_p=0.0, seed=None,
 
 def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                      delta_ref, seed_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                     *, sm_scale, causal, block_q, block_kv, n_q, group,
-                     heads, head_dim, dropout_p):
+                     *, sm_scale, causal, block_q, block_kv, strip, n_q,
+                     group, heads, head_dim, dropout_p):
     bi = pl.program_id(0)
     gi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -293,69 +464,79 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _body(masked):
+    def _body(tiles, keeps):
         # VPU economy (see _fwd_kernel): sm_scale folded into the q slice
         # (st lands in lse space; the same scaled q also serves the dk dot,
         # since dk = pt*(dpt-delta) . q*scale), causal select after the
-        # exp, diagonal-crossing cells only
+        # exp and on diagonal sub-tiles only; scores are transposed, so a
+        # diagonal cell's strips are kv rows against the q columns from
+        # their own position to the block's end
         qb = q_ref[0]                            # (block_q, G*D)
         kb = k_ref[0]                            # (block_kv, G*D)
         vb = v_ref[0]
         dob = do_ref[0]
-        if masked or dropout_p > 0.0:
-            q_pos_t, k_pos_t = _causal_positions(
-                qi, ki, block_q, block_kv, transposed=True)
-        if masked:
-            causal_keep = q_pos_t >= k_pos_t
+        pos = [_positions(qi * block_q + q0, ki * block_kv + k0,
+                          block_q - q0, kc, transposed=True)
+               if dropout_p > 0.0 else None for k0, kc, q0 in tiles]
         for h in range(group):
-            q = (qb[:, h * D:(h + 1) * D] *
-                 jnp.asarray(sm_scale, qb.dtype))
-            k = kb[:, h * D:(h + 1) * D]
-            v = vb[:, h * D:(h + 1) * D]
-            do = dob[:, h * D:(h + 1) * D]
-            lse = lse_ref[0, h][:1, :]           # (1, block_q)
-            delta = delta_ref[0, h][:1, :]
-            st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32,
-                                     precision=_prec(k.dtype))
-            pt = jnp.exp(st - lse)
-            if masked:
-                pt = jnp.where(causal_keep, pt, 0.0)
-            pt_v = pt
-            if dropout_p > 0.0:
-                keep = _dropout_keep(seed_ref[0],
-                                     bi * heads + gi * group + h,
-                                     q_pos_t, k_pos_t, 1.0 - dropout_p)
-                pt_v = jnp.where(keep, pt / (1.0 - dropout_p), 0.0)
-            dv_acc[h] += jax.lax.dot_general(
-                pt_v.astype(v.dtype), do, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=_prec(v.dtype))
-            dpt = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32,
-                                      precision=_prec(v.dtype))
-            if dropout_p > 0.0:
-                dpt = jnp.where(keep, dpt / (1.0 - dropout_p), 0.0)
-            dst = pt * (dpt - delta)
-            dk_acc[h] += jax.lax.dot_general(
-                dst.astype(k.dtype), q, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=_prec(k.dtype))
+            q_h = (qb[:, h * D:(h + 1) * D] *
+                   jnp.asarray(sm_scale, qb.dtype))
+            do_h = dob[:, h * D:(h + 1) * D]
+            k_h = kb[:, h * D:(h + 1) * D]
+            v_h = vb[:, h * D:(h + 1) * D]
 
-    if causal:
-        first_k = ki * block_kv
-        diag = (qi * block_q + block_q - 1 >= first_k) & \
-            (qi * block_q < first_k + block_kv)
+            def score(tile):
+                k0, kc, q0 = tile
+                return _dot(k_h[k0:k0 + kc], q_h[q0:], 1)
 
-        @pl.when(diag)
-        def _run_diag():
-            _body(True)
+            def dprob(tile):
+                k0, kc, q0 = tile
+                return _dot(v_h[k0:k0 + kc], do_h[q0:], 1)
 
-        @pl.when(qi * block_q >= first_k + block_kv)
-        def _run_full():
-            _body(False)
-    else:
-        _body(False)
+            def accumulate(tile, keep, qk_pos, st, dpt=None):
+                k0, kc, q0 = tile
+                rows = slice(k0, k0 + kc)
+                q, do = q_h[q0:], do_h[q0:]
+                lse = lse_ref[0, h, :1, q0:]     # (1, q columns)
+                delta = delta_ref[0, h, :1, q0:]
+                pt = jnp.exp(st - lse)
+                if keep is not None:
+                    pt = _select_edge(keep, pt, 0.0, leading=True)
+                pt_v = pt
+                if dropout_p > 0.0:
+                    drop_keep = _dropout_keep(seed_ref[0],
+                                              bi * heads + gi * group + h,
+                                              *qk_pos, 1.0 - dropout_p)
+                    pt_v = jnp.where(drop_keep, pt / (1.0 - dropout_p), 0.0)
+                dv_acc[h, rows] += _dot(pt_v.astype(v_h.dtype), do, 0)
+                if dpt is None:
+                    dpt = dprob(tile)
+                if dropout_p > 0.0:
+                    dpt = jnp.where(drop_keep, dpt / (1.0 - dropout_p), 0.0)
+                dst = pt * (dpt - delta)
+                dk_acc[h, rows] += _dot(dst.astype(k_h.dtype), q, 0)
+
+            if len(tiles) == 1:
+                accumulate(tiles[0], keeps[0], pos[0], score(tiles[0]))
+                continue
+            # strips: every strip's two score-shaped products first, then
+            # every strip's exponential and accumulating matmuls (the
+            # forward's two phases; a whole tile is fastest in the order
+            # above, its dprob matmul between its two accumulations)
+            done = [(score(t), dprob(t)) for t in tiles]
+            for t, keep, qk_pos, sd in zip(tiles, keeps, pos, done):
+                accumulate(t, keep, qk_pos, *sd)
+
+    first_k = ki * block_kv
+    _run_cells(
+        causal,
+        diag=(qi * block_q + block_q - 1 >= first_k) &
+        (qi * block_q < first_k + block_kv),
+        full=qi * block_q >= first_k + block_kv,
+        body=_body,
+        diag_cell=lambda: _diag_cell(qi, ki, block_q, block_kv, strip,
+                                     transposed=True),
+        whole=(0, block_kv, 0))
 
     @pl.when(qi == n_q - 1)
     def _finish():
@@ -366,7 +547,8 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    seed_ref, dq_ref, dq_acc, *, sm_scale, causal, block_q,
-                   block_kv, n_kv, group, heads, head_dim, dropout_p):
+                   block_kv, strip, n_kv, group, heads, head_dim,
+                   dropout_p):
     bi = pl.program_id(0)
     gi = pl.program_id(1)
     qi = pl.program_id(2)
@@ -377,61 +559,72 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def _body(masked):
+    def _body(tiles, keeps):
         # same VPU economy as the forward: sm_scale folded into the small
         # q slice (s lands in lse space directly) and into the k slice of
         # the final dot (dq = p*(dp-delta) . k*scale); the causal select
-        # runs on p AFTER the exp and only on diagonal-crossing cells
+        # runs on p AFTER the exp and on diagonal sub-tiles only; a
+        # diagonal cell runs the forward's strips
         qb = q_ref[0]
         kb = k_ref[0]
         vb = v_ref[0]
         dob = do_ref[0]
-        if masked or dropout_p > 0.0:
-            q_pos, k_pos = _causal_positions(qi, ki, block_q, block_kv)
-        if masked:
-            causal_keep = q_pos >= k_pos
+        pos = [_positions(qi * block_q + r0, ki * block_kv, rn, kn)
+               if dropout_p > 0.0 else None for r0, rn, kn in tiles]
         for h in range(group):
             scale = jnp.asarray(sm_scale, qb.dtype)
-            q = qb[:, h * D:(h + 1) * D] * scale
-            k = kb[:, h * D:(h + 1) * D]
-            v = vb[:, h * D:(h + 1) * D]
-            do = dob[:, h * D:(h + 1) * D]
-            lse = jnp.swapaxes(lse_ref[0, h], 0, 1)[:, :1]   # (block_q, 1)
-            delta = jnp.swapaxes(delta_ref[0, h], 0, 1)[:, :1]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32,
-                                    precision=_prec(q.dtype))
-            p = jnp.exp(s - lse)
-            if masked:
-                p = jnp.where(causal_keep, p, 0.0)
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32,
-                                     precision=_prec(do.dtype))
-            if dropout_p > 0.0:
-                keep = _dropout_keep(seed_ref[0],
-                                     bi * heads + gi * group + h,
-                                     q_pos, k_pos, 1.0 - dropout_p)
-                dp = jnp.where(keep, dp / (1.0 - dropout_p), 0.0)
-            ds = p * (dp - delta)
-            dq_acc[h] += jax.lax.dot_general(
-                ds.astype(k.dtype), k * scale, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=_prec(k.dtype))
+            k_h = kb[:, h * D:(h + 1) * D]
+            # one transpose a head, sliced by the tiles (see _fwd_kernel)
+            lse_h = jnp.swapaxes(lse_ref[0, h], 0, 1)[:, :1]  # (block_q, 1)
+            delta_h = jnp.swapaxes(delta_ref[0, h], 0, 1)[:, :1]
+            q_h = qb[:, h * D:(h + 1) * D] * scale
+            v_h = vb[:, h * D:(h + 1) * D]
+            do_h = dob[:, h * D:(h + 1) * D]
 
-    if causal:
-        last_q = qi * block_q + block_q - 1
-        diag = (ki * block_kv <= last_q) & \
-            (ki * block_kv + block_kv - 1 > last_q - block_q)
+            def score(tile):
+                r0, rn, kn = tile
+                return _dot(q_h[r0:r0 + rn], k_h[:kn], 1)
 
-        @pl.when(diag)
-        def _run_diag():
-            _body(True)
+            def dprob(tile):
+                r0, rn, kn = tile
+                return _dot(do_h[r0:r0 + rn], v_h[:kn], 1)
 
-        @pl.when(ki * block_kv + block_kv - 1 <= last_q - block_q)
-        def _run_full():
-            _body(False)
-    else:
-        _body(False)
+            def accumulate(tile, keep, qk_pos, s, dp=None, ks_h=None):
+                r0, rn, kn = tile
+                rows = slice(r0, r0 + rn)
+                p = jnp.exp(s - lse_h[rows])
+                if keep is not None:
+                    p = _select_edge(keep, p, 0.0)
+                if dp is None:
+                    dp = dprob(tile)
+                if dropout_p > 0.0:
+                    drop_keep = _dropout_keep(seed_ref[0],
+                                              bi * heads + gi * group + h,
+                                              *qk_pos, 1.0 - dropout_p)
+                    dp = jnp.where(drop_keep, dp / (1.0 - dropout_p), 0.0)
+                ds = p * (dp - delta_h[rows])
+                if ks_h is None:
+                    ks_h = k_h * scale
+                dq_acc[h, rows] += _dot(ds.astype(k_h.dtype), ks_h[:kn], 0)
+
+            if len(tiles) == 1:
+                accumulate(tiles[0], keeps[0], pos[0], score(tiles[0]))
+                continue
+            # strips in two phases, as in the dkdv kernel
+            done = [(score(t), dprob(t)) for t in tiles]
+            ks_h = k_h * scale
+            for t, keep, qk_pos, sd in zip(tiles, keeps, pos, done):
+                accumulate(t, keep, qk_pos, *sd, ks_h)
+
+    last_q = qi * block_q + block_q - 1
+    _run_cells(
+        causal,
+        diag=(ki * block_kv <= last_q) &
+        (ki * block_kv + block_kv - 1 > last_q - block_q),
+        full=ki * block_kv + block_kv - 1 <= last_q - block_q,
+        body=_body,
+        diag_cell=lambda: _diag_cell(qi, ki, block_q, block_kv, strip),
+        whole=(0, block_q, block_kv))
 
     @pl.when(ki == n_kv - 1)
     def _finish():
@@ -439,16 +632,17 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_ref[0, :, h * D:(h + 1) * D] = dq_acc[h].astype(dq_ref.dtype)
 
 
-def _bwd(heads, causal, sm_scale, dropout_p, res, do):
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "causal", "sm_scale", "dropout_p", "plan", "interpret"))
+def _bwd(qkv, out, lse, seed, do, *, heads, causal, sm_scale, dropout_p,
+         plan, interpret):
+    """The packed qkv cotangent ``(b, s, 3*H*D)``; jitted like ``_fwd``."""
     from jax.experimental.pallas import tpu as pltpu
-    qkv, out, lse, seed = res
-    if seed is None:
-        seed = jnp.zeros((1,), jnp.int32)
     b, sq, hd3 = qkv.shape
     hd = hd3 // 3
     D = hd // heads
     skv = sq
-    bq, bkv, G = _plan(sq, skv, heads, D, qkv.dtype)
+    bq, bkv, G, strip = plan
     n_q, n_kv = sq // bq, skv // bkv
     n_g = heads // G
     gd = G * D
@@ -477,8 +671,8 @@ def _bwd(heads, causal, sm_scale, dropout_p, res, do):
 
     dkdv = functools.partial(
         _bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
-        block_kv=bkv, n_q=n_q, group=G, heads=heads, head_dim=D,
-        dropout_p=dropout_p)
+        block_kv=bkv, strip=strip, n_q=n_q, group=G, heads=heads,
+        head_dim=D, dropout_p=dropout_p)
     dk, dv = pl.pallas_call(
         dkdv,
         grid=(b, n_g, n_kv, n_q),
@@ -505,14 +699,14 @@ def _bwd(heads, causal, sm_scale, dropout_p, res, do):
             pltpu.VMEM((G, bkv, D), jnp.float32),
             pltpu.VMEM((G, bkv, D), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
         name="flash_packed_bwd_dkdv",
     )(qkv, qkv, qkv, do, lse, delta_t, seed)
 
     dqk = functools.partial(
         _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
-        block_kv=bkv, n_kv=n_kv, group=G, heads=heads, head_dim=D,
-        dropout_p=dropout_p)
+        block_kv=bkv, strip=strip, n_kv=n_kv, group=G, heads=heads,
+        head_dim=D, dropout_p=dropout_p)
     dq = pl.pallas_call(
         dqk,
         grid=(b, n_g, n_q, n_kv),
@@ -532,17 +726,38 @@ def _bwd(heads, causal, sm_scale, dropout_p, res, do):
         out_specs=pl.BlockSpec((1, bq, gd), lambda bb, g, i, j: (bb, i, g)),
         out_shape=jax.ShapeDtypeStruct((b, sq, hd), qkv.dtype),
         scratch_shapes=[pltpu.VMEM((G, bq, D), jnp.float32)],
-        interpret=_interpret(),
+        interpret=interpret,
         name="flash_packed_bwd_dq",
     )(qkv, qkv, qkv, do, lse, delta_t, seed)
 
-    dqkv = jnp.concatenate([dq, dk, dv], axis=-1)   # (b, s, 3*H*D)
-    return (dqkv, None)                             # None: the int seed array
+    return jnp.concatenate([dq, dk, dv], axis=-1)   # (b, s, 3*H*D)
 
 
 # ---------------------------------------------------------------------------
 # custom_vjp wrapper
 # ---------------------------------------------------------------------------
+
+def _statics(qkv, heads, causal, sm_scale, dropout_p):
+    """The static arguments of ``_fwd`` / ``_bwd``: all of their trace's
+    key that is not a shape — the plan, and whether the kernels lower
+    interpreted (``_interpret()`` asks the backend, which a test or an
+    AOT-for-TPU lowering in a CPU process stands in for).  Runs whenever
+    a program that holds the kernels is traced, so it is also where the
+    build hears what share of the score square they compute."""
+    from ....observability.programs import note_kernel_fact
+    _, sq, hd3 = qkv.shape
+    plan = _plan(sq, sq, heads, hd3 // 3 // heads, qkv.dtype)
+    note_kernel_fact("executed_score_share",
+                     _score_share(sq, sq, plan, causal))
+    return dict(heads=heads, causal=causal, sm_scale=sm_scale,
+                dropout_p=dropout_p, plan=plan, interpret=_interpret())
+
+
+def _seed_array(seed):
+    """The kernels always take a (1,) int32 seed; without dropout they
+    never read it."""
+    return jnp.zeros((1,), jnp.int32) if seed is None else seed
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
 def flash_attention_packed(qkv, heads, causal, sm_scale, dropout_p=0.0,
@@ -553,13 +768,22 @@ def flash_attention_packed(qkv, heads, causal, sm_scale, dropout_p=0.0,
     output projection. ``seed`` is a (1,) int32 array, required when
     ``dropout_p > 0``.
     """
-    out, _ = _fwd(qkv, heads, causal, sm_scale, dropout_p, seed)
+    out, _ = _fwd(qkv, _seed_array(seed),
+                  **_statics(qkv, heads, causal, sm_scale, dropout_p))
     return out
 
 
 def _vjp_fwd(qkv, heads, causal, sm_scale, dropout_p=0.0, seed=None):
-    out, lse = _fwd(qkv, heads, causal, sm_scale, dropout_p, seed)
+    out, lse = _fwd(qkv, _seed_array(seed),
+                    **_statics(qkv, heads, causal, sm_scale, dropout_p))
     return out, (qkv, out, lse, seed)
 
 
-flash_attention_packed.defvjp(_vjp_fwd, _bwd)
+def _vjp_bwd(heads, causal, sm_scale, dropout_p, res, do):
+    qkv, out, lse, seed = res
+    dqkv = _bwd(qkv, out, lse, _seed_array(seed), do,
+                **_statics(qkv, heads, causal, sm_scale, dropout_p))
+    return (dqkv, None)                             # None: the int seed array
+
+
+flash_attention_packed.defvjp(_vjp_fwd, _vjp_bwd)

@@ -752,7 +752,8 @@ def instrument_jit(fn, site: str, registry: Optional[MetricRegistry] = None,
     :class:`programs.ProgramRegistry` — site label, build index,
     compile wall and where it went by jax's own build events
     (``trace_s``, ``lower_s``, ``backend_compile_s``, ``cache_hit``:
-    ``programs.read_build_clock``), signature, retrace cause, and (when
+    ``programs.read_build_clock``), what its kernels noted of themselves
+    (``programs.read_kernel_facts``), signature, retrace cause, and (when
     ``PHT_PROGRAM_ANALYSIS`` is armed) the AOT memory/cost harvest.
     The raw jitted function stays on ``wrapped._jit_fn`` (AOT
     lowering / HLO inspection)."""
@@ -793,6 +794,7 @@ def instrument_jit(fn, site: str, registry: Optional[MetricRegistry] = None,
         if grew:
             wall = time.perf_counter() - t0
             clock = _programs.read_build_clock()
+            kernel_facts = _programs.read_kernel_facts()
             builds.inc()
             seconds.observe(wall)
             prog.record_build(
@@ -800,7 +802,7 @@ def instrument_jit(fn, site: str, registry: Optional[MetricRegistry] = None,
                 compile_s=wall, t_end_ns=time.perf_counter_ns(),
                 registry=reg, labels=labels,
                 donated=getattr(fn, "_pht_donate_argnums", None),
-                build_clock=clock)
+                build_clock=clock, kernel_facts=kernel_facts)
         return out
 
     wrapped._jit_fn = fn

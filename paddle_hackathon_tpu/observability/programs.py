@@ -12,6 +12,9 @@ calls never touch it — with:
   to the existing ``jit_builds_total``), and where that wall went by
   jax's own build events: ``trace_s``, ``lower_s``,
   ``backend_compile_s`` and ``cache_hit`` (:func:`read_build_clock`);
+  and ``kernel_facts``, what the kernels traced into the program said
+  of themselves (:func:`note_kernel_fact`: the packed flash kernels'
+  ``executed_score_share``);
 - an abstract **call signature**: per-arg aval shape/dtype/weak_type,
   sharding spec when known, static-arg fingerprints and the donation
   map.  Signature capture is host-metadata-only (aval walks — never a
@@ -72,7 +75,8 @@ __all__ = ["ProgramRegistry", "get_program_registry", "capture_signature",
            "diff_signatures", "signature_from_spec_key", "program_analysis",
            "mosaic_kernels", "phase_census", "phase_counts",
            "PHASE_COMPONENTS", "PHASES", "start_build_clock",
-           "read_build_clock", "BUILD_CLOCK_KEYS",
+           "read_build_clock", "BUILD_CLOCK_KEYS", "note_kernel_fact",
+           "read_kernel_facts",
            "analysis_enabled", "observe_static_build",
            "observe_static_eviction", "COMPILES_LANE_TID",
            "HISTORY_PER_SITE"]
@@ -571,6 +575,7 @@ def start_build_clock() -> None:
         _listen_to_builds()
     _build_tls.spans = None
     _build_tls.cache_hit = False
+    _build_tls.facts = None
 
 
 def read_build_clock() -> dict:
@@ -592,6 +597,25 @@ def read_build_clock() -> dict:
     out = {k: round(v, 6) for k, v in out.items()}
     out["cache_hit"] = bool(getattr(_build_tls, "cache_hit", False))
     return out
+
+
+def note_kernel_fact(key: str, value) -> None:
+    """A static fact a kernel states about itself — what its plan makes
+    it compute, decided from shapes — said from the kernel's wrapper
+    while a program that holds it is traced.  The build record of that
+    program carries it (``kernel_facts``, :func:`read_kernel_facts`).
+    One set insertion a trace on this thread; nothing at run time."""
+    facts = getattr(_build_tls, "facts", None)
+    if facts is None:
+        facts = _build_tls.facts = {}
+    facts.setdefault(key, set()).add(value)
+
+
+def read_kernel_facts() -> Dict[str, list]:
+    """``{key: distinct values, sorted}`` of what this thread's kernels
+    noted since :func:`start_build_clock`; ``{}`` where none did."""
+    facts = getattr(_build_tls, "facts", None) or {}
+    return {k: sorted(v) for k, v in facts.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -645,7 +669,8 @@ class ProgramRegistry:
                      kind: str = "jit", registry=None,
                      labels: Optional[dict] = None,
                      donated=None,
-                     build_clock: Optional[dict] = None) -> dict:
+                     build_clock: Optional[dict] = None,
+                     kernel_facts: Optional[dict] = None) -> dict:
         """Record one trace+compile at ``site`` and return the build
         record.  Computes the signature (host metadata only) unless the
         caller already did, diffs it against the site's retained
@@ -655,7 +680,9 @@ class ProgramRegistry:
         ``build_clock`` is the caller's :func:`read_build_clock` of the
         call that built: ``trace_s``, ``lower_s``, ``backend_compile_s``
         and ``cache_hit`` go into the record beside ``compile_s`` (the
-        call's whole wall).  The harvest is timed as ``analysis_s`` and
+        call's whole wall), and ``kernel_facts``, where the call's trace
+        noted any (:func:`read_kernel_facts`), under that name beside the
+        analysis.  The harvest is timed as ``analysis_s`` and
         its own trace/lower/compile events as ``analysis_split``: they
         are the analysis pass's cost, not the build's."""
         sig = tuple(signature) if signature is not None \
@@ -687,6 +714,8 @@ class ProgramRegistry:
             record = {"build": n, "ts": now,
                       "compile_s": round(float(compile_s), 6), **timing,
                       "cause": cause, "analysis": analysis}
+            if kernel_facts:
+                record["kernel_facts"] = dict(kernel_facts)
             rec.history.append(record)
         self._emit(site, record, compile_s, t_end_ns, kind, registry, labels)
         return record

@@ -470,6 +470,170 @@ class TestPackedFlashAttention:
             a, H, True, 0.18, 0.3, seed).astype(jnp.float32) ** 2))(qkv)
         assert np.isfinite(np.asarray(g, np.float32)).all()
 
+    @staticmethod
+    def _with_plan(monkeypatch, plan):
+        from paddle_hackathon_tpu.incubate.nn.kernels import (
+            flash_attention_packed as fap)
+        monkeypatch.setattr(fap, "_plan", lambda *a, **k: plan)
+        return fap
+
+    @staticmethod
+    def _out_and_grad(fap, qkv, H, scale, dropout=0.0, seed=None):
+        import jax
+
+        def loss(a):
+            o = fap.flash_attention_packed(a, H, True, scale, dropout, seed)
+            return jnp.sum(o.astype(jnp.float32) ** 2), o
+        (_, out), grad = jax.value_and_grad(loss, has_aux=True)(qkv)
+        return (np.asarray(out, np.float32), np.asarray(grad, np.float32))
+
+    # (s, H, D, block, strip): diagonal cells with 1, 2 and 3 kv blocks a
+    # row, strips of a half and of a quarter of the block, both head dims
+    @pytest.mark.parametrize("S,H,D,block,strip", [
+        (128, 2, 64, 128, 32),
+        (512, 1, 64, 256, 128),     # lane-tile strips: the folded reductions
+        (384, 1, 128, 128, 32),
+    ])
+    def test_strips_match_whole_tile_and_reference(self, monkeypatch, S, H,
+                                                   D, block, strip):
+        """A diagonal cell run as trapezoid strips leaves out exactly
+        what the causal mask zeroes: forward and gradient agree with the
+        whole-tile masked body to rounding, and with float32 attention."""
+        import jax
+        rng = np.random.RandomState(S + D)
+        qkv = jnp.asarray(rng.randn(1, S, 3 * H * D) * 0.3, jnp.bfloat16)
+        scale = 1.0 / np.sqrt(D)
+        no_seed = jnp.zeros((1,), jnp.int32)
+        fap = self._with_plan(monkeypatch, (block, block, H, strip))
+        out, grad = self._out_and_grad(fap, qkv, H, scale)
+        _, lse = fap._fwd(qkv, no_seed, heads=H, causal=True, sm_scale=scale,
+                          dropout_p=0.0, plan=(block, block, H, strip),
+                          interpret=True)
+        fap = self._with_plan(monkeypatch, (block, block, H, 0))
+        out_w, grad_w = self._out_and_grad(fap, qkv, H, scale)
+        _, lse_w = fap._fwd(qkv, no_seed, heads=H, causal=True, sm_scale=scale,
+                            dropout_p=0.0, plan=(block, block, H, 0),
+                            interpret=True)
+        # float32 statistics to float32 rounding; bf16 results to an ulp
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_w),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(out, out_w, rtol=2 ** -7, atol=1e-3)
+        np.testing.assert_allclose(grad, grad_w, rtol=2 ** -7, atol=2e-3)
+        np.testing.assert_allclose(out, self._ref(qkv, H), rtol=0.05,
+                                   atol=0.02)
+
+        def ref_loss(a):
+            x = a.astype(jnp.float32)
+            q, k, v = (x[..., i * H * D:(i + 1) * H * D].reshape(
+                1, S, H, D).transpose(0, 2, 1, 3) for i in range(3))
+            sc = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+            sc = jnp.where(jnp.tril(jnp.ones((S, S), bool)), sc, -1e30)
+            o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(sc, -1), v)
+            return jnp.sum(o ** 2)
+        np.testing.assert_allclose(
+            grad, np.asarray(jax.grad(ref_loss)(qkv), np.float32),
+            rtol=0.1, atol=0.05)
+
+    def test_strips_keep_dropout_masks_in_step(self, monkeypatch):
+        """With strips the three kernels still draw one mask from (seed,
+        head, global q, global k): every result equals the whole-tile
+        body's, and the v-gradient is the forward's own linear map (the
+        output is linear in v, so <dv, e> = <w, out(v + e) - out(v)>)."""
+        rng = np.random.RandomState(7)
+        S, H, D = 256, 2, 64
+        qkv = jnp.asarray(rng.randn(1, S, 3 * H * D) * 0.3, jnp.bfloat16)
+        seed = jnp.asarray([4321], jnp.int32)
+        fap = self._with_plan(monkeypatch, (128, 128, H, 32))
+        out, grad = self._out_and_grad(fap, qkv, H, 0.125, 0.3, seed)
+        e = np.zeros(qkv.shape, np.float32)
+        e[..., 2 * H * D:] = rng.randint(-1, 2, (1, S, H * D)) * 0.25
+        shifted = (qkv.astype(jnp.float32) + e).astype(jnp.bfloat16)
+        out_e = np.asarray(fap.flash_attention_packed(
+            shifted, H, True, 0.125, 0.3, seed), np.float32)
+        fap = self._with_plan(monkeypatch, (128, 128, H, 0))
+        out_w, grad_w = self._out_and_grad(fap, qkv, H, 0.125, 0.3, seed)
+        np.testing.assert_allclose(out, out_w, rtol=2 ** -7, atol=1e-3)
+        np.testing.assert_allclose(grad, grad_w, rtol=2 ** -7, atol=4e-3)
+        assert (out != np.asarray(fap.flash_attention_packed(
+            qkv, H, True, 0.125), np.float32)).mean() > 0.5  # masks drawn
+        # d/dv of sum(out^2) along e, by the chain rule through out
+        np.testing.assert_allclose(
+            np.sum(grad * e), np.sum(2 * out * (out_e - out)), rtol=0.03)
+
+    def test_layers_share_one_trace_of_each_kernel(self, monkeypatch):
+        """The kernel bodies are Python-unrolled over heads and strips:
+        a stack of layers must trace each once, not once a layer."""
+        import jax
+        from paddle_hackathon_tpu.incubate.nn.kernels import (
+            flash_attention_packed as fap)
+        calls = {}
+        for name in ("_fwd_kernel", "_bwd_dkdv_kernel", "_bwd_dq_kernel"):
+            def counted(*a, _real=getattr(fap, name), _name=name, **k):
+                calls[_name] = calls.get(_name, 0) + 1
+                return _real(*a, **k)
+            monkeypatch.setattr(fap, name, counted)
+        H, D = 2, 64
+        scale = 0.1237          # a scale no other test's trace has cached
+
+        def loss(x):
+            for _ in range(4):
+                o = fap.flash_attention_packed(x, H, True, scale)
+                x = jnp.concatenate([o, o, o], -1)
+            return jnp.sum(x.astype(jnp.float32))
+        qkv = jax.ShapeDtypeStruct((1, 256, 3 * H * D), jnp.bfloat16)
+        jax.jit(jax.grad(loss)).trace(qkv)
+        assert calls == {"_fwd_kernel": 1, "_bwd_dkdv_kernel": 1,
+                         "_bwd_dq_kernel": 1}
+        # and one lowered function of each serves the four layers; the
+        # same shapes and statics lower compiled once the backend's
+        # answer changes (it is part of the trace's key)
+        monkeypatch.setattr(fap, "_interpret", lambda: False)
+        text = jax.jit(jax.grad(loss)).trace(qkv).lower(
+            lowering_platforms=("tpu",)).as_text()
+        import re
+        assert sorted(re.findall(r'kernel_name = "(\w+)"', text)) == [
+            "flash_packed_bwd_dkdv", "flash_packed_bwd_dq",
+            "flash_packed_fwd"]
+
+    def test_executed_score_share(self, monkeypatch):
+        from paddle_hackathon_tpu.incubate.nn.kernels import (
+            flash_attention_packed as fap)
+        bf16 = jnp.bfloat16
+        # both benchmark cells: 3 whole tiles of 4 before the strips; two
+        # of the three are diagonal and run (4 + 1) / 8 of their tile
+        for heads, d in ((16, 64), (16, 128)):
+            assert fap._plan(1024, 1024, heads, d, bf16)[:2] == (512, 512)
+            assert fap.executed_score_share(
+                1024, 1024, heads, d, bf16, True) == 0.5625
+            assert fap.executed_score_share(
+                1024, 1024, heads, d, bf16, False) == 1.0
+        assert fap.executed_score_share(512, 512, 12, 64, bf16, True) \
+            == 0.625                                    # all diagonal
+        assert fap.executed_score_share(4096, 4096, 12, 64, bf16, True) \
+            == (28 + 8 * 0.625) / 64
+        # a plan the strips do not cover reads what the whole-tile body
+        # runs: every cell the diagonal touches, whole
+        assert fap._score_share(1024, 1024, (512, 512, 8, 0), True) == 0.75
+        assert fap._score_share(1024, 1024, (256, 512, 8, 0), True) == 0.75
+        assert fap._score_share(1024, 1024, (512, 256, 8, 0), True) == 0.75
+        assert fap._strip_rows(512, 256) == 0 == fap._strip_rows(128, 128)
+        assert fap._strip_rows(256, 256) == 0       # under four strips
+        assert fap._strip_rows(512, 512) == 128
+        # the autotune cache's three-number override gets its strip rows
+        # from the same rule
+        from paddle_hackathon_tpu.core import autotune as at
+        monkeypatch.setattr(at, "enabled", lambda: True)
+        monkeypatch.setattr(at, "kernel_cache", at.AutoTuneCache())
+        at.kernel_cache.put(fap._tune_key(1024, 1024, 16, bf16),
+                            (256, 512, 8))
+        assert fap._plan(1024, 1024, 16, 64, bf16) == (256, 512, 8, 0)
+        assert fap.executed_score_share(1024, 1024, 16, 64, bf16, True) \
+            == 0.75
+        # a shape no plan covers has no share to state
+        assert not fap.supported(1000, 1000, 3, 8, bf16)
+        with pytest.raises(ValueError, match="no packed flash plan"):
+            fap.executed_score_share(1000, 1000, 3, 8, bf16, True)
+
     def test_supported_gates(self):
         from paddle_hackathon_tpu.incubate.nn.kernels import (
             flash_attention_packed as fap)

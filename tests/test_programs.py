@@ -742,3 +742,38 @@ def test_instrumented_jit_build_record_and_compile_span_carry_the_clock():
     (attrs,) = [a for n, a in spans if n == f"compile:{site}"]
     assert {k: attrs[k] for k in programs.BUILD_CLOCK_KEYS} \
         == {k: rec[k] for k in programs.BUILD_CLOCK_KEYS}
+
+
+def test_build_record_carries_what_its_kernels_said_of_themselves():
+    """The packed flash kernels' wrapper states their executed score
+    share while the program is traced; the record of that build, and of
+    no other, carries it (``chip_smoke.py`` prints it for the trainer)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_hackathon_tpu.incubate.nn.kernels import (
+        flash_attention_packed as fap)
+    heads, d = 2, 64
+
+    def attend(causal):
+        return lambda x: jax.grad(lambda a: jnp.sum(
+            fap.flash_attention_packed(a, heads, causal, 0.125).astype(
+                jnp.float32)))(x)
+    qkv = jnp.ones((1, 512, 3 * heads * d), jnp.bfloat16)
+    want = fap.executed_score_share(512, 512, heads, d, qkv.dtype, True)
+    assert want == 0.625                    # one cell, four strips
+    reg = MetricRegistry()
+    site = _site("facts")
+    for causal in (True, False):            # two builds at one site
+        instrument_jit(jax.jit(attend(causal)), site=site, registry=reg)(qkv)
+    plain = _site("facts")
+    instrument_jit(jax.jit(jnp.tanh), site=plain, registry=reg)(qkv)
+    sites = get_program_registry().snapshot()["sites"]
+    assert [h["kernel_facts"] for h in sites[site]["history"]] == [
+        {"executed_score_share": [want]}, {"executed_score_share": [1.0]}]
+    assert "kernel_facts" not in sites[plain]["history"][0]
+    # facts die with the build they were said in
+    programs.note_kernel_fact("k", 2)
+    programs.note_kernel_fact("k", 1)
+    assert programs.read_kernel_facts() == {"k": [1, 2]}
+    programs.start_build_clock()
+    assert programs.read_kernel_facts() == {}
