@@ -104,9 +104,11 @@ class Controller:
             if rc == 0:
                 return 0
             if restarts >= self.ctx.args.max_restart:
+                failed = self.pod.first_failed
                 sys.stderr.write(
                     f"[launch] job failed (exit={rc}) after {restarts} "
-                    f"restarts; giving up\n")
+                    f"restarts; giving up.  Last lines of "
+                    f"{failed.out_path}:\n{failed.logs()}\n")
                 return rc
             restarts += 1
             sys.stderr.write(

@@ -30,7 +30,6 @@ class Container:
         self._proc: Optional[subprocess.Popen] = None
         self._out_f = None
         self._err_f = None
-        self.restarts = 0
 
     def start(self) -> None:
         d = os.path.dirname(self.out_path)
@@ -89,6 +88,9 @@ class Pod:
 
     def __init__(self):
         self.containers: List[Container] = []
+        # the container whose exit ended ``join`` (the others were killed
+        # after it, so their exit codes say nothing)
+        self.first_failed: Optional[Container] = None
 
     def add(self, c: Container) -> None:
         self.containers.append(c)
@@ -103,9 +105,6 @@ class Pod:
     def exit_codes(self) -> List[Optional[int]]:
         return [c.exit_code() for c in self.containers]
 
-    def failed(self) -> bool:
-        return any(rc not in (None, 0) for rc in self.exit_codes())
-
     def join(self, poll_interval: float = 0.2) -> int:
         """Wait until every essential container exits; on any failure stop
         the rest. Non-essential containers (PS servers) are stopped once the
@@ -119,10 +118,12 @@ class Pod:
             if essential and all(rc == 0 for rc in essential):
                 self.stop_graceful()  # reap the non-essential servers
                 return 0
-            bad = [rc for rc in self.exit_codes() if rc not in (None, 0)]
+            bad = [c for c in self.containers
+                   if c.exit_code() not in (None, 0)]
             if bad:
+                self.first_failed = bad[0]
                 self.stop(force=True)
-                return bad[0]
+                return bad[0].exit_code()
             if not essential and all(rc == 0 for rc in self.exit_codes()):
                 return 0
             time.sleep(poll_interval)
@@ -143,9 +144,3 @@ class Pod:
         for c in self.containers:
             if c.is_running():
                 c.terminate(force=True)
-
-    def restart(self) -> None:
-        self.stop(force=True)
-        for c in self.containers:
-            c.restarts += 1
-        self.deploy()

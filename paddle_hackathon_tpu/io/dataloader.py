@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Callable, Optional
 
 import numpy as np
@@ -299,6 +300,7 @@ def _proc_worker(dataset, collate_fn, worker_init_fn, wid, num_workers,
     _worker_info.info = WorkerInfo(wid, num_workers, dataset)
     if worker_init_fn is not None:
         worker_init_fn(wid)
+    data_q.put(("ready", wid, None))
     while True:
         task = task_q.get()
         if task is None:
@@ -335,6 +337,15 @@ def _proc_worker(dataset, collate_fn, worker_init_fn, wid, num_workers,
             data_q.put(("error", f"{type(e).__name__}: {e}\n"
                                  f"{traceback.format_exc(limit=8)}", None))
             return
+
+
+# A worker process says "ready" once, when it has been started, has
+# imported what its payload needs and has run ``worker_init_fn``.  One
+# that is alive and has not said so after this long never will (a child
+# forked from a threaded parent that blocks on an inherited lock, a
+# forkserver child stuck in an import): the parent raises instead of
+# polling for ever.
+_WORKER_START_TIMEOUT_S = 120.0
 
 
 class _ProcPrefetchIter:
@@ -431,6 +442,8 @@ class _ProcPrefetchIter:
             for wid in range(loader.num_workers)]
         for w in self.workers:
             w.start()
+        self._ready = set()
+        self._start_deadline = time.monotonic() + _WORKER_START_TIMEOUT_S
         while (self.next_task < self.n_tasks
                and self.next_task < self.max_outstanding):
             self._submit()
@@ -473,15 +486,16 @@ class _ProcPrefetchIter:
             try:
                 item = self.data_q.get(
                     timeout=timeout if timeout else 5.0)
-            except Exception:
+            except queue.Empty:
                 if timeout:
                     self.close()
                     raise RuntimeError(
                         f"DataLoader worker timed out after {timeout}s")
-                # a worker killed mid-task (OOM/segfault) never delivers
-                # its batch — waiting for the rest would hang forever
-                dead = [w for w in self.workers
-                        if w.exitcode not in (None, 0)]
+                # a worker that is gone mid-epoch (killed by OOM or a
+                # segfault, or exited by itself: the sentinels go out
+                # only after the last batch) never delivers its batch —
+                # waiting for the rest would hang forever
+                dead = [w for w in self.workers if w.exitcode is not None]
                 if dead:
                     codes = [w.exitcode for w in dead]
                     self.close()
@@ -489,11 +503,19 @@ class _ProcPrefetchIter:
                         f"DataLoader worker process(es) died "
                         f"(exitcode {codes}); their in-flight batches "
                         "are lost") from None
-                if not any(w.is_alive() for w in self.workers):
+                if (len(self._ready) < len(self.workers)
+                        and time.monotonic() > self._start_deadline):
+                    silent = [w.pid for wid, w in enumerate(self.workers)
+                              if wid not in self._ready]
                     self.close()
                     raise RuntimeError(
-                        "all DataLoader worker processes exited "
-                        "unexpectedly") from None
+                        f"DataLoader worker process(es) {silent} are alive "
+                        "but did not come up within "
+                        f"{_WORKER_START_TIMEOUT_S:g} s (stuck at start, "
+                        "in an import or in worker_init_fn)") from None
+                continue
+            if item[0] == "ready":
+                self._ready.add(item[1])
                 continue
             if item[0] == "error":
                 self.close()
@@ -523,21 +545,22 @@ class _ProcPrefetchIter:
             self.task_q.put(None)
         pending = list(self.results.values())
         self.results.clear()
-        import queue as _q
-        import time as _time
-        deadline = _time.monotonic() + 5.0
+        deadline = time.monotonic() + 5.0
         while (any(w.is_alive() for w in self.workers)
-               and _time.monotonic() < deadline):
+               and time.monotonic() < deadline):
             try:
                 item = self.data_q.get(timeout=0.1)
-            except _q.Empty:
+            except queue.Empty:
                 continue
             if item and not isinstance(item[0], str):
                 pending.append((item[1], item[2]))
         for w in self.workers:
             if w.is_alive():
                 w.terminate()
-            w.join()
+            w.join(5.0)
+            if w.is_alive():  # SIGTERM blocked or ignored
+                w.kill()
+                w.join(5.0)
         # final drain after join: everything the feeders flushed
         while True:
             try:

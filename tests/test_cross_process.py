@@ -34,6 +34,26 @@ pytestmark = pytest.mark.skipif(
 _REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 
+def _launch_two(script, log_dir, job):
+    """Two ranks through the launcher; returns (exit code, both logs).
+    A rank that fails is not restarted (the failure is the result), and
+    whatever ends the wait, the per-test limit included, carries what
+    the ranks had written: two processes that sit at their rendezvous
+    say nothing in the launcher's own stack."""
+    def logs():
+        return "".join(f"--- {f.name}\n{f.read_text()}"
+                       for f in sorted(log_dir.iterdir()))
+    try:
+        rc = launch(["--nproc_per_node", "2", "--max_restart", "0",
+                     "--log_dir", str(log_dir), "--job_id", job,
+                     str(script)])
+    except BaseException as e:
+        e.add_note("the ranks' logs:\n" + (logs() if log_dir.exists()
+                                            else "(none written)"))
+        raise
+    return rc, logs()
+
+
 @pytest.fixture(autouse=True)
 def _no_leaked_mesh():
     """The single-process references create pp meshes through
@@ -192,13 +212,9 @@ def test_two_process_checkpoint_save_then_resume(tmp_path):
         os.environ["CKPT_PHASE"] = phase
         os.environ["CKPT_PATH"] = ckpt
         try:
-            rc = launch(["--nproc_per_node", "2", "--log_dir",
-                         str(tmp_path / ("logs_" + phase)), "--job_id",
-                         job, str(script)])
+            rc, logs = _launch_two(script, tmp_path / ("logs_" + phase), job)
         finally:
             del os.environ["CKPT_PHASE"], os.environ["CKPT_PATH"]
-        logs = "".join(f.read_text()
-                       for f in (tmp_path / ("logs_" + phase)).iterdir())
         assert rc == 0, logs
         per_rank = {}
         for line in logs.splitlines():
@@ -243,10 +259,7 @@ def test_two_process_checkpoint_save_then_resume(tmp_path):
 def test_two_process_trainstep_matches_single_process(tmp_path):
     script = tmp_path / "dist_trainstep.py"
     script.write_text(textwrap.dedent(_WORKER))
-    rc = launch(["--nproc_per_node", "2", "--log_dir",
-                 str(tmp_path / "logs"), "--job_id", "xproc",
-                 str(script)])
-    logs = "".join(f.read_text() for f in (tmp_path / "logs").iterdir())
+    rc, logs = _launch_two(script, tmp_path / "logs", "xproc")
     assert rc == 0, logs
 
     per_rank = {}

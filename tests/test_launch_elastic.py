@@ -78,20 +78,58 @@ class TestLauncher:
         assert time.monotonic() - t0 < 60
 
     def test_ps_controller_topology(self, tmp_path):
+        # the launcher stops its servers as soon as every trainer has
+        # ended, and a real trainer cannot end before its servers are
+        # up: it talks to them.  These trainers wait for the servers'
+        # marks (at most 60 s); without that, a server that starts
+        # slowly on a loaded machine is stopped before its first print
+        up = tmp_path / "up"
+        up.mkdir()
         script = _write(tmp_path, "role.py", """
-            import os
+            import os, sys, time
             print(os.environ["PADDLE_ROLE"],
-                  os.environ["PADDLE_PSERVER_ENDPOINTS"])
+                  os.environ["PADDLE_PSERVER_ENDPOINTS"], flush=True)
+            if os.environ["PADDLE_ROLE"] == "PSERVER":
+                open(os.path.join(
+                    sys.argv[1], os.environ["PADDLE_SERVER_ID"]), "w").close()
+            else:
+                deadline = time.time() + 60
+                while (len(os.listdir(sys.argv[1])) < 2
+                       and time.time() < deadline):
+                    time.sleep(0.05)
         """)
         rc = launch(["--run_mode", "ps", "--server_num", "2",
                      "--trainer_num", "2",
                      "--log_dir", str(tmp_path / "logs"),
-                     "--job_id", "pstest", script])
+                     "--job_id", "pstest", script, str(up)])
         assert rc == 0
         logs = {f.name: f.read_text() for f in
                 sorted((tmp_path / "logs").iterdir())}
         roles = [v.split()[0] for v in logs.values() if v.strip()]
         assert roles.count("PSERVER") == 2 and roles.count("TRAINER") == 2
+
+    def test_a_server_that_dies_at_start_fails_the_job_with_its_log(
+            self, tmp_path, capfd):
+        """The launcher does not wait for trainers whose server is gone:
+        it stops what it started, returns the server's exit code, and
+        says what the server last wrote."""
+        script = _write(tmp_path, "role.py", """
+            import os, sys, time
+            if os.environ["PADDLE_ROLE"] == "PSERVER":
+                print("cannot bind: address already in use", flush=True)
+                sys.exit(5)
+            time.sleep(600)   # a trainer waiting for that server
+        """)
+        t0 = time.monotonic()
+        rc = launch(["--run_mode", "ps", "--server_num", "1",
+                     "--trainer_num", "1", "--max_restart", "0",
+                     "--log_dir", str(tmp_path / "logs"),
+                     "--job_id", "psdead", script])
+        assert rc == 5
+        assert time.monotonic() - t0 < 60
+        err = capfd.readouterr().err
+        assert "psdead.server0.log" in err
+        assert "cannot bind: address already in use" in err
 
     def test_make_controller_dispatch(self):
         ctx = Context(parse_args(["--run_mode", "ps", "--server_num", "1",

@@ -89,9 +89,8 @@ def test_repo_wide_lint_is_clean():
     """THE gate: zero unsuppressed findings across the package, tools
     and bench driver, and zero unused baseline entries (a fixed finding
     must take its suppression with it).  The same walk feeds the
-    --stats plumbing and the wall-time budget: the linter itself rides
-    the tier-1 suite, so rule growth must not silently blow the budget
-    (tier-1 already overruns 870s — tools/test_budget.py workflow)."""
+    --stats plumbing and the walk's own CPU budget below: the linter
+    rides the tier-1 suite, so a slow new rule is a test failure here."""
     stats = {}
     findings, suppressed, unused = run_lint(stats=stats)
     assert findings == [], "unsuppressed pht-lint findings:\n" + "\n".join(
@@ -439,7 +438,7 @@ def test_changed_paths_include_branch_commits(tmp_path):
 
     def git(*a):
         subprocess.run(["git", *a], cwd=tmp_path, check=True,
-                       capture_output=True)
+                       capture_output=True, timeout=60)
     git("init", "-b", "main")
     git("config", "user.email", "t@t")
     git("config", "user.name", "t")
@@ -462,7 +461,7 @@ def test_changed_paths_include_untracked_files(tmp_path):
 
     def git(*a):
         subprocess.run(["git", *a], cwd=tmp_path, check=True,
-                       capture_output=True)
+                       capture_output=True, timeout=60)
     git("init", "-b", "main")
     git("config", "user.email", "t@t")
     git("config", "user.name", "t")

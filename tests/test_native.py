@@ -14,6 +14,7 @@ import time
 
 import numpy as np
 import pytest
+from conftest import join_within
 
 from paddle_hackathon_tpu.core import native
 from paddle_hackathon_tpu.parallel.store import TCPStore
@@ -234,7 +235,7 @@ class TestTCPStore:
         t0 = time.time()
         assert store.get("late", timeout=10) == b"arrived"
         assert time.time() - t0 >= 0.15
-        t.join()
+        join_within([t], 30, "the setter thread")
         other.close()
         store.close()
 
@@ -303,7 +304,7 @@ def test_staging_ring_strict_order():
             break
         got.append(int(arr[0]))
         ring.release(slot)
-    t.join()
+    join_within([t], 30, "the ring's producer")
     assert got == list(range(8))
 
 

@@ -17,6 +17,7 @@ from paddle_hackathon_tpu.jit import InputSpec
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(ROOT, "paddle_hackathon_tpu", "native", "serving.cc")
+_GXX_S = 240  # one g++ -O2 run of serving.cc takes ~15 s alone
 
 CLIENT_CC = r"""
 // Pure-C++ serving client: no Python anywhere in this translation unit.
@@ -166,7 +167,7 @@ def native_bits(tmp_path_factory):
             ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", SRC,
              f"-I{inc}", f"-L{libdir}", f"-l{pyver}",
              f"-Wl,-rpath,{libdir}", "-o", so],
-            check=True, capture_output=True, text=True)
+            check=True, capture_output=True, text=True, timeout=_GXX_S)
     except (subprocess.CalledProcessError, FileNotFoundError) as e:
         pytest.skip(f"cannot build serving shim: {e}")
     client_src = tmp / "client.cc"
@@ -176,7 +177,7 @@ def native_bits(tmp_path_factory):
         ["g++", "-O2", "-std=c++17", str(client_src), so,
          f"-Wl,-rpath,{os.path.dirname(so)}", f"-Wl,-rpath,{libdir}",
          "-o", client],
-        check=True, capture_output=True, text=True)
+        check=True, capture_output=True, text=True, timeout=_GXX_S)
     return client, model + ".pdmodel", expect
 
 
@@ -232,7 +233,7 @@ def gen_bits(tmp_path_factory, native_bits):
         ["g++", "-O2", "-std=c++17", str(src), so, "-pthread",
          f"-Wl,-rpath,{os.path.dirname(so)}", f"-Wl,-rpath,{libdir}",
          "-o", client],
-        check=True, capture_output=True, text=True)
+        check=True, capture_output=True, text=True, timeout=_GXX_S)
     return client, mdir, expects
 
 

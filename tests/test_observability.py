@@ -3,7 +3,7 @@ serving + compiled-fit instrumentation, chrome-trace counter events,
 and the perf-gate recompilation tripwire.
 
 Lean by design: one tiny serving-engine run and one 2-step fit carry all
-the integration assertions (tier-1 runs near its 870 s budget)."""
+the integration assertions (tier-1 is compile-bound on the CPU)."""
 
 import json
 import os
@@ -12,6 +12,7 @@ import sys
 import threading
 
 import numpy as np
+from conftest import join_within
 
 import paddle_hackathon_tpu as paddle
 from paddle_hackathon_tpu import hapi, io, nn, optimizer as optim
@@ -174,8 +175,7 @@ def test_thread_safety_smoke():
     ts = [threading.Thread(target=work) for _ in range(4)]
     for t in ts:
         t.start()
-    for t in ts:
-        t.join()
+    join_within(ts, 60, "the metric writers")
     assert c.value == 4000
     assert h.count == 4000
 

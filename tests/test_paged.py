@@ -3,7 +3,7 @@ reservation regression, paged-vs-dense exact equivalence through the
 serving engine (greedy, speculative, mp-sharded), and the Pallas decode
 kernel's numerics under the interpreter.
 
-Lean by design (tier-1 overruns its 870s budget): the fast subset is the
+Lean by design (tier-1 is compile-bound on the CPU): the fast subset is the
 pure-numpy/jnp units plus the two acceptance-critical tiny-GPT engine
 runs (paged-vs-dense equivalence, prefix reuse); every other
 engine-compiling test (spec verify, mp sharding, admission backpressure,

@@ -251,89 +251,3 @@ def test_slo_scheduling_gate():
     # rows without the embedded evidence (every other suite row) skip
     assert perf_gate.compare_slo_scheduling(
         [{"metric": "x", "value": 1.0}]) == []
-
-
-# ---------------------------------------------------- tools/test_budget.py
-import test_budget  # noqa: E402  (tools/ already on sys.path above)
-
-_DUR_LOG = """\
-============================= slowest durations ==============================
-12.50s call     tests/test_parallel_trainstep.py::test_big
-2.00s call     tests/test_parallel_trainstep.py::test_small
-0.50s setup    tests/test_parallel_trainstep.py::test_big
-4.10s call     tests/test_lint.py::test_repo_wide
-1.00s call     tests/test_newfile.py::test_something
-30.00s call     tests/test_unbudgeted_heavy.py::test_x
-0.01s teardown tests/test_lint.py::test_repo_wide
-= 5 passed in 50.00s =
-"""
-
-
-def test_budget_parses_and_sums_per_file(tmp_path):
-    totals, saw = test_budget.measured_per_file(_DUR_LOG.splitlines())
-    assert saw
-    assert totals["test_parallel_trainstep.py"] == pytest.approx(15.0)
-    assert totals["test_lint.py"] == pytest.approx(4.11)
-
-
-def test_budget_flags_only_over_budget_files(tmp_path, capsys):
-    log = tmp_path / "d.log"
-    log.write_text(_DUR_LOG)
-    conftest = tmp_path / "conftest.py"
-    conftest.write_text(
-        "_FILE_COST = {'test_parallel_trainstep.py': 5,\n"
-        "              'test_lint.py': 12,\n"
-        "              'test_unbudgeted_heavy.py': 40}\n")
-    rc = test_budget.main([str(log), "--conftest", str(conftest)])
-    out = capsys.readouterr().out
-    # trainstep measured 15s vs 5s budget * 1.5 slack -> over; lint
-    # (4.1s vs 12s) and the heavy-but-budgeted file stay quiet
-    assert rc == 1
-    assert "OVER BUDGET: test_parallel_trainstep.py" in out
-    assert "test_lint.py" not in out.replace("note:", "")
-    # within budget -> rc 0
-    conftest.write_text("_FILE_COST = {'test_parallel_trainstep.py': 30,\n"
-                        "              'test_lint.py': 12,\n"
-                        "              'test_unbudgeted_heavy.py': 40}\n")
-    assert test_budget.main([str(log), "--conftest", str(conftest)]) == 0
-    capsys.readouterr()
-
-
-def test_budget_strict_fails_unbudgeted_heavy_files(tmp_path, capsys):
-    """A new heavy test file with NO _FILE_COST entry sorts mid-pack
-    blind — --strict turns that into a failure so the entry gets added
-    with the PR that added the file."""
-    log = tmp_path / "d.log"
-    log.write_text(_DUR_LOG)
-    conftest = tmp_path / "conftest.py"
-    conftest.write_text("_FILE_COST = {'test_parallel_trainstep.py': 30,\n"
-                        "              'test_lint.py': 12}\n")
-    assert test_budget.main([str(log), "--conftest", str(conftest)]) == 0
-    rc = test_budget.main([str(log), "--conftest", str(conftest),
-                           "--strict"])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "UNBUDGETED: test_unbudgeted_heavy.py" in out
-    # the 1s file stays under --min-seconds either way
-    assert "test_newfile.py" not in out
-
-
-def test_budget_usage_errors_are_exit_2(tmp_path, capsys):
-    assert test_budget.main([str(tmp_path / "missing.log")]) == 2
-    log = tmp_path / "empty.log"
-    log.write_text("no durations here\n")
-    assert test_budget.main([str(log)]) == 2
-    bad_conftest = tmp_path / "c.py"
-    bad_conftest.write_text("OTHER = 1\n")
-    log.write_text(_DUR_LOG)
-    assert test_budget.main([str(log), "--conftest",
-                             str(bad_conftest)]) == 2
-    capsys.readouterr()
-
-
-def test_budget_live_conftest_budgets_load():
-    """The real tests/conftest.py parses without importing jax, and the
-    tool's --help documents the DOTS_PASSED comparison workflow."""
-    budgets = test_budget.load_budgets(test_budget.DEFAULT_CONFTEST)
-    assert budgets.get("test_lint.py") and budgets.get("test_serving.py")
-    assert "DOTS_PASSED" in test_budget.__doc__

@@ -3,7 +3,7 @@ retrace-cause taxonomy, registry semantics, the instrument_jit fallback
 fix, to_static wiring, the /debug/programs endpoint, and the
 gate/report/dump surfaces.
 
-Lean by design (tier-1 runs near its 870 s budget): almost everything
+Lean by design (tier-1 is compile-bound on the CPU): almost everything
 here is pure-host — numpy callables through instrument_jit's
 signature-probe fallback, fake AOT handles for the analysis harvest —
 and the one test that really compiles (to_static) traces a scalar
@@ -18,6 +18,7 @@ import urllib.request
 
 import numpy as np
 import pytest
+from conftest import join_within
 
 import paddle_hackathon_tpu as paddle
 from paddle_hackathon_tpu.core import flags
@@ -162,8 +163,7 @@ def test_registry_thread_safety_under_lock_sanitizer():
                    for s in sites]
         for t in threads:
             t.start()
-        for t in threads:
-            t.join()
+        join_within(threads, 60, "the registry writers")
         snap = prog.snapshot()
         assert sum(s["builds"] for s in snap["sites"].values()) == 200
     sanitizers.reset_lock_graph()

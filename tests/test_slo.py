@@ -2,7 +2,7 @@
 request lifecycle record, the /load capacity report (golden schema),
 beacon GC, /healthz max_age validation, and trainer MFU accounting.
 
-Lean by design (tier-1 runs near its 870 s budget): one tiny serving
+Lean by design (tier-1 is compile-bound on the CPU): one tiny serving
 engine carries the lifecycle + /load acceptance assertions, one tiny
 compiled fit carries MFU/phase attribution; everything else is pure
 host work."""
@@ -14,6 +14,7 @@ import urllib.request
 
 import numpy as np
 import pytest
+from conftest import join_within
 
 import paddle_hackathon_tpu as paddle
 from paddle_hackathon_tpu.observability import (SlidingWindowHistogram,
@@ -117,8 +118,7 @@ def test_swh_thread_safety_smoke():
     ts = [threading.Thread(target=work) for _ in range(4)]
     for t in ts:
         t.start()
-    for t in ts:
-        t.join()
+    join_within(ts, 60, "the histogram writers")
     # mid-window (no rotation in flight): nothing may be lost
     assert h.count == 8000
 
@@ -130,7 +130,7 @@ def test_swh_thread_safety_smoke():
 def test_beacon_gc_drops_dead_thread_owner():
     t = threading.Thread(target=lambda: tracing.heartbeat("unit.worker"))
     t.start()
-    t.join()
+    join_within([t], 30, "the beacon's owner")
     # the owning thread exited without cleanup: the beacon must NOT sit
     # at an ever-growing age and 503 every ?max_age probe — GC at read
     assert "unit.worker" not in tracing.beacon_ages()
@@ -144,7 +144,7 @@ def test_pinned_beacon_survives_owner_exit():
 
     t = threading.Thread(target=crash_path)
     t.start()
-    t.join()
+    join_within([t], 30, "the beacon's owner")
     # pinned = the crashed-loop alert: it ages forever on purpose
     assert "unit.crashed" in tracing.beacon_ages()
     tracing.remove_beacon("unit.crashed")

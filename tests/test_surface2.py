@@ -242,15 +242,17 @@ def test_model_zoo_surface_complete():
     assert missing == []
 
 
-def test_new_models_forward():
-    m = paddle.vision.models
+# one case per model: eager construction and forward compile every
+# layer shape by itself (densenet121 alone is a minute on a quiet
+# machine), and as one test the four were the suite's longest by far
+@pytest.mark.parametrize("ctor", ["densenet121", "squeezenet1_1",
+                                  "shufflenet_v2_x0_25", "MobileNetV3Small"])
+def test_new_models_forward(ctor):
     paddle.seed(0)
     x = paddle.to_tensor(
         np.random.RandomState(0).randn(1, 3, 64, 64).astype(np.float32))
-    for ctor in (m.densenet121, m.squeezenet1_1, m.shufflenet_v2_x0_25,
-                 m.MobileNetV3Small):
-        out = ctor(num_classes=7)(x)
-        assert out.shape == [1, 7]
+    out = getattr(paddle.vision.models, ctor)(num_classes=7)(x)
+    assert out.shape == [1, 7]
 
 
 def test_static_namespace_surface_complete():

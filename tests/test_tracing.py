@@ -1,10 +1,10 @@
 """Event-level observability: span API, flight recorder, crash dumps,
 and the HTTP introspection server.
 
-Lean by design (tier-1 runs near its 870 s budget): the pure-host tests
+Lean by design (tier-1 is compile-bound on the CPU): the pure-host tests
 carry the API semantics; the two tests that compile a model (serving
 under a recording Profiler, the compiled-fit watchdog) are marked
-``slow`` and run only in untimed suites."""
+``slow`` and run only without ``-m 'not slow'``."""
 
 import json
 import os

@@ -7,18 +7,19 @@ Contract pinned here:
   replicated global clip preamble as the resident path) and a per-tensor
   streamed update (h2d -> the SAME pinned update body -> d2h through
   `io.TransferRing`).  On identical gradient inputs the update math is
-  bitwise the resident ZeRO step's; opt-state device bytes drop to ~0
-  while `placement=host` carries the footprint.  (End-to-end multi-step
-  series may drift ~1 ulp: the split program materializes the
-  all-reduced gradient at the program boundary where the fused one
-  reduce-scatters — stated, tested at tolerance.)
+  bitwise the resident ZeRO step's (pinned with the clip off);
+  opt-state device bytes drop to ~0 while `placement=host` carries the
+  footprint.  (With the clip on, the split program materializes the
+  all-reduced gradient at the program boundary and sums its squares
+  whole, where the fused one reduce-scatters and sums slices: the clip
+  scale is reassociated — stated, tested at tolerance.)
 - **Overlap is explicit emission, series-tolerance numerics.**
   `grad_overlap=True` pins each gradient to its moment sharding straight
-  after the backward (BEFORE the clip): the unoptimized lowering carries
-  the per-tensor sharding custom_calls ahead of the clip reduction, the
-  compiled module carries >=2 independent (distinct-channel) grad-shaped
-  scatter collectives, and the loss series matches the fused order to
-  f32 reassociation tolerance.
+  after the backward (BEFORE the clip): the traced program carries the
+  per-tensor sharding constraints ahead of the clip reduction, the
+  compiled module schedules >=2 independent (distinct-channel)
+  grad-shaped scatter collectives ahead of the clip scale, and the loss
+  series matches the fused order to f32 reassociation tolerance.
 - **ZeRO x pp composes.**  `zero_stage>=1` with a 'pp' axis shards the
   stacked per-stage moments over BOTH pp (the stage dim) and the data
   axis; offloaded composed state lives in host numpy; dp-reshard resume
@@ -136,21 +137,68 @@ def test_device_prefetch_rides_the_ring():
 # ---------------------------------------------------------------------------
 
 
-def test_sharded_step_offload_bitwise_and_host_placement():
-    """Two full steps: params, moments AND the reported losses are
-    bitwise the resident ZeRO run's; the offloaded state is host numpy;
-    the placement gauge reports device ~0 / host > 0."""
-    l_res, s_res, _ = _run_sharded(2)
-    l_off, s_off, _ = _run_sharded(2, zero_offload=True)
-    assert l_res == l_off
+def _entry(compiled_text):
+    """The compiled entry computation's instructions, in schedule order."""
+    body = re.search(r"^ENTRY [^\n]*\n(.*?)^}", compiled_text,
+                     re.S | re.M).group(1)
+    return [l.strip() for l in body.splitlines() if " = " in l]
+
+
+def _clip_reads_slices(entry):
+    """How many of the clip's instructions read only this device's own
+    slice of a gradient (`partition-id` is the slice's offset)."""
+    return sum(1 for l in entry if "/clip/" in l and "%partition-id" in l)
+
+
+@pytest.mark.parametrize("clip", [None, 1.0], ids=["no_clip", "clip"])
+def test_sharded_step_offload_bitwise_and_host_placement(clip):
+    """Two full steps against the resident ZeRO run; the offloaded state
+    is host numpy; the placement gauge reports device 0 / host > 0.
+
+    Update math and transport are BITWISE: with the clip off, params,
+    moments and losses are equal bit for bit.  With the clip on, the
+    two compiled programs sum the squares in different orders: the
+    resident step's clip reads each device's slice of the reduced
+    gradients (jax 0.9.0's partitioner propagates the moment sharding
+    back through the clip's multiply), the offload path's grads-only
+    program hands whole gradients over its boundary and sums those.
+    The scale differs in its last bit and the state after two steps
+    by what that reassociation gives: measured 3.0e-8 absolute on the
+    params, 9.1e-7 relative on the moments; held to 1e-7 / 1e-5."""
+    l_res, s_res, step_res = _run_sharded(2, grad_clip_norm=clip)
+    l_off, s_off, step_off = _run_sharded(2, grad_clip_norm=clip,
+                                          zero_offload=True)
+
+    def same(a, b):
+        if clip is None:
+            np.testing.assert_array_equal(a, b)
+        else:
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+    same(np.asarray(l_res), np.asarray(l_off))
     for k in s_res["params"]:
-        np.testing.assert_array_equal(np.asarray(s_res["params"][k]),
-                                      np.asarray(s_off["params"][k]))
+        same(np.asarray(s_res["params"][k]), np.asarray(s_off["params"][k]))
         for sl, v in s_off["opt_state"][k].items():
             assert isinstance(v, np.ndarray) and not isinstance(
                 v, jax.Array)
-            np.testing.assert_array_equal(
-                np.asarray(s_res["opt_state"][k][sl]), v)
+            same(np.asarray(s_res["opt_state"][k][sl]), v)
+            assert s_res["opt_state"][k][sl].sharding.memory_kind == "device"
+    # the resident moments sit on the device, each on its 1/dp slice
+    m = s_res["opt_state"]["0.weight"]["m"]
+    assert m.sharding.spec[0] == "dp"
+    assert m.addressable_shards[0].data.shape == (4, 32)
+    if clip is not None:
+        batch = (jnp.asarray(_X), jnp.asarray(_Y))
+        key, lr = jax.random.key(0), jnp.float32(1e-2)
+        resident = step_res._jitted.lower(
+            s_res["params"], s_res["opt_state"], s_res["step"], batch, key,
+            lr).compile().as_text()
+        grads_only = step_off._jitted.lower(
+            s_off["params"], s_off["step"], batch, key,
+            lr).compile().as_text()
+        # 3 of the MLP's 4 tensors split over dp=4 (the (2,) bias cannot)
+        assert _clip_reads_slices(_entry(resident)) >= 3
+        assert _clip_reads_slices(_entry(grads_only)) == 0
     from paddle_hackathon_tpu.observability import get_registry
     fam = get_registry().get("train_opt_state_bytes")
     pl = {dict(c.labels)["placement"]: c.value for c in fam.children()
@@ -202,34 +250,52 @@ def test_group_sharded_offload_flag_warns():
 # ---------------------------------------------------------------------------
 
 
-def _lowered(overlap):
+def _traced(overlap):
     mesh = parallel.create_mesh({"dp": 4}, devices=jax.devices()[:4])
     model = _mlp()
     step, state = parallel.make_sharded_train_step(
         model, mesh, rule=None, zero_stage=1, loss_fn=_loss_fn,
         grad_clip_norm=1.0, grad_overlap=overlap)
-    return step._jitted.lower(
+    return step._jitted.trace(
         state["params"], state["opt_state"], state["step"],
         (jnp.asarray(_X), jnp.asarray(_Y)), jax.random.key(0),
         jnp.float32(1e-2))
 
 
+_GRAD_SHAPES = ("f32[32,16]", "f32[2,32]")  # the MLP weight grads
+
+
 def test_grad_overlap_emits_scatters_before_clip():
-    """The schedule IS the emission order: under overlap the per-tensor
-    grad sharding pins appear BEFORE the global-norm clip's sqrt in the
-    unoptimized lowering (each tensor's reduce-scatter is independent of
-    the clip scalar, so XLA may start it during the remaining backward);
-    the fused path emits zero pins before the clip — the clip there runs
-    on replicated grads by design (bit-exactness vs replicated)."""
-    def pins_before_clip(txt):
-        lines = txt.splitlines()
-        first_sqrt = next(i for i, l in enumerate(lines) if "sqrt" in l)
-        return sum(1 for i, l in enumerate(lines)
-                   if i < first_sqrt and "custom_call" in l
-                   and "Sharding" in l)
-    assert pins_before_clip(_lowered(False).as_text()) == 0
+    """The flag is the emission order: under overlap the traced program
+    pins each gradient to its moment sharding BEFORE the global-norm
+    clip's sqrt (each tensor's reduce-scatter is independent of the clip
+    scalar, so XLA may start it during the remaining backward); the
+    fused order emits no pin before the clip.
+
+    And the compiled overlap program keeps that order: every grad-shaped
+    collective is scheduled before the instruction that turns the summed
+    squares into the clip scale, and the clip's sums read the device's
+    own slices: the scatter has happened by then.  (On jax 0.9.0's CPU
+    backend the fused order compiles to the same schedule, because the
+    partitioner propagates the moment sharding backwards by itself; the
+    traced programs differ, the flag is what guarantees the order.)"""
+    def pins_before_clip(traced):
+        names = [e.primitive.name for e in traced.jaxpr.eqns]
+        return names[:names.index("sqrt")].count("sharding_constraint")
+    assert pins_before_clip(_traced(False)) == 0
+    overlap = _traced(True)
     # one pin per MLP tensor (2 weights + 2 biases)
-    assert pins_before_clip(_lowered(True).as_text()) >= 4
+    assert pins_before_clip(overlap) == 4
+
+    entry = _entry(overlap.lower().compile().as_text())
+    scale_at = next(i for i, l in enumerate(entry)
+                    if "/clip/" in l and "/clip/reduce_sum" not in l)
+    scatters = [i for i, l in enumerate(entry)
+                if re.search(r"(reduce-scatter|all-reduce)(-start)?\(", l)
+                and any(s in l for s in _GRAD_SHAPES)]
+    assert len(scatters) >= 2 and max(scatters) < scale_at, entry
+    # 3 of the 4 tensors split over dp=4 (the (2,) bias cannot)
+    assert _clip_reads_slices(entry[:scale_at]) >= 3, entry
 
 
 def test_grad_overlap_hlo_independent_scatter_collectives():
@@ -238,13 +304,12 @@ def test_grad_overlap_hlo_independent_scatter_collectives():
     barrier).  This jaxlib's CPU backend spells reduce-scatter as a
     full-shape all-reduce feeding a dynamic-slice; TPU lowers the same
     pins to reduce-scatter proper — accept either."""
-    text = _lowered(True).compile().as_text()
-    grad_shapes = ("f32[32,16]", "f32[2,32]")  # the MLP weight grads
+    text = _traced(True).lower().compile().as_text()
     chans = set()
     for line in text.splitlines():
         if not re.search(r"(reduce-scatter|all-reduce)(-start)?\(", line):
             continue
-        if not any(s in line for s in grad_shapes):
+        if not any(s in line for s in _GRAD_SHAPES):
             continue
         m = re.search(r"channel_id=(\d+)", line)
         if m:

@@ -1,19 +1,13 @@
 """precommit: the docs/STATIC_ANALYSIS.md pre-PR checklist as ONE command.
 
-    python tools/precommit.py [--durations /tmp/durations.log] [--stats]
+    python tools/precommit.py [--stats]
 
 Chains, in order:
 
 1. **pht-lint --changed** — lints the .py files your change touches
    (worktree + index + untracked + commits since the merge-base with
    main); PHT003's lock graph still spans the whole scope.
-2. **test-budget drift** — ``tools/test_budget.py`` diffs a
-   ``pytest --durations=0`` log against ``tests/conftest.py _FILE_COST``
-   so budget drift fails HERE instead of as an RC=137 archaeology
-   session.  Runs when ``--durations`` is given or the default log
-   exists; otherwise SKIPPED with the command to produce one (a lint-only
-   change doesn't need a suite run, so a missing log is not a failure).
-3. **fault drills** — deterministic ``PHT_FAULTS`` drills against
+2. **fault drills** — deterministic ``PHT_FAULTS`` drills against
    host-only stubs (no tick program compiles).  The fleet
    dispatch-failover drill — an injected ``fleet.dispatch`` fault
    plus a submit-time replica death must re-dispatch cleanly (retry
@@ -37,9 +31,8 @@ import subprocess
 import sys
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-DEFAULT_DURATIONS = "/tmp/durations.log"
 
-# ``PHT_FAULTS`` fault drills run as step 3: (name, env-spec, script).
+# ``PHT_FAULTS`` fault drills run as step 2: (name, env-spec, script).
 # Each script runs in a fresh interpreter with the spec armed through
 # the environment (the same delivery the crash drills use), against
 # host-only stubs — no tick program compiles, so the step stays cheap.
@@ -517,10 +510,6 @@ def main(argv=None) -> int:
         prog="python tools/precommit.py",
         formatter_class=argparse.RawDescriptionHelpFormatter,
         description=__doc__)
-    ap.add_argument("--durations", default=None,
-                    help="pytest --durations=0 log for the budget drift "
-                         f"check (default: {DEFAULT_DURATIONS} when it "
-                         "exists; otherwise the step is skipped)")
     ap.add_argument("--stats", action="store_true",
                     help="pass --stats through to pht-lint (per-rule "
                          "counts + per-pass wall time)")
@@ -532,23 +521,6 @@ def main(argv=None) -> int:
     if args.stats:
         lint_cmd.append("--stats")
     _run_step("pht-lint", lint_cmd, results)
-
-    durations = args.durations
-    if durations is None and os.path.exists(DEFAULT_DURATIONS):
-        durations = DEFAULT_DURATIONS
-    if durations is not None:
-        if not os.path.exists(durations):
-            print(f"precommit: durations log {durations!r} not found",
-                  file=sys.stderr)
-            return 2
-        _run_step("test-budget",
-                  [sys.executable, "tools/test_budget.py", durations],
-                  results)
-    else:
-        results.append(("test-budget", "SKIP (no durations log)"))
-        print("== test-budget: SKIPPED — to include it:\n"
-              "   python -m pytest tests/ -q -m 'not slow' --durations=0 "
-              "-p no:cacheprovider | tee /tmp/durations.log")
 
     for name, spec, script in _DRILLS:
         _run_step(name, [sys.executable, "-c", script], results,
