@@ -25,17 +25,24 @@ This kernel family keeps everything in the projection-native layout:
 
 Stats (lse) live transposed as (b, H, 8, s) sublane-broadcast rows — the
 running max/sum also live transposed in VMEM ((G, 8, block) instead of
-(G, block, 128)), which is what lets 512-edge blocks fit.  Causal masking
-works at two grains.  Cells wholly above the diagonal skip compute AND
-their DMA (diagonal-clamped index maps).  A cell the diagonal crosses
-computes, in all three kernels, only the trapezoid its rows can see:
-c-row strips with static extents (``_diag_cell``), the ``where`` on each
-strip's one (c, c) diagonal sub-tile — (n + 1) / 2n of the cell's score
-area with n = block / c strips, where the whole-tile masked body (kept
-for plans with unequal blocks) computes all of it and masks half.
-``executed_score_share`` is that arithmetic for a whole call.  Dropout
-reuses the positional-hash mask, keyed by the global head index so each
-head draws an independent mask.
+(G, block, 128)), which is what lets 512-edge blocks fit.  The forward
+computes in that layout: its score tile is kv-major, (kv, q) as the dkdv
+kernel's, so a q row's maximum and sum are reductions down sublanes
+(elementwise over the sublane tiles, one 8-to-1 step; no cross-lane
+pass) that are born as the (1, block) rows they are stored in, and its
+accumulator is (G, D, block), turned once a head and q block when the
+output is written.
+
+Causal masking works at two grains.  Cells wholly above the diagonal
+skip compute AND their DMA (diagonal-clamped index maps).  A cell the
+diagonal crosses computes, in all three kernels, only the trapezoid its
+rows can see: c-row strips with static extents (``_diag_cell``), the
+``where`` on each strip's one (c, c) diagonal sub-tile — (n + 1) / 2n of
+the cell's score area with n = block / c strips, where the whole-tile
+masked body (kept for plans with unequal blocks) computes all of it and
+masks half.  ``executed_score_share`` is that arithmetic for a whole
+call.  Dropout reuses the positional-hash mask, keyed by the global head
+index so each head draws an independent mask.
 
 Each kernel body is Python-unrolled over the heads of a cell and the
 strips of a diagonal cell, so ``_fwd`` and ``_bwd`` are jitted with
@@ -180,7 +187,7 @@ def _score_share(sq, skv, plan, causal):
 def _positions(q0, k0, nq, nk, transposed=False):
     """Global (q, k) positions of the (nq, nk) score tile whose first row
     is q position ``q0`` and first column k position ``k0`` — or of its
-    (nk, nq) transpose (the dkdv kernel's layout)."""
+    (nk, nq) transpose (the forward's and the dkdv kernel's layout)."""
     shape = (nk, nq) if transposed else (nq, nk)
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape,
                                           1 if transposed else 0)
@@ -194,14 +201,14 @@ def _diag_cell(qi, ki, block_q, block_kv, strip, transposed=False):
     computes, static, and each tile's causal keep-mask.
 
     A tile is ``(r0, rn, kn)`` — q rows ``[r0, r0 + rn)`` of the block
-    against its first ``kn`` kv columns — or, transposed (dkdv),
-    ``(k0, kc, q0)`` — kv rows ``[k0, k0 + kc)`` against the q columns
-    from ``q0`` to the block's end.  With ``strip`` = c (a square,
-    aligned cell: ``qi == ki``) the tiles are the block's c-row trapezoid
-    strips, each reaching exactly as far as its rows can see, and the
-    mask is the one (c, c) triangle every strip carries on the sub-tile
-    at its diagonal end; without, the one whole tile and its mask from
-    global positions."""
+    against its first ``kn`` kv columns (dq) — or, transposed (the
+    forward and dkdv), ``(k0, kc, q0)`` — kv rows ``[k0, k0 + kc)``
+    against the q columns from ``q0`` to the block's end.  With
+    ``strip`` = c (a square, aligned cell: ``qi == ki``) the tiles are
+    the block's c-row trapezoid strips, each reaching exactly as far as
+    its rows can see, and the mask is the one (c, c) triangle every
+    strip carries on the sub-tile at its diagonal end; without, the one
+    whole tile and its mask from global positions."""
     if not strip:
         q_pos, k_pos = _positions(qi * block_q, ki * block_kv, block_q,
                                   block_kv, transposed)
@@ -214,35 +221,31 @@ def _diag_cell(qi, ki, block_q, block_kv, strip, transposed=False):
     return tiles, [tri] * len(tiles)
 
 
-def _dot(a, b, b_dim):
+def _dot(a, b, b_dim, a_dim=1):
     """``a`` (rows, K) times ``b`` contracted over its dim ``b_dim``
     (1: b is (cols, K), no transpose exists — current Mosaic takes (1,1)
-    bf16 contractions natively), accumulated in float32."""
-    return jax.lax.dot_general(a, b, (((1,), (b_dim,)), ((), ())),
+    bf16 contractions natively), accumulated in float32.  ``a_dim`` = 0
+    contracts ``a`` (K, rows) on its axis 0: the forward's PV product
+    into its transposed accumulator, where the small (kv, D) operand is
+    the one that turns and the (kv, q) probability tile never does."""
+    return jax.lax.dot_general(a, b, (((a_dim,), (b_dim,)), ((), ())),
                                preferred_element_type=jnp.float32,
                                precision=_prec(a.dtype))
 
 
-def _lane_fold(x, combine):
-    """(rows, k * 128) -> (rows, 128): the lane tiles of ``x`` combined
-    elementwise (VPU work); ``x`` itself at any other width."""
-    if x.shape[1] % _LANES:
-        return x
-    return functools.reduce(combine, [x[:, i:i + _LANES] for i in
-                                      range(0, x.shape[1], _LANES)])
-
-
-def _row_reduce(parts, reduce):
-    """``reduce`` along the rows of each part, the parts' rows stacked
-    into one column.  Lane-folded parts (``_lane_fold``) are stacked
-    first and reduced across lanes ONCE, as one (rows, 128) array: a
-    diagonal cell's strips then cost the cross-lane unit what one tile
-    does (the reductions are a quarter of the forward: PERF.md §6).  One
-    part alone is reduced as it is."""
-    if len(parts) > 1 and all(p.shape[1] == _LANES for p in parts):
-        return reduce(jnp.concatenate(parts, axis=0), axis=1, keepdims=True)
-    cols = [reduce(p, axis=1, keepdims=True) for p in parts]
-    return cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=0)
+def _join_trailing(whole, part, combine):
+    """``combine`` ``part`` into the trailing columns of ``whole`` (the
+    first part given is the whole).  The kv-major strips of a diagonal
+    cell reach from their own q position to the block's end, so their
+    per-column partials nest at the trailing edge; a split falls on a
+    lane-tile boundary."""
+    if whole is None:
+        return part
+    w = part.shape[1]
+    if w == whole.shape[1]:
+        return combine(whole, part)
+    return jnp.concatenate(
+        [whole[:, :-w], combine(whole[:, -w:], part)], axis=1)
 
 
 def _select_edge(keep, x, fill, leading=False):
@@ -299,87 +302,98 @@ def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, o_ref, lse_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     def _body(tiles, keeps):
-        # VPU passes over the score tile are the kernel's critical path
-        # (time per tile is the same at D=64 and D=128: PERF.md §5), so
-        # the softmax touches as few score elements as few times as
+        # VPU passes over the float32 score tile are most of the kernel's
+        # time (PERF.md §5), so the softmax touches as few score elements
+        # as few times as
         # possible: sm_scale is folded into the small (rows, D) q slice
         # (exact for power-of-two 1/sqrt(D)); a diagonal cell computes
         # only the strips' trapezoid (``_diag_cell``); the causal select
         # runs on diagonal sub-tiles only — cells under the diagonal skip
-        # it entirely.  Masking comes BEFORE the running max (a raw-block
-        # max could be inflated by a masked outlier logit, underflowing
-        # every valid probability in the row).
+        # it entirely.  The score tile is kv-major, (kv, q) as the dkdv
+        # kernel has it: a q row's maximum and sum are reductions down
+        # axis 0 — elementwise over the sublane tiles, one 8-to-1
+        # sublane step at the end, nothing across lanes — and are born
+        # as the (1, block_q) rows the statistics are stored in.
+        # Masking comes BEFORE the running max (a raw-block max could be
+        # inflated by a masked outlier logit, underflowing every valid
+        # probability in the row).
         qb = q_ref[0]                            # (block_q, G*D)
         kb = k_ref[0]                            # (block_kv, G*D)
         vb = v_ref[0]
-        pos = [_positions(qi * block_q + r0, ki * block_kv, rn, kn)
-               if dropout_p > 0.0 else None for r0, rn, kn in tiles]
+        pos = [_positions(qi * block_q + q0, ki * block_kv + k0,
+                          block_q - q0, kc, transposed=True)
+               if dropout_p > 0.0 else None for k0, kc, q0 in tiles]
         for h in range(group):
             q_h = qb[:, h * D:(h + 1) * D] * jnp.asarray(sm_scale, qb.dtype)
             k_h = kb[:, h * D:(h + 1) * D]
             v_h = vb[:, h * D:(h + 1) * D]
             # two phases a head, each over all the tiles: every strip's
-            # score matmul and folded row maximum, then every strip's
-            # exponential, folded row sum and PV matmul.  A strip run end
-            # to end by itself pays the whole matmul -> reduce -> exp ->
+            # score matmul and column maximum, then every strip's
+            # exponential, column sum and PV matmul.  A strip run end to
+            # end by itself pays the whole matmul -> reduce -> exp ->
             # matmul latency chain (0.3-0.4 us a strip, PERF.md §6).  A
-            # whole tile has nothing to fold: its ops are the ones it
-            # always ran, in their order
-            fold = _lane_fold if len(tiles) > 1 else (lambda x, _: x)
-            scores, m_cur = [], []
-            for (r0, rn, kn), keep in zip(tiles, keeps):
-                s = _dot(q_h[r0:r0 + rn], k_h[:kn], 1)
+            # strip's q columns run from its own position to the block's
+            # end, so a q column's statistics span the strips up to its
+            # own: the strips' partial rows are joined per column before
+            # the exponentials
+            scores, m_cur = [], None
+            for (k0, kc, q0), keep in zip(tiles, keeps):
+                st = _dot(k_h[k0:k0 + kc], q_h[q0:], 1)  # (kc, q columns)
                 if keep is not None:
-                    s = _select_edge(keep, s, _NEG_INF)
-                scores.append(s)
-                m_cur.append(fold(s, jnp.maximum))
-            # stats live transposed (8, block_q); work in (rows, 1).  One
-            # transpose each way a head, whatever the tiles: the tiles'
-            # rows partition the block in order, so their columns of
-            # statistics are slices of, and concatenate back to, the
-            # block's (a narrow transpose costs as much as a wide one).
-            # Read AFTER the score matmuls: read before them, a 256-edge
-            # cell's forward was 12 % slower at s768 (PERF.md §6)
-            m_old = jnp.swapaxes(m_ref[h], 0, 1)[:, :1]
-            l_old = jnp.swapaxes(l_ref[h], 0, 1)[:, :1]
-            m_next = jnp.maximum(m_old, _row_reduce(m_cur, jnp.max))
-            alpha = jnp.exp(m_old - m_next)              # (block_q, 1)
-            l_cur, pv = [], []
-            for (r0, rn, kn), s, qk_pos in zip(tiles, scores, pos):
-                p = jnp.exp(s - m_next[r0:r0 + rn])
-                l_cur.append(fold(p, jnp.add))
+                    st = _select_edge(keep, st, _NEG_INF, leading=True)
+                scores.append(st)
+                m_cur = _join_trailing(
+                    m_cur, jnp.max(st, axis=0, keepdims=True), jnp.maximum)
+            # read AFTER the score matmuls: read before them, a 256-edge
+            # cell's forward was 12 % slower at s768 (PERF.md §6, PR 27)
+            m_old = m_ref[h, :1]                         # (1, block_q)
+            l_old = l_ref[h, :1]
+            m_next = jnp.maximum(m_old, m_cur)
+            alpha = jnp.exp(m_old - m_next)
+            # a strip reads its columns of the new maximum back from the
+            # scratch, as the dkdv kernel reads lse: Mosaic cannot
+            # lane-slice the (1, block_q) value itself ("Invalid input
+            # layout" on the broadcast of a replicated row's slice)
+            m_ref[h] = jnp.broadcast_to(m_next, (_SUB, block_q))
+            l_cur, pv = None, None
+            for (k0, kc, q0), st, qk_pos in zip(tiles, scores, pos):
+                pt = jnp.exp(st - m_ref[h, :1, q0:])
+                l_cur = _join_trailing(
+                    l_cur, jnp.sum(pt, axis=0, keepdims=True), jnp.add)
                 if dropout_p > 0.0:
                     drop_keep = _dropout_keep(seed_ref[0],
                                               bi * heads + gi * group + h,
                                               *qk_pos, 1.0 - dropout_p)
-                    p = jnp.where(drop_keep, p / (1.0 - dropout_p), 0.0)
-                pv.append(_dot(p.astype(v_h.dtype), v_h[:kn], 0))
-            l_next = l_old * alpha + _row_reduce(l_cur, jnp.sum)
-            acc_ref[h] = acc_ref[h] * alpha + (
-                pv[0] if len(pv) == 1 else jnp.concatenate(pv, axis=0))
-            m_ref[h] = jnp.swapaxes(
-                jnp.broadcast_to(m_next, (block_q, _SUB)), 0, 1)
-            l_ref[h] = jnp.swapaxes(
-                jnp.broadcast_to(l_next, (block_q, _SUB)), 0, 1)
+                    pt = jnp.where(drop_keep, pt / (1.0 - dropout_p), 0.0)
+                # the accumulator is transposed too, (D, block_q): the
+                # small (kc, D) operand is the one contracted on axis 0
+                pv = _join_trailing(
+                    pv, _dot(v_h[k0:k0 + kc], pt.astype(v_h.dtype), 0,
+                             a_dim=0), jnp.add)
+            acc_ref[h] = acc_ref[h] * alpha + pv
+            l_ref[h] = jnp.broadcast_to(l_old * alpha + l_cur,
+                                        (_SUB, block_q))
 
+    first_k = ki * block_kv
     last_q = qi * block_q + block_q - 1
     _run_cells(
         causal,
-        diag=(ki * block_kv <= last_q) &
-        (ki * block_kv + block_kv - 1 > last_q - block_q),
-        full=ki * block_kv + block_kv - 1 <= last_q - block_q,
+        diag=(first_k <= last_q) & (first_k + block_kv - 1 > last_q - block_q),
+        full=first_k + block_kv - 1 <= last_q - block_q,
         body=_body,
-        diag_cell=lambda: _diag_cell(qi, ki, block_q, block_kv, strip),
-        whole=(0, block_q, block_kv))
+        diag_cell=lambda: _diag_cell(qi, ki, block_q, block_kv, strip,
+                                     transposed=True),
+        whole=(0, block_kv, 0))
 
     @pl.when(ki == n_kv - 1)
     def _finish():
         for h in range(group):
             lt = l_ref[h]                        # (8, block_q)
             lt = jnp.where(lt == 0.0, 1.0, lt)
-            l_col = jnp.swapaxes(lt, 0, 1)[:, :1]
-            o_ref[0, :, h * D:(h + 1) * D] = (
-                acc_ref[h] / l_col).astype(o_ref.dtype)
+            # the one transpose a head and q block: the normalised
+            # accumulator, (D, block_q) -> (block_q, D)
+            o_ref[0, :, h * D:(h + 1) * D] = jnp.swapaxes(
+                acc_ref[h] / lt[:1], 0, 1).astype(o_ref.dtype)
             lse_ref[0, h] = m_ref[h] + jnp.log(jnp.maximum(lt, 1e-30))
 
 
@@ -439,7 +453,7 @@ def _fwd(qkv, seed, *, heads, causal, sm_scale, dropout_p, plan, interpret):
             jax.ShapeDtypeStruct((b, heads, _SUB, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((G, bq, D), jnp.float32),       # acc
+            pltpu.VMEM((G, D, bq), jnp.float32),       # acc (transposed)
             pltpu.VMEM((G, _SUB, bq), jnp.float32),    # m (transposed)
             pltpu.VMEM((G, _SUB, bq), jnp.float32),    # l (transposed)
         ],
@@ -563,8 +577,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # same VPU economy as the forward: sm_scale folded into the small
         # q slice (s lands in lse space directly) and into the k slice of
         # the final dot (dq = p*(dp-delta) . k*scale); the causal select
-        # runs on p AFTER the exp and on diagonal sub-tiles only; a
-        # diagonal cell runs the forward's strips
+        # runs on p AFTER the exp and on diagonal sub-tiles only; the
+        # one kernel whose scores are row-major, so a diagonal cell's
+        # strips are q rows against the kv columns they can see
         qb = q_ref[0]
         kb = k_ref[0]
         vb = v_ref[0]
@@ -574,7 +589,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         for h in range(group):
             scale = jnp.asarray(sm_scale, qb.dtype)
             k_h = kb[:, h * D:(h + 1) * D]
-            # one transpose a head, sliced by the tiles (see _fwd_kernel)
+            # one transpose a head, sliced by the tiles: a narrow
+            # transpose costs as much as a wide one (PERF.md §6, PR 27)
             lse_h = jnp.swapaxes(lse_ref[0, h], 0, 1)[:, :1]  # (block_q, 1)
             delta_h = jnp.swapaxes(delta_ref[0, h], 0, 1)[:, :1]
             q_h = qb[:, h * D:(h + 1) * D] * scale

@@ -534,6 +534,140 @@ class TestPackedFlashAttention:
             grad, np.asarray(jax.grad(ref_loss)(qkv), np.float32),
             rtol=0.1, atol=0.05)
 
+    @staticmethod
+    def _q_major_forward(qkv, H, scale, causal, block):
+        """The row-major forward this kernel replaced (PR 27's body),
+        restated in plain jnp: score tiles (q, kv), row maximum and row
+        sum across the kv axis, the accumulator (q, D), block by block
+        with the kernel's roundings (q scaled in bf16, float32 scores and
+        statistics, p cast to bf16 for the PV product).  ``(out, lse)``
+        with lse (b, H, s)."""
+        b, s, hd3 = qkv.shape
+        D = hd3 // 3 // H
+        q, k, v = (qkv[..., i * H * D:(i + 1) * H * D].reshape(
+            b, s, H, D).transpose(0, 2, 1, 3) for i in range(3))
+        q = q * jnp.asarray(scale, q.dtype)
+        m = jnp.full((b, H, s, 1), -1e30, jnp.float32)
+        l = jnp.zeros((b, H, s, 1), jnp.float32)
+        acc = jnp.zeros((b, H, s, D), jnp.float32)
+        q_pos = jnp.arange(s)[:, None]
+        for k0 in range(0, s, block):
+            sc = jnp.einsum("bhqd,bhkd->bhqk", q, k[:, :, k0:k0 + block],
+                            preferred_element_type=jnp.float32)
+            if causal:
+                sc = jnp.where(q_pos >= k0 + jnp.arange(block)[None, :],
+                               sc, -1e30)
+            m_next = jnp.maximum(m, sc.max(-1, keepdims=True))
+            alpha = jnp.exp(m - m_next)
+            p = jnp.exp(sc - m_next)
+            if causal:          # the kernel skips cells above the diagonal
+                p = jnp.where(q_pos >= k0, p, 0.0)
+                m_next = jnp.where(q_pos >= k0, m_next, m)
+                alpha = jnp.where(q_pos >= k0, alpha, 1.0)
+            l = l * alpha + p.sum(-1, keepdims=True)
+            acc = acc * alpha + jnp.einsum(
+                "bhqk,bhkd->bhqd", p.astype(qkv.dtype),
+                v[:, :, k0:k0 + block], preferred_element_type=jnp.float32)
+            m = m_next
+        out = (acc / l).astype(qkv.dtype).transpose(0, 2, 1, 3)
+        return (np.asarray(out.reshape(b, s, H * D), np.float32),
+                np.asarray((m + jnp.log(l))[..., 0]))
+
+    def _ref_lse(self, qkv, H, scale, causal):
+        b, s, hd3 = qkv.shape
+        D = hd3 // 3 // H
+        x = np.asarray(qkv, np.float32)
+        q, k = (x[..., i * H * D:(i + 1) * H * D].reshape(
+            b, s, H, D).transpose(0, 2, 1, 3) for i in range(2))
+        sc = np.einsum("bhqd,bhkd->bhqk", q, k) * scale
+        if causal:
+            sc = np.where(np.tril(np.ones((s, s), bool)), sc, -np.inf)
+        top = sc.max(-1)
+        return top + np.log(np.exp(sc - top[..., None]).sum(-1))
+
+    # (s, H, D, plan or None for ``_plan``'s own, causal): 1, 2 and 3 kv
+    # blocks a row, both head dims, strips and whole tiles, the plans the
+    # benchmark's cells run (512-edge, four 128-row strips) and a 256-edge
+    # plan (whole-tile diagonal body)
+    @pytest.mark.parametrize("S,H,D,plan,causal", [
+        (128, 2, 64, (128, 128, 2, 32), True),
+        (512, 2, 64, (256, 256, 2, 128), True),
+        (384, 1, 128, (128, 128, 1, 32), True),
+        (512, 2, 64, None, True),           # (512, 512, 2, 128): one cell
+        (1024, 1, 128, None, True),         # a whole cell under the diagonal
+        (768, 2, 64, None, True),           # (256, 256, 2, 0), 3 kv blocks
+        (256, 2, 64, (128, 128, 2, 0), False),
+        (512, 1, 128, None, False),         # one whole 512-edge tile
+    ], ids=["strips-1kv-d64", "strips-2kv-d64", "strips-3kv-d128",
+            "cell-plan-1kv-d64", "cell-plan-2kv-d128", "edge256-3kv-d64",
+            "noncausal-2kv-d64", "noncausal-1kv-d128"])
+    def test_kv_major_forward_matches_row_major_and_reference(
+            self, monkeypatch, S, H, D, plan, causal):
+        """The forward's scores are kv-major and its accumulator
+        transposed; ``out`` AND ``lse`` (the backward kernels read it)
+        equal the row-major body's to rounding and float32 attention's."""
+        from paddle_hackathon_tpu.incubate.nn.kernels import (
+            flash_attention_packed as fap)
+        if plan is None:
+            plan = fap._plan(S, S, H, D, jnp.bfloat16)
+        assert plan[0] == plan[1] and S % plan[0] == 0
+        rng = np.random.RandomState(S + D + H)
+        qkv = jnp.asarray(rng.randn(1, S, 3 * H * D) * 0.5, jnp.bfloat16)
+        scale = 1.0 / np.sqrt(D)
+        out, lse = fap._fwd(qkv, jnp.zeros((1,), jnp.int32), heads=H,
+                            causal=causal, sm_scale=scale, dropout_p=0.0,
+                            plan=plan, interpret=True)
+        out, lse = np.asarray(out, np.float32), np.asarray(lse)
+        assert lse.shape == (1, H, 8, S)        # the stored layout
+        assert (lse == lse[:, :, :1]).all()
+        out_q, lse_q = self._q_major_forward(qkv, H, scale, causal, plan[1])
+        np.testing.assert_allclose(lse[:, :, 0], lse_q, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(out, out_q, rtol=2 ** -7, atol=1e-3)
+        np.testing.assert_allclose(out, self._ref(qkv, H, causal),
+                                   rtol=0.05, atol=0.02)
+        np.testing.assert_allclose(
+            lse[:, :, 0], self._ref_lse(qkv, H, scale, causal), atol=0.03)
+
+    def test_kv_major_strips_keep_statistics_finite(self):
+        """The q columns at the head of a diagonal cell are outside every
+        strip but the first, and logits of +-60 put every masked and many
+        live entries beyond exp's range: no maximum, sum or alpha may
+        turn NaN or inf, in the first kv block of a row or a later one."""
+        from paddle_hackathon_tpu.incubate.nn.kernels import (
+            flash_attention_packed as fap)
+        S, H, D = 256, 2, 64
+        rng = np.random.RandomState(11)
+        qkv = np.asarray(rng.randn(1, S, 3 * H * D) * 3.0, np.float32)
+        qkv[0, :, :8] = 30.0                    # one outlier direction
+        qkv = jnp.asarray(qkv, jnp.bfloat16)
+        out, lse = fap._fwd(qkv, jnp.zeros((1,), jnp.int32), heads=H,
+                            causal=True, sm_scale=0.125, dropout_p=0.0,
+                            plan=(128, 128, H, 32), interpret=True)
+        out, lse = np.asarray(out, np.float32), np.asarray(lse)
+        assert np.isfinite(out).all() and np.isfinite(lse).all()
+        np.testing.assert_allclose(
+            lse[:, :, 0], self._ref_lse(qkv, H, 0.125, True),
+            rtol=2e-3, atol=0.05)
+        # row 0 sees key 0 alone: its output is v[0], its lse the one logit
+        v0 = np.asarray(qkv, np.float32)[0, 0, 2 * H * D:]
+        np.testing.assert_allclose(out[0, 0], v0, rtol=2 ** -7)
+
+    def test_forward_tile_helpers(self):
+        from paddle_hackathon_tpu.incubate.nn.kernels import (
+            flash_attention_packed as fap)
+        a = jnp.arange(8.0).reshape(1, 8)
+        b = jnp.full((1, 4), 10.0)
+        assert fap._join_trailing(None, a, jnp.add) is a
+        np.testing.assert_array_equal(
+            fap._join_trailing(a, b, jnp.add),
+            [[0, 1, 2, 3, 14, 15, 16, 17]])
+        np.testing.assert_array_equal(
+            fap._join_trailing(a, a, jnp.maximum), a)
+        x = jnp.asarray(np.random.RandomState(0).randn(16, 8), jnp.float32)
+        y = jnp.asarray(np.random.RandomState(1).randn(16, 4), jnp.float32)
+        np.testing.assert_allclose(fap._dot(x, y, 0, a_dim=0),
+                                   np.asarray(x).T @ y, rtol=1e-5, atol=1e-5)
+
     def test_strips_keep_dropout_masks_in_step(self, monkeypatch):
         """With strips the three kernels still draw one mask from (seed,
         head, global q, global k): every result equals the whole-tile

@@ -51,6 +51,8 @@ def _aval(shape, dtype):
     (32, 1024, 12, 64, True, 0.0),     # gpt2-small train step
     (32, 1024, 12, 64, True, 0.1),     # ... with attention dropout
     (6, 1024, 16, 128, True, 0.0),     # GPT-3 1.3B
+    (16, 1024, 16, 64, True, 0.0),     # gpt2-medium: G = 8, four strips
+    (16, 768, 16, 64, True, 0.0),      # a 256-edge plan: whole-tile cells
     (64, 512, 12, 64, False, 0.0),     # ERNIE-base, non-causal
     (4, 4096, 12, 64, True, 0.0),      # long context
 ])
@@ -65,6 +67,49 @@ def test_packed_flash_fwd_and_bwd_lower(b, s, heads, d, causal, dropout):
                          _aval((b, s, 3 * heads * d), jnp.bfloat16))
     assert sorted(names) == ["flash_packed_bwd_dkdv", "flash_packed_bwd_dq",
                              "flash_packed_fwd"]
+
+
+@pytest.fixture(scope="module")
+def one_described_chip():
+    """A v5e chip that is described, not attached: the TPU's own compiler
+    then says what the chip's would (layouts Mosaic refuses, scoped VMEM),
+    which the lowering above cannot.  Described inside a fixture, so that
+    every worker collects the same tests and only this file's loads the
+    TPU's library."""
+    import os
+    pytest.importorskip("libtpu", reason="the TPU's compiler is not here")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    # with the library installed, any failure to describe the chip fails
+    # the tests: a skip would hide the one guard this side of the chip
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("b,s,heads,d,causal,dropout", [
+    (16, 1024, 16, 64, True, 0.0),     # gpt2-medium.train-s1024's plan
+    (6, 1024, 16, 128, True, 0.0),     # gpt3-1.3b.train-s1024's
+    (16, 768, 16, 64, True, 0.0),      # 256-edge: whole-tile diagonal
+    (64, 512, 12, 64, False, 0.0),     # ERNIE: one whole tile a row
+    (32, 1024, 12, 64, True, 0.1),     # dropout, G = 6
+])
+def test_packed_flash_forward_compiles_for_a_described_v5e(
+        one_described_chip, b, s, heads, d, causal, dropout):
+    """The kv-major forward slices statistics rows, contracts axis 0 of
+    both PV operands and transposes its accumulator once: each was a
+    question for Mosaic's compiler, not for the lowering (a lane slice
+    of a replicated row lowered and then failed with "Invalid input
+    layout")."""
+    plan = fap._plan(s, s, heads, d, jnp.bfloat16)
+    qkv = jax.ShapeDtypeStruct((b, s, 3 * heads * d), jnp.bfloat16,
+                               sharding=one_described_chip)
+    seed = jax.ShapeDtypeStruct((1,), jnp.int32,
+                                sharding=one_described_chip)
+    compiled = fap._fwd.lower(
+        qkv, seed, heads=heads, causal=causal, sm_scale=1.0 / math.sqrt(d),
+        dropout_p=dropout, plan=plan, interpret=False).compile()
+    assert "flash_packed_fwd" in compiled.as_text()
 
 
 @pytest.mark.parametrize("bh,s,d,causal,dtype", [
