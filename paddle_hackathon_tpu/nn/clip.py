@@ -56,9 +56,14 @@ class ClipGradByGlobalNorm(ClipGradBase):
         self.clip_norm = float(clip_norm)
 
     def _clip(self, grads):
+        """The norm is summed in float32; the scale is rounded to each
+        gradient's dtype and the product taken there, so a bf16 gradient
+        tree is never widened to float32 (for float32 gradients the two
+        forms are the same bits).  THE global-norm clip of the package:
+        the eager optimizers and all three compiled trainers call it."""
         if not grads:
             return grads
         sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in grads)
         global_norm = jnp.sqrt(sq)
         scale = self.clip_norm / jnp.maximum(global_norm, self.clip_norm)
-        return [(g.astype(jnp.float32) * scale).astype(g.dtype) for g in grads]
+        return [g * scale.astype(g.dtype) for g in grads]

@@ -47,8 +47,30 @@ def _make_zero_update(opt, shard_info):
     return zero_update
 
 
+class StackedParameter:
+    """What a trainer hands ``functional_update(params=)`` for a leaf that
+    holds N same-shaped parameters stacked on a leading layer dim (the pp
+    trainers' ``(L, ...)`` blocks): one layer's metadata (name, lr scale)
+    and the stacked value.  A rule that takes per-parameter norms (LAMB,
+    LARS) is vmapped over dim 0 of such a leaf, so every layer keeps its
+    own trust ratio — a stack-wide norm would be a different optimizer
+    (the reference computes it per parameter:
+    ``distributed_fused_lamb.py:86``)."""
+
+    layer_stacked = True
+
+    def __init__(self, like, value):
+        self.name = like.name
+        self.optimize_attr = like.optimize_attr
+        self._value = value
+
+
 class Optimizer:
     _accum_names: List[str] = []
+    # the rule reduces over the whole parameter (a norm), so a
+    # layer-stacked leaf must be vmapped rather than updated as one array
+    _per_param_norm = False
+    _stacked = ()
 
     def __init__(self, learning_rate=0.001, parameters=None, weight_decay=None,
                  grad_clip=None, multi_precision=False, name=None):
@@ -189,18 +211,26 @@ class Optimizer:
             param_lrs = (1.0,) * len(vals)
         self._prepare_functional(params)
         try:
-            if shard_info is not None:
-                return self._sharded_update(vals, grads, states, lr,
-                                            step_t, tuple(param_lrs),
-                                            shard_info)
-            return self._update_all(vals, grads, states, lr, step_t,
-                                    tuple(param_lrs))
+            grads = self._preprocess_grads(
+                self._decay_vals(vals, states, shard_info), grads)
+            # the phase names of every compiled trainer's program
+            # (observability/programs.py phase_census): "clip" is in
+            # _preprocess_grads, "update" is here, each written once
+            with jax.named_scope("update"):
+                if shard_info is not None:
+                    return self._sharded_rules(vals, grads, states, lr,
+                                               step_t, tuple(param_lrs),
+                                               shard_info)
+                return self._apply_rules(vals, grads, states, lr, step_t,
+                                         tuple(param_lrs))
         finally:
             self._prepare_functional(None)
 
     def _prepare_functional(self, params):
         """Hook: derive per-parameter trace-time metadata from an explicit
         param list (``None`` restores the eager ``step()`` behavior)."""
+        self._stacked = () if params is None else tuple(
+            getattr(p, "layer_stacked", False) for p in params)
 
     def _preprocess_grads(self, vals, grads):
         """The grad preamble shared by every update path: f32 cast,
@@ -221,39 +251,71 @@ class Optimizer:
                 grads = [g + self._weight_decay * v.astype(g.dtype)
                          for g, v in zip(grads, vals)]
         if self._grad_clip is not None:
-            grads = self._grad_clip._clip(grads)
+            with jax.named_scope("clip"):
+                grads = self._grad_clip._clip(grads)
         return grads
 
     def _update_all(self, vals, grads, states, lr, step_t, param_lrs):
         grads = self._preprocess_grads(vals, grads)
+        return self._apply_rules(vals, grads, states, lr, step_t, param_lrs)
+
+    def _apply_rules(self, vals, grads, states, lr, step_t, param_lrs):
+        """The per-tensor rule over already preprocessed gradients."""
         new_vals, new_states = [], []
-        for v, g, s, plr in zip(vals, grads, states, param_lrs):
-            nv, ns = self._apply_one(v, g, s, lr * plr, step_t)
+        stacked = self._stacked if len(self._stacked) == len(vals) \
+            else (False,) * len(vals)
+        for v, g, s, plr, st in zip(vals, grads, states, param_lrs, stacked):
+            rule = self._apply_one
+            if st and self._per_param_norm:
+                rule = jax.vmap(rule, in_axes=(0, 0, 0, None, None))
+            nv, ns = rule(v, g, s, lr * plr, step_t)
             new_vals.append(nv.astype(v.dtype))
             new_states.append(ns)
         return new_vals, new_states
 
+    @staticmethod
+    def _decay_vals(vals, states, shard_info):
+        """What the preamble's coupled decay (and its f32-cast selector)
+        reads as the parameter: the f32 master where one is kept."""
+        if shard_info is None or not shard_info.master_weights:
+            return vals
+        return [s.get("master", v) for v, s in zip(vals, states)]
+
     def _sharded_update(self, vals, grads, states, lr, step_t, param_lrs,
                         shard_info):
-        """ZeRO shard-aware update (``parallel.sharding.ZeroShardInfo``).
+        """The eager ``step()``'s ZeRO update: the preamble on the
+        replicated gradients, then :meth:`_sharded_rules`."""
+        grads = self._preprocess_grads(
+            self._decay_vals(vals, states, shard_info), grads)
+        return self._sharded_rules(vals, grads, states, lr, step_t,
+                                   param_lrs, shard_info)
+
+    def _sharded_rules(self, vals, grads, states, lr, step_t, param_lrs,
+                       shard_info):
+        """ZeRO shard-aware update (``parallel.sharding.ZeroShardInfo``)
+        over already preprocessed gradients.
 
         Per tensor: grad pinned to the moment sharding → the pending dp
         grad psum fuses with the slice into a reduce-scatter; moments
         (and the optional f32 ``"master"`` slot) pinned in AND out so
         GSPMD cannot re-replicate them anywhere in the program; the
-        update rule itself is the unmodified ``_update_all`` core run on
+        update rule itself is the unmodified ``_apply_rules`` core run on
         the 1/dp slice; the new param value is cast to the param dtype
         FIRST and then pinned to the param's own spec — a per-tensor
         all-gather (bf16-sized under master weights) that depends only
         on its own update, so the scheduler overlaps it with the other
         params' update compute and the next step's forward entry.
 
-        Weight decay + global-norm clip run BEFORE the pins (on the
+        Weight decay + global-norm clip ran BEFORE the pins (on the
         replicated grads) — see ``_preprocess_grads`` — keeping the
         sharded loss series bit-exact vs the replicated update for
         elementwise rules.  Per-param-norm rules (LAMB/LARS) compute
         their norms on the sharded slices with GSPMD-inserted
-        cross-shard reductions — globally correct, reassociated."""
+        cross-shard reductions — globally correct, reassociated.
+
+        A ``shard_info`` whose ``axis`` is ``None`` (no ZeRO axis on the
+        mesh) pins everything to the parameter's own spec: that is how a
+        master slot is carried without ZeRO."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh = shard_info.mesh
@@ -263,9 +325,6 @@ class Optimizer:
             return jax.lax.with_sharding_constraint(
                 a, NamedSharding(mesh, P(*spec)))
 
-        grads = self._preprocess_grads(
-            vals if not shard_info.master_weights
-            else [s.get("master", v) for v, s in zip(vals, states)], grads)
         mspecs = [shard_info.moment_spec(v.shape, existing=ps)
                   for v, ps in zip(vals, pspecs)]
         g_sh = [pin(g, ms) for g, ms in zip(grads, mspecs)]
@@ -280,16 +339,8 @@ class Optimizer:
             inner_states = states
         inner_states = [{k: pin(v, ms) for k, v in s.items()}
                         for s, ms in zip(inner_states, mspecs)]
-        # decay/clip already applied above — run the core rule only (the
-        # attribute save/restore is trace-time Python, never traced state)
-        saved_clip, saved_wd = self._grad_clip, self._weight_decay
-        self._grad_clip = None
-        self._weight_decay = None
-        try:
-            new_vals, new_states = self._update_all(
-                compute_vals, g_sh, inner_states, lr, step_t, param_lrs)
-        finally:
-            self._grad_clip, self._weight_decay = saved_clip, saved_wd
+        new_vals, new_states = self._apply_rules(
+            compute_vals, g_sh, inner_states, lr, step_t, param_lrs)
         out_states = [{k: pin(v, ms) for k, v in s.items()}
                       for s, ms in zip(new_states, mspecs)]
         if shard_info.master_weights:
@@ -326,18 +377,12 @@ class Optimizer:
                                shard_info, param_lr=1.0):
         """One tensor of the ZeRO update, for the offload streaming pipe:
         ``grad`` is already preprocessed (``preprocess_grads_offload``),
-        so clip/decay are nulled and ``_sharded_update`` runs on
-        single-element lists — the identical per-tensor core the
-        resident path traces.  ``shard_info.param_specs`` must carry
-        exactly this tensor's spec.  Returns ``(new_val, new_state)``."""
-        saved_clip, saved_wd = self._grad_clip, self._weight_decay
-        self._grad_clip = None
-        self._weight_decay = None
-        try:
-            nvs, nss = self._sharded_update(
-                [val], [grad], [state], lr, step_t, (param_lr,), shard_info)
-        finally:
-            self._grad_clip, self._weight_decay = saved_clip, saved_wd
+        so ``_sharded_rules`` runs on single-element lists — the
+        identical per-tensor core the resident path traces.
+        ``shard_info.param_specs`` must carry exactly this tensor's
+        spec.  Returns ``(new_val, new_state)``."""
+        nvs, nss = self._sharded_rules(
+            [val], [grad], [state], lr, step_t, (param_lr,), shard_info)
         return nvs[0], nss[0]
 
     def _decoupled_weight_decay(self) -> bool:

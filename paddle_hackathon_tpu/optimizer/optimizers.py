@@ -37,12 +37,17 @@ class Momentum(Optimizer):
         return new_v, {"velocity": vel}
 
 
+def _moment_dtype(moment_dtype):
+    """The dtype moments are STORED in (the math stays f32)."""
+    return jnp.float32 if moment_dtype is None else jnp.dtype(moment_dtype)
+
+
 def adam_update(value, grad, m, v, lr, t, beta1, beta2, eps,
                 moment_dtype=jnp.float32):
     """One Adam tensor update — THE single owner of the update math
     (bias-corrected moments computed in f32, stored in ``moment_dtype``).
-    Used by both the eager ``Adam._apply_one`` and the sharded train
-    step's inlined optimizer (``parallel/api.py``); returns
+    ``Adam._apply_one`` is its one caller: the eager ``step()`` and all
+    three compiled trainers reach it through the class.  Returns
     ``(new_value_f32, new_m_stored, new_v_stored)``.
     """
     g32 = grad.astype(jnp.float32)
@@ -60,10 +65,10 @@ class Adam(Optimizer):
 
     ``moment_dtype='bfloat16'`` stores m/v in bf16 (compute stays f32) —
     an optax ``mu_dtype``-style TPU option the reference lacks: halves the
-    optimizer state's HBM traffic and capacity on HBM-bound updates
-    (+26% on the GPT-3 1.3B row in round 3, old toolchain; not
-    re-measured on the current one).  Default f32 matches the
-    reference's fused adam bit-for-bit behavior class.
+    optimizer state's HBM traffic and capacity (the benchmark's
+    ``gpt3-1.3b`` cell trains with it, ``gpt2-medium`` with the default;
+    PERF.md has their numbers).  Default f32 matches the reference's
+    fused adam bit-for-bit behavior class.
     """
 
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
@@ -75,8 +80,7 @@ class Adam(Optimizer):
         self._beta1 = beta1
         self._beta2 = beta2
         self._eps = epsilon
-        self._moment_dtype = (jnp.float32 if moment_dtype is None
-                              else jnp.dtype(moment_dtype))
+        self._moment_dtype = _moment_dtype(moment_dtype)
 
     def _init_accumulators(self, p):
         return {"moment1": jnp.zeros(p._value.shape, self._moment_dtype),
@@ -89,8 +93,43 @@ class Adam(Optimizer):
         return new_v, {"moment1": m, "moment2": u}
 
 
-class AdamW(Adam):
-    """AdamW with decoupled weight decay (ref ``optimizer/adamw.py``)."""
+class _PerParamDecayMixin:
+    """Per-parameter weight-decay exclusion (AdamW's
+    ``apply_decay_param_fun``, LAMB's and LARS's exclusion lists).
+
+    ``_apply_one`` has no access to the parameter identity, so the step is
+    intercepted to precompute a decay on/off flag per live parameter (in
+    the same trainable+has-grad order the base ``step`` uses) and
+    ``_apply_one`` consumes them positionally at trace time — the flags
+    are Python constants baked into the compiled update, and the jit
+    cache key (param ids) already guards staleness."""
+
+    def _decay_excluded(self, p) -> bool:
+        raise NotImplementedError
+
+    def step(self):
+        self._wd_on = tuple(
+            not self._decay_excluded(p) for p in self._parameter_list
+            if p.trainable and p._grad_value is not None)
+        super().step()
+
+    def _prepare_functional(self, params):
+        super()._prepare_functional(params)
+        self._wd_on = (() if params is None else
+                       tuple(not self._decay_excluded(p) for p in params))
+
+    def _apply_rules(self, vals, grads, states, lr, step_t, param_lrs):
+        flags = getattr(self, "_wd_on", ())
+        self._wd_iter = iter(flags if len(flags) == len(vals)
+                             else (True,) * len(vals))
+        return super()._apply_rules(vals, grads, states, lr, step_t,
+                                    param_lrs)
+
+
+class AdamW(_PerParamDecayMixin, Adam):
+    """AdamW with decoupled weight decay (ref ``optimizer/adamw.py``);
+    parameters whose name ``apply_decay_param_fun`` rejects (biases,
+    LayerNorm) take plain Adam."""
 
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
                  epsilon=1e-8, parameters=None, weight_decay=0.01,
@@ -103,52 +142,19 @@ class AdamW(Adam):
         self._wd_coeff = float(weight_decay) if not hasattr(
             weight_decay, "coeff") else weight_decay.coeff
         self._apply_decay_param_fun = apply_decay_param_fun
-        self._decay_mask = None
 
     def _decoupled_weight_decay(self):
         return True
 
-    def step(self):
-        if self._apply_decay_param_fun is not None and self._decay_mask is None:
-            self._decay_mask = {
-                id(p): bool(self._apply_decay_param_fun(p.name))
-                for p in self._parameter_list}
-        super().step()
-
-    def _prepare_functional(self, params):
-        # functional callers (compiled trainers) supply the param order
-        # explicitly — nothing carries ``_grad_value`` under a trace
-        self._functional_plist = params
-        if params is not None and self._apply_decay_param_fun is not None \
-                and self._decay_mask is None:
-            self._decay_mask = {
-                id(p): bool(self._apply_decay_param_fun(p.name))
-                for p in params}
+    def _decay_excluded(self, p):
+        fn = self._apply_decay_param_fun
+        return fn is not None and not fn(p.name)
 
     def _apply_one(self, v, g, s, lr, step_t):
         new_v, ns = super()._apply_one(v, g, s, lr, step_t)
-        decay = self._wd_coeff
-        new_v = new_v - lr * decay * v.astype(jnp.float32)
+        if next(self._wd_iter, True):
+            new_v = new_v - lr * self._wd_coeff * v.astype(jnp.float32)
         return new_v, ns
-
-    def _update_all(self, vals, grads, states, lr, step_t, param_lrs):
-        if self._decay_mask is not None:
-            # parameters excluded from decay (e.g. biases/LN) use plain Adam
-            params = getattr(self, "_functional_plist", None) or [
-                p for p in self._parameter_list
-                if p.trainable and p._grad_value is not None]
-            new_vals, new_states = [], []
-            if self._grad_clip is not None:
-                grads = self._grad_clip._clip(grads)
-            for p, v, g, s, plr in zip(params, vals, grads, states, param_lrs):
-                g32 = g.astype(jnp.float32)
-                nv, ns = Adam._apply_one(self, v, g32, s, lr * plr, step_t)
-                if self._decay_mask.get(id(p), True):
-                    nv = nv - lr * plr * self._wd_coeff * v.astype(jnp.float32)
-                new_vals.append(nv.astype(v.dtype))
-                new_states.append(ns)
-            return new_vals, new_states
-        return super()._update_all(vals, grads, states, lr, step_t, param_lrs)
 
 
 class Adagrad(Optimizer):
@@ -247,46 +253,18 @@ class Adamax(Optimizer):
         return new_v, {"moment": m, "inf_norm": inf}
 
 
-class _PerParamDecayMixin:
-    """Per-parameter weight-decay exclusion for layer-adaptive rules.
-
-    ``_apply_one`` has no access to the parameter identity, so the step is
-    intercepted to precompute a decay on/off flag per live parameter (in
-    the same trainable+has-grad order the base ``step`` uses) and
-    ``_apply_one`` consumes them positionally at trace time — the flags
-    are Python constants baked into the compiled update, and the jit
-    cache key (param ids) already guards staleness."""
-
-    def _decay_excluded(self, p) -> bool:
-        raise NotImplementedError
-
-    def step(self):
-        self._wd_on = tuple(
-            not self._decay_excluded(p) for p in self._parameter_list
-            if p.trainable and p._grad_value is not None)
-        super().step()
-
-    def _prepare_functional(self, params):
-        self._wd_on = (() if params is None else
-                       tuple(not self._decay_excluded(p) for p in params))
-
-    def _update_all(self, vals, grads, states, lr, step_t, param_lrs):
-        flags = getattr(self, "_wd_on", ())
-        self._wd_iter = iter(flags if len(flags) == len(vals)
-                             else (True,) * len(vals))
-        return super()._update_all(vals, grads, states, lr, step_t,
-                                   param_lrs)
-
-
 class Lamb(_PerParamDecayMixin, Optimizer):
     """LAMB (ref ``optimizer/lamb.py``; fused-sharded variant
-    ``incubate/optimizer/distributed_fused_lamb.py:86``)."""
+    ``incubate/optimizer/distributed_fused_lamb.py:86``).
+    ``moment_dtype`` as on :class:`Adam`."""
+
+    _per_param_norm = True
 
     def __init__(self, learning_rate=0.001,
                  lamb_weight_decay=None, beta1=None,
                  beta2=None, epsilon=None, parameters=None, grad_clip=None,
                  exclude_from_weight_decay_fn=None, multi_precision=False,
-                 name=None):
+                 moment_dtype=None, name=None):
         lamb_weight_decay = (LAMB_DEFAULTS["lamb_weight_decay"]
                              if lamb_weight_decay is None
                              else lamb_weight_decay)
@@ -298,19 +276,20 @@ class Lamb(_PerParamDecayMixin, Optimizer):
         self._wd = lamb_weight_decay
         self._beta1, self._beta2, self._eps = beta1, beta2, epsilon
         self._exclude_fn = exclude_from_weight_decay_fn
+        self._moment_dtype = _moment_dtype(moment_dtype)
 
     def _decay_excluded(self, p):
         return bool(self._exclude_fn(p)) if self._exclude_fn else False
 
     def _init_accumulators(self, p):
-        return {"moment1": jnp.zeros(p._value.shape, jnp.float32),
-                "moment2": jnp.zeros(p._value.shape, jnp.float32)}
+        return {"moment1": jnp.zeros(p._value.shape, self._moment_dtype),
+                "moment2": jnp.zeros(p._value.shape, self._moment_dtype)}
 
     def _apply_one(self, v, g, s, lr, step_t):
         wd = self._wd if next(self._wd_iter, True) else 0.0
         new_v, m, u = lamb_update(v, g, s["moment1"], s["moment2"], lr,
                                   step_t, self._beta1, self._beta2,
-                                  self._eps, wd)
+                                  self._eps, wd, self._moment_dtype)
         return new_v, {"moment1": m, "moment2": u}
 
 
@@ -318,13 +297,13 @@ def lamb_update(value, grad, m, v, lr, t, beta1, beta2, eps, wd,
                 moment_dtype=jnp.float32):
     """One LAMB tensor update — THE single owner of the update math (ref
     ``optimizer/lamb.py``; the sharded-trust-ratio contract of
-    ``incubate/optimizer/distributed_fused_lamb.py:86``).  Used by the
-    eager :class:`Lamb` and the sharded train step (``parallel/api.py``
-    ``optimizer="lamb"``) — there the param/update norms are computed on
-    the *logical* arrays, so under zero_stage=3 sharding XLA inserts the
-    cross-shard reductions automatically: the trust ratio is globally
-    correct by construction, which is the entire point of the reference's
-    hand-fused distributed LAMB.  Returns
+    ``incubate/optimizer/distributed_fused_lamb.py:86``).
+    :class:`Lamb` is its one caller.  Inside a compiled trainer the
+    param/update norms are computed on the *logical* arrays, so under
+    ZeRO / zero_stage=3 / TP sharding XLA inserts the cross-shard
+    reductions automatically: the trust ratio is globally correct by
+    construction, which is the entire point of the reference's hand-fused
+    distributed LAMB.  Returns
     (new_value_f32, new_m_stored, new_v_stored)."""
     g32 = grad.astype(jnp.float32)
     w32 = value.astype(jnp.float32)
@@ -343,9 +322,8 @@ def lamb_update(value, grad, m, v, lr, t, beta1, beta2, eps, wd,
 
 # THE single home of the LARS/LAMB hyperparameter defaults (ref
 # lars_momentum_op.cc attribute defaults; optimizer/lamb.py) — consulted
-# by the eager classes, fleet's strategy configs/_swap_update_rule, and
-# the sharded train step so the same nominal configuration means the
-# same numbers on every path.
+# by the classes and fleet's strategy configs/_swap_update_rule, so the
+# same nominal configuration means the same numbers on every path.
 LARS_DEFAULTS = {"momentum": 0.9, "lars_coeff": 0.001,
                  "lars_weight_decay": 0.0005, "epsilon": 0.0}
 LAMB_DEFAULTS = {"beta1": 0.9, "beta2": 0.999, "epsilon": 1e-6,
@@ -362,9 +340,8 @@ def lars_update(value, grad, velocity, lr, momentum, lars_coeff, lars_wd,
         velocity = mu * velocity + local_lr * (g + wd * w)
         w       -= velocity
 
-    Shared by the eager :class:`Lars` and the sharded train step
-    (``parallel/api.py``) so fleet's ``lars=True`` means the same rule in
-    both paths.  All math in f32; returns (new_value_f32, new_velocity).
+    :class:`Lars` is its one caller.  All math in f32; returns
+    (new_value_f32, new_velocity).
     """
     g32 = grad.astype(jnp.float32)
     v32 = value.astype(jnp.float32)
@@ -383,7 +360,10 @@ class Lars(_PerParamDecayMixin, Optimizer):
     (ref ``fleet/meta_optimizers/lars_optimizer.py`` +
     ``operators/optimizers/lars_momentum_op.cc``; You et al. 2017).
     ``fleet.distributed_optimizer`` swaps a Momentum optimizer to this
-    class when ``strategy.lars`` is set."""
+    class when ``strategy.lars`` is set.  ``moment_dtype`` stores the
+    velocity narrower (the math stays f32), as on :class:`Adam`."""
+
+    _per_param_norm = True
 
     def __init__(self, learning_rate=0.001,
                  momentum=LARS_DEFAULTS["momentum"],
@@ -391,9 +371,10 @@ class Lars(_PerParamDecayMixin, Optimizer):
                  lars_weight_decay=LARS_DEFAULTS["lars_weight_decay"],
                  epsilon=LARS_DEFAULTS["epsilon"], parameters=None,
                  grad_clip=None, exclude_from_weight_decay=None,
-                 multi_precision=False, name=None):
+                 multi_precision=False, moment_dtype=None, name=None):
         super().__init__(learning_rate, parameters, None, grad_clip,
                          multi_precision, name)
+        self._moment_dtype = _moment_dtype(moment_dtype)
         self._momentum = momentum
         self._coeff = lars_coeff
         self._lars_wd = lars_weight_decay
@@ -420,13 +401,14 @@ class Lars(_PerParamDecayMixin, Optimizer):
         return any(s in pname for s in self._exclude)
 
     def _init_accumulators(self, p):
-        return {"velocity": jnp.zeros(p._value.shape, jnp.float32)}
+        return {"velocity": jnp.zeros(p._value.shape, self._moment_dtype)}
 
     def _apply_one(self, v, g, s, lr, step_t):
         wd = self._lars_wd if next(self._wd_iter, True) else 0.0
-        new_v, vel = lars_update(v, g, s["velocity"], lr, self._momentum,
-                                 self._coeff, wd, self._eps)
-        return new_v, {"velocity": vel}
+        new_v, vel = lars_update(v, g, s["velocity"].astype(jnp.float32),
+                                 lr, self._momentum, self._coeff, wd,
+                                 self._eps)
+        return new_v, {"velocity": vel.astype(self._moment_dtype)}
 
 
 LarsMomentum = Lars  # the reference exposes both spellings

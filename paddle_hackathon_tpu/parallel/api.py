@@ -341,8 +341,10 @@ def make_functional_train_step(optimizer, plist, order, grads_of,
             -> (new_params, new_opt_states, new_step, loss)
 
     — THE single owner of the forward+backward+update step body, shared
-    by ``auto_parallel.Engine`` (per-batch SPMD program, gradient merge)
-    and ``hapi.Model``'s compiled fit path (K-step ``lax.scan`` unroll).
+    by ``auto_parallel.Engine`` (per-batch SPMD program, gradient merge),
+    ``hapi.Model``'s compiled fit path (K-step ``lax.scan`` unroll) and
+    ``make_sharded_train_step`` (whose ``grads_of`` returns what it has
+    already differentiated from its own frame).
 
     - ``grads_of(params, xs, ys, step) -> (loss, grads)``, grads keyed
       like ``params``; ``order`` maps ``plist`` (the optimizer's ordered
@@ -439,6 +441,43 @@ def make_functional_train_step(optimizer, plist, order, grads_of,
     return train_step
 
 
+# optimizer="adam" | "lamb" | "lars" is shorthand for a class of
+# ``optimizer/`` built with ``optimizer_kwargs``, ``moment_dtype`` and a
+# global-norm clip at ``grad_clip_norm``: the class, and the defaults the
+# shorthand has that the class has not (adam: the LM-pretraining beta2)
+_SHORTHAND = {"adam": ("Adam", {"beta2": 0.95}),
+              "lamb": ("Lamb", {}), "lars": ("Lars", {})}
+
+# the sharded step's state names its slots ``m`` / ``v`` (lars: ``m``), as
+# the benchmark and the checkpoints of it read them; ``optimizer/`` keeps
+# the reference's names, which ``state_dict`` and the flat ``opt::i::slot``
+# checkpoint layout of the other two trainers carry
+_SLOT_NAMES = {"moment1": "m", "moment2": "v", "velocity": "m"}
+
+
+def _resolve_optimizer(optimizer, kwargs, learning_rate, moment_dtype,
+                       grad_clip_norm, parameters):
+    from .. import optimizer as _opt
+    from ..nn.clip import ClipGradByGlobalNorm
+    if isinstance(optimizer, _opt.Optimizer):
+        if kwargs or moment_dtype is not None:
+            raise ValueError(
+                "optimizer_kwargs / moment_dtype belong to the "
+                "adam/lamb/lars shorthand; an Optimizer instance brings its "
+                "own hyper-parameters, moment dtype and grad_clip")
+        return optimizer
+    kind = str(optimizer).lower()
+    if kind not in _SHORTHAND:
+        raise ValueError("optimizer must be adam/lamb/lars or an Optimizer "
+                         f"instance, got {optimizer}")
+    cls, defaults = _SHORTHAND[kind]
+    return getattr(_opt, cls)(
+        learning_rate=learning_rate, parameters=parameters,
+        grad_clip=None if grad_clip_norm is None
+        else ClipGradByGlobalNorm(grad_clip_norm),
+        moment_dtype=moment_dtype, **{**defaults, **(kwargs or {})})
+
+
 def make_sharded_train_step(model: Layer, mesh: Mesh,
                             rule: Optional[Callable] = None,
                             learning_rate: float = 1e-4,
@@ -451,15 +490,26 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
                             pp_microbatches: Optional[int] = None,
                             moment_dtype=None,
                             sp_mode: str = "auto",
-                            optimizer: str = "adam",
+                            optimizer="adam",
                             optimizer_kwargs: Optional[dict] = None,
                             master_weights: bool = False,
                             zero_offload: bool = False,
                             grad_overlap: bool = False,
                             offload_depth: int = 2):
     """Build (step_fn, state) — one compiled SPMD program per step covering
-    forward, backward, grad psum over dp, Adam update on (optionally
-    'sharding'/'dp'-sharded) optimizer state.
+    forward, backward, grad psum over dp, and the optimizer's update on
+    (optionally 'sharding'/'dp'-sharded) optimizer state.
+
+    This function owns placement, the pp / sp / rng / recompute forward,
+    jit + donation, the state's layout and the host-side ``step``.  The
+    step body (grads -> clip -> update) is ``make_functional_train_step``
+    over ``Optimizer.functional_update``, the one the other two trainers
+    compile.  ``optimizer`` is an ``Optimizer`` instance (``AdamW`` with
+    its decay mask, a ``Lamb`` with its exclusions, ...: its own
+    ``grad_clip`` and learning rate hold, ``step(lr=)`` overrides the
+    rate per call), or the shorthand ``"adam"`` / ``"lamb"`` / ``"lars"``
+    built from ``optimizer_kwargs``, ``moment_dtype``, ``learning_rate``
+    and a global-norm clip at ``grad_clip_norm`` (``_SHORTHAND``).
 
     ``zero_stage=None`` (default) means stage 1 wherever the mesh has a
     data axis; ``zero_stage>=1`` shards the OPTIMIZER STATE over the ZeRO
@@ -584,7 +634,9 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
     # longer replicates the moments.  An EXPLICIT zero_stage>=1 on a mesh
     # with no data axis warns — keeping dp full copies after an explicit
     # ask must never be silent (same rule as Engine/Model.fit)
-    from .sharding import observe_opt_state_bytes, zero_data_axis
+    from ..optimizer.optimizer import StackedParameter
+    from .sharding import (ZeroShardInfo, observe_opt_state_bytes,
+                           place_zero_state, zero_data_axis)
     zaxis = zero_data_axis(mesh)
     zero_on = zero_stage >= 1 and zaxis is not None
     if zero_explicit and zero_stage >= 1 and zaxis is None:
@@ -604,65 +656,6 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
             stacklevel=2)
     if grad_overlap and not zero_on:
         grad_overlap = False  # nothing to scatter onto — inert
-
-    def opt_state_spec(name, arr):
-        if pp_degree > 1 and name.startswith(
-                pp_spec["block_prefix"] + "$stacked."):
-            rel = name[len(pp_spec["block_prefix"]) + len("$stacked."):]
-            spec = _pp_stacked_spec(rel, arr, mesh, rule,
-                                    pp_spec["block_prefix"], zero_on,
-                                    axis=zaxis or "sharding")
-            return NamedSharding(mesh, P(*spec))
-        spec = list(rule(name, arr.shape)) if rule else [None] * arr.ndim
-        spec = list(_filter_spec(spec, mesh))
-        if zero_on:
-            spec = list(_shard_spec_for(arr.shape, mesh, axis=zaxis,
-                                        existing=spec))
-        return NamedSharding(mesh, P(*spec))
-
-    # moment_dtype=jnp.bfloat16 stores Adam m/v in bf16 (compute stays
-    # f32) — optax mu_dtype-style; on HBM-bound updates this cuts the
-    # optimizer's traffic by ~8 bytes/param and frees 8 bytes/param of
-    # capacity.  Default f32 matches the reference's fused adam exactly.
-    opt_kind = optimizer.lower()
-    if opt_kind not in ("adam", "lamb", "lars"):
-        raise ValueError(f"optimizer must be adam/lamb/lars, got {optimizer}")
-    okw = dict(optimizer_kwargs or {})
-    mdt = jnp.float32 if moment_dtype is None else jnp.dtype(moment_dtype)
-    # lars keeps a single velocity slot; adam/lamb keep two moments
-    slots = ("m",) if opt_kind == "lars" else ("m", "v")
-    m_sh = {k: opt_state_spec(k, v) for k, v in params.items()}
-
-    def _init_slots(k, v):
-        st = {s: jax.device_put(jnp.zeros(v.shape, mdt), m_sh[k])
-              for s in slots}
-        if master_weights and jnp.issubdtype(v.dtype, jnp.floating):
-            # f32 master copy sharded like the moments; the bf16 compute
-            # param is re-derived from it every step by cast + gather
-            from .sharding import master_copy
-            st["master"] = jax.device_put(master_copy(v), m_sh[k])
-        return st
-
-    def _init_slots_host(k, v):
-        # offload: same slots, same zeros, same f32 master values — just
-        # parked in host RAM (the h2d stream scatters them to m_sh[k]
-        # while each tensor's update is in flight)
-        st = {s: np.zeros(v.shape, mdt) for s in slots}
-        if master_weights and jnp.issubdtype(v.dtype, jnp.floating):
-            st["master"] = np.asarray(v).astype(np.float32)
-        return st
-
-    if offload_on:
-        opt_state = {k: _init_slots_host(k, v) for k, v in params.items()}
-        observe_opt_state_bytes("sharded_step", {}, host_tree=opt_state)
-    else:
-        opt_state = {k: _init_slots(k, v) for k, v in params.items()}
-        observe_opt_state_bytes("sharded_step", opt_state)
-    # placed like the jitted step returns it: an unplaced counter made
-    # call 2 see a different argument sharding and compile the whole
-    # step a second time (the program observatory's first finding)
-    step_no = jax.device_put(jnp.zeros((), jnp.int32),
-                             NamedSharding(mesh, P()))
 
     if pp_degree > 1:
         loss_fn = _make_pipeline_loss(
@@ -687,61 +680,69 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
                 loss = loss + moe_aux_weight(model) * aux
             return loss
 
-    from ..optimizer.optimizers import LAMB_DEFAULTS, LARS_DEFAULTS
-    if opt_kind == "adam":
-        # the LM-pretraining adam defaults this step has always used
-        b1, b2, eps = (float(okw.get("beta1", 0.9)),
-                       float(okw.get("beta2", 0.95)),
-                       float(okw.get("epsilon", 1e-8)))
+    opt = _resolve_optimizer(optimizer, optimizer_kwargs, learning_rate,
+                             moment_dtype, grad_clip_norm,
+                             list(model.parameters()))
+    # what optimizer/ is told of each leaf: the model's own Parameter, or
+    # for a pp-stacked (L, ...) block layer 0's, marked as stacked
+    param_tensors = dict(model.named_parameters())
+    order = sorted(params)
+    stacked_head = pp_spec["block_prefix"] + "$stacked." if pp_spec else None
+
+    def _meta(k):
+        if stacked_head and k.startswith(stacked_head):
+            return StackedParameter(
+                param_tensors[pp_spec["block_prefix"] + "0."
+                              + k[len(stacked_head):]], params[k])
+        return param_tensors[k]
+
+    plist = [_meta(k) for k in order]
+    # the update's placement: every slot extends its parameter's own spec
+    # (TP dims, 'pp' on a stacked leaf) by the ZeRO axis; with no ZeRO
+    # axis it is the parameter's own spec, which also carries the master
+    # slot.  The update takes the pinned path only when there is
+    # something to pin.
+    si = ZeroShardInfo(
+        mesh=mesh, axis=zaxis if zero_on else None, stage=zero_stage,
+        master_weights=bool(master_weights)).with_param_specs([
+            tuple(params[k].sharding.spec) + (None,) * (
+                params[k].ndim - len(params[k].sharding.spec))
+            for k in order])
+    update_si = si if (zero_on or master_weights) else None
+    if offload_on:
+        # moments (+ f32 masters) parked in host RAM; the step splits into
+        # a grads-only device program and the streamed per-tensor update
+        from .offload import ZeroOffloadUpdater
+        updater = ZeroOffloadUpdater.for_optimizer(
+            opt, plist, si, depth=offload_depth,
+            site="parallel.zero_offload")
+        slots = ZeroOffloadUpdater.host_state_for_optimizer(opt, plist, si)
     else:
-        b1 = float(okw.get("beta1", LAMB_DEFAULTS["beta1"]))
-        b2 = float(okw.get("beta2", LAMB_DEFAULTS["beta2"]))
-        eps = float(okw.get(
-            "epsilon", LAMB_DEFAULTS["epsilon"] if opt_kind == "lamb"
-            else LARS_DEFAULTS["epsilon"]))
-    lamb_wd = float(okw.get("lamb_weight_decay",
-                            LAMB_DEFAULTS["lamb_weight_decay"]))
-    lars_mu = float(okw.get("momentum", LARS_DEFAULTS["momentum"]))
-    lars_coeff = float(okw.get("lars_coeff", LARS_DEFAULTS["lars_coeff"]))
-    lars_wd = float(okw.get("lars_weight_decay",
-                            LARS_DEFAULTS["lars_weight_decay"]))
+        slots = place_zero_state(
+            si, [params[k] for k in order],
+            [opt._init_accumulators(p) for p in plist])
+    # the state's slot names are the short ones (_SLOT_NAMES); optimizer/
+    # reads and writes its own, renamed at trace time
+    full = {_SLOT_NAMES.get(s, s): s for s in slots[0]}
 
-    def _is_stacked(k):
-        return pp_degree > 1 and k.startswith(
-            pp_spec["block_prefix"] + "$stacked.")
+    def _short(st):
+        return {_SLOT_NAMES.get(s, s): v for s, v in st.items()}
 
-    def _apply_update(k, p, g, st, lr, t):
-        """One tensor's update.  adam is elementwise; lamb/lars compute
-        per-PARAMETER norms, so pp-stacked (L, ...) blocks vmap the rule
-        over the layer dim — a stack-wide norm would silently change the
-        trust ratio (the reference computes it per parameter:
-        distributed_fused_lamb.py:86 trust-ratio-div).  Under zero3/TP
-        sharding the norms run on the logical arrays and XLA inserts the
-        cross-shard reductions — globally correct trust ratios with no
-        hand-fused kernel."""
-        from ..optimizer.optimizers import (adam_update, lamb_update,
-                                            lars_update)
-        if opt_kind == "adam":
-            nv, m, v = adam_update(p, g, st["m"], st["v"], lr, t,
-                                   b1, b2, eps, mdt)
-            return nv, {"m": m, "v": v}
-        if opt_kind == "lamb":
-            fn = lambda p_, g_, m_, v_: lamb_update(
-                p_, g_, m_, v_, lr, t, b1, b2, eps, lamb_wd, mdt)
-            if _is_stacked(k):
-                fn = jax.vmap(fn)
-            nv, m, v = fn(p, g, st["m"], st["v"])
-            return nv, {"m": m, "v": v}
-        fn = lambda p_, g_, vel_: lars_update(
-            p_, g_, vel_, lr, lars_mu, lars_coeff, lars_wd, eps)
-        if _is_stacked(k):
-            fn = jax.vmap(fn)
-        nv, vel = fn(p, g, st["m"].astype(jnp.float32))
-        return nv, {"m": vel.astype(mdt)}
+    def _full(st):
+        return {full.get(s, s): v for s, v in st.items()}
 
-    param_shardings = {k: a.sharding for k, a in params.items()}
+    opt_state = dict(zip(order, map(_short, slots)))
+    if offload_on:
+        observe_opt_state_bytes("sharded_step", {}, host_tree=opt_state)
+    else:
+        observe_opt_state_bytes("sharded_step", opt_state)
+    # placed like the jitted step returns it: an unplaced counter made
+    # call 2 see a different argument sharding and compile the whole
+    # step a second time (the program observatory's first finding)
+    step_no = jax.device_put(jnp.zeros((), jnp.int32),
+                             NamedSharding(mesh, P()))
 
-    def train_step(params, opt_state, step_no, batch, rng, lr):
+    def loss_of(batch, rng):
         def pure_loss(p):
             return loss_fn(model, p, buffers, batch, rng)
 
@@ -750,67 +751,37 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
             # auto_parallel_recompute pass) — XLA re-runs it in backward.
             from .recompute import jit_recompute
             pure_loss = jit_recompute(pure_loss, policy=recompute_policy)
-        loss, grads = jax.value_and_grad(pure_loss)(params)
-        if zero_on and grad_overlap:
-            # overlap schedule: pin every grad to its moment sharding
-            # the moment the backward produces it — per-tensor
-            # reduce-scatters with no dependence on the clip scalar, so
-            # the scheduler interleaves them with the remaining backward
-            # compute; the clip norm below then reduces over the
-            # SCATTERED shards (reassociated — series tolerance vs the
-            # default order, which clips first and stays bit-exact)
-            grads = {k: jax.lax.with_sharding_constraint(g, m_sh[k])
-                     for k, g in grads.items()}
-        if grad_clip_norm is not None:
-            # without grad_overlap the global clip norm is computed
-            # BEFORE the ZeRO grad pins (on the replicated grads) so
-            # sharded-vs-replicated runs clip by the bit-identical scale
-            with jax.named_scope("clip"):
-                gnorm = jnp.sqrt(sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in jax.tree.leaves(grads)))
-                scale = grad_clip_norm / jnp.maximum(gnorm, grad_clip_norm)
-                grads = jax.tree.map(lambda g: g * scale.astype(g.dtype),
-                                     grads)
-        t = step_no + 1
-        new_params, new_opt = {}, {}
-        with jax.named_scope("update"):
-            for k in params:
-                g, st = grads[k], opt_state[k]
-                st = dict(st)
-                master = st.pop("master", None)
-                if zero_on:
-                    # ZeRO pins, per tensor: the pending dp grad psum fuses
-                    # with the slice into a reduce-scatter; moments stay on
-                    # their 1/dp slice in AND out (GSPMD cannot re-replicate
-                    # them); the updated param casts to the compute dtype
-                    # FIRST and then gathers back to its own sharding — an
-                    # independent per-tensor all-gather the scheduler
-                    # overlaps with the other params' update compute
-                    msh = m_sh[k]
+        return pure_loss
 
-                    def wsc(a, _m=msh):
-                        return jax.lax.with_sharding_constraint(a, _m)
+    # Both programs differentiate from their OWN frame and hand the shared
+    # body the result, instead of letting it call back into the forward:
+    # on the chip's host every Python frame between the jit and the model's
+    # forward cost the trace of a 24-layer step 0.3 s and more (PERF.md §6,
+    # PR 30: three frames, +0.85 to +3.0 s of set-up).
 
-                    g = wsc(g)
-                    st = {s: wsc(v) for s, v in st.items()}
-                    p_upd = wsc(master) if master is not None \
-                        else wsc(params[k])
-                else:
-                    p_upd = master if master is not None else params[k]
-                new_v, new_st = _apply_update(k, p_upd, g, st, lr, t)
-                if zero_on:
-                    new_st = {s: wsc(v) for s, v in new_st.items()}
-                if master is not None:
-                    # the f32 master never leaves its shard
-                    new_st["master"] = wsc(new_v) if zero_on else new_v
-                nv = new_v.astype(params[k].dtype)
-                if zero_on:
-                    nv = jax.lax.with_sharding_constraint(nv,
-                                                          param_shardings[k])
-                new_params[k] = nv
-                new_opt[k] = new_st
-        return new_params, new_opt, step_no + 1, loss
+    def train_step(params, opt_state, step_no, batch, rng, lr):
+        loss_grads = jax.value_and_grad(loss_of(batch, rng))(params)
+        # the shared body: [overlap pins] -> clip -> update
+        body = make_functional_train_step(
+            opt, plist, order, lambda *_: loss_grads, shard_info=update_si,
+            grad_overlap=grad_overlap)
+        new_params, new_states, new_step, loss = body(
+            params, [_full(opt_state[k]) for k in order], step_no, lr, batch)
+        return (new_params, dict(zip(order, map(_short, new_states))),
+                new_step, loss)
+
+    def grads_step(params, step_no, batch, rng, lr):
+        # offload's device half: forward + backward + the grad preamble on
+        # the replicated gradients (the resident ZeRO step's own), no update
+        loss, grads = jax.value_and_grad(loss_of(batch, rng))(params)
+        gs = [grads[k] for k in order]
+        if grad_overlap:
+            gs = [jax.lax.with_sharding_constraint(g, sh)
+                  for g, sh in zip(gs, updater.state_shardings)]
+        gs = opt.preprocess_grads_offload(
+            [params[k] for k in order], gs,
+            master_weights=bool(master_weights))
+        return loss, gs, step_no + 1
 
     bspec = batch_spec(mesh)
     if sp_degree > 1:
@@ -828,19 +799,38 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
         # jit_builds_total{site=parallel.sharded_train_step} — a step that
         # silently recompiles mid-run shows up in telemetry, not just as a
         # mystery stall
-        from ..observability.sanitizers import sanitize_donation
-        return sanitize_donation(_obs.instrument_jit(jax.jit(
-            train_step,
-            donate_argnums=(0, 1, 2),
-            in_shardings=(param_sh, opt_sh, scalar_sh, batch_sh, None, None),
-            # pin output shardings to the input layout — without this XLA may
-            # pick a different layout for the updated params, forcing a
-            # re-jit (and a second full compile) on the next step.
-            out_shardings=(param_sh, opt_sh, scalar_sh, scalar_sh),
-        ), site="parallel.sharded_train_step"),
-            donate_argnums=(0, 1, 2), site="parallel.sharded_train_step")
+        site = "parallel.sharded_train_step"
+        if not offload_on:
+            from ..observability.sanitizers import sanitize_donation
+            return sanitize_donation(_obs.instrument_jit(jax.jit(
+                train_step,
+                donate_argnums=(0, 1, 2),
+                in_shardings=(param_sh, opt_sh, scalar_sh, batch_sh, None,
+                              None),
+                # pin output shardings to the input layout — without this
+                # XLA may pick a different layout for the updated params,
+                # forcing a re-jit (and a second full compile) on the next
+                # step.
+                out_shardings=(param_sh, opt_sh, scalar_sh, scalar_sh),
+            ), site=site), donate_argnums=(0, 1, 2), site=site)
+        grads_jitted = _obs.instrument_jit(jax.jit(
+            grads_step,
+            in_shardings=(param_sh, scalar_sh, batch_sh, None, None),
+            out_shardings=(scalar_sh, [param_sh[k] for k in order],
+                           scalar_sh)), site=site)
 
-    jitted = None if offload_on else _make_jitted(
+        def streamed(params, opt_state, step_no, batch, rng, lr):
+            loss, gs, t = grads_jitted(params, step_no, batch, rng, lr)
+            new_vals, new_host = updater.apply(
+                [params[k] for k in order], gs,
+                [_full(opt_state[k]) for k in order], lr, t)
+            return (dict(zip(order, new_vals)),
+                    dict(zip(order, map(_short, new_host))), t, loss)
+
+        streamed._jit_fn = grads_jitted._jit_fn
+        return streamed
+
+    jitted = _make_jitted(
         (NamedSharding(mesh, bspec), NamedSharding(mesh, bspec)))
 
     # Batch elements may be pytrees (e.g. (ids, masked_positions) feeding a
@@ -860,7 +850,6 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
         return _jit_cache[key]
 
     state = {"params": params, "opt_state": opt_state, "step": step_no}
-    param_tensors = dict(model.named_parameters())
     host_steps = itertools.count()
 
     def step(state, ids, labels, rng, lr=None):
@@ -881,7 +870,7 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
         # things this function does on the host
         with jax.profiler.StepTraceAnnotation("train_step",
                                               step_num=next(host_steps)):
-            lr_now = jnp.float32(learning_rate if lr is None else lr)
+            lr_now = jnp.float32(opt.get_lr() if lr is None else lr)
             fn = jitted if (hasattr(ids, "ndim")
                             and hasattr(labels, "ndim")) \
                 else _get_jitted((ids, labels))
@@ -919,122 +908,9 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
                 for i in range(pp_spec["num_layers"]):
                     param_tensors[f"{prefix}{i}.{rel}"]._set_value(v[i])
 
-    if offload_on:
-        from .offload import ZeroOffloadUpdater
-        key_order = list(params)
-
-        def grads_step(params_, step_no_, batch, rng, lr):
-            def pure_loss(p):
-                return loss_fn(model, p, buffers, batch, rng)
-
-            if recompute:
-                from .recompute import jit_recompute
-                pure_loss = jit_recompute(pure_loss,
-                                          policy=recompute_policy)
-            loss, grads = jax.value_and_grad(pure_loss)(params_)
-            if grad_overlap:
-                # same overlap schedule as the resident step: per-tensor
-                # scatter pins before the clip (series tolerance)
-                grads = {k: jax.lax.with_sharding_constraint(g, m_sh[k])
-                         for k, g in grads.items()}
-            if grad_clip_norm is not None:
-                # replicated-grads global clip — the bit-identical
-                # preamble of the resident (non-overlap) ZeRO step
-                with jax.named_scope("clip"):
-                    gnorm = jnp.sqrt(sum(
-                        jnp.sum(jnp.square(g.astype(jnp.float32)))
-                        for g in jax.tree.leaves(grads)))
-                    scale = grad_clip_norm / jnp.maximum(gnorm,
-                                                         grad_clip_norm)
-                    grads = jax.tree.map(
-                        lambda g: g * scale.astype(g.dtype), grads)
-            return loss, grads, step_no_ + 1
-
-        def _offload_tensor_update(i, p, g, st, lr, t):
-            # the EXACT per-tensor body of the resident train_step's
-            # update loop — bit-exact offload is this sharing
-            k = key_order[i]
-            st = dict(st)
-            master = st.pop("master", None)
-            msh = m_sh[k]
-
-            def wsc(a, _m=msh):
-                return jax.lax.with_sharding_constraint(a, _m)
-
-            g = wsc(g)
-            st = {s: wsc(v) for s, v in st.items()}
-            p_upd = wsc(master) if master is not None else wsc(p)
-            new_v, new_st = _apply_update(k, p_upd, g, st, lr, t)
-            new_st = {s: wsc(v) for s, v in new_st.items()}
-            if master is not None:
-                new_st["master"] = wsc(new_v)
-            nv = jax.lax.with_sharding_constraint(
-                new_v.astype(p.dtype), param_shardings[k])
-            return nv, new_st
-
-        updater = ZeroOffloadUpdater(
-            _offload_tensor_update, [m_sh[k] for k in key_order],
-            depth=offload_depth, site="parallel.zero_offload")
-
-        def _make_grads_jitted(batch_sh):
-            return _obs.instrument_jit(jax.jit(
-                grads_step,
-                in_shardings=(param_sh, scalar_sh, batch_sh, None, None),
-                out_shardings=(scalar_sh, param_sh, scalar_sh)),
-                site="parallel.sharded_train_step")
-
-        grads_jitted = _make_grads_jitted(
-            (NamedSharding(mesh, bspec), NamedSharding(mesh, bspec)))
-        _grads_cache = {}
-
-        def _get_grads_jitted(batch):
-            leaves, treedef = jax.tree.flatten(batch)
-            key = (treedef, tuple(l.ndim for l in leaves))
-            if key not in _grads_cache:
-                bsh = jax.tree.unflatten(treedef, [
-                    NamedSharding(mesh, P(*tuple(bspec)[:l.ndim]))
-                    for l in leaves])
-                _grads_cache[key] = _make_grads_jitted(bsh)
-            return _grads_cache[key]
-
-        def step(state, ids, labels, rng, lr=None):  # noqa: F811
-            if sp_degree > 1:
-                for leaf in jax.tree.leaves((ids, labels)):
-                    if getattr(leaf, "ndim", 0) >= 2 and \
-                            leaf.shape[1] % sp_degree:
-                        raise ValueError(
-                            f"sequence length {leaf.shape[1]} must "
-                            f"divide evenly over the 'sp' axis "
-                            f"(degree {sp_degree})")
-            with jax.profiler.StepTraceAnnotation(
-                    "train_step", step_num=next(host_steps)):
-                lr_now = jnp.float32(learning_rate if lr is None else lr)
-                fn = grads_jitted if (hasattr(ids, "ndim")
-                                      and hasattr(labels, "ndim")) \
-                    else _get_grads_jitted((ids, labels))
-                with _tracing.span("train.dispatch"), _set_mesh(mesh):
-                    loss, grads, t = fn(state["params"], state["step"],
-                                        (ids, labels), rng, lr_now)
-                vals = [state["params"][k] for k in key_order]
-                gs = [grads[k] for k in key_order]
-                hst = [state["opt_state"][k] for k in key_order]
-                new_vals, new_hst = updater.apply(vals, gs, hst, lr_now, t)
-                new_params = dict(zip(key_order, new_vals))
-                with _tracing.span("train.rebind"):
-                    for k, v in new_params.items():
-                        tn = param_tensors.get(k)
-                        if tn is not None:
-                            tn._set_value(v)
-            return ({"params": new_params,
-                     "opt_state": dict(zip(key_order, new_hst)),
-                     "step": t}, loss)
-
-        step._jitted = grads_jitted._jit_fn
-        step.sync_model = sync_model
-        return step, state
-
     # exposed for AOT lowering / HLO inspection (the RAW jit function —
-    # the instrumentation wrapper has no .lower)
+    # the instrumentation wrapper has no .lower); under zero_offload it is
+    # the grads-only program, (params, step, batch, rng, lr)
     step._jitted = jitted._jit_fn
     step.sync_model = sync_model
     return step, state

@@ -8,7 +8,7 @@ each step streams one tensor's state through a depth-bounded h2d → jit
 → d2h pipe (``io.transfer.TransferRing``, the same overlap pattern the
 dataloader's ``device_prefetch`` uses), so opt-state HBM residency is
 ~``depth+1`` tensor shards instead of the whole state, while the math
-is the unmodified ``Optimizer._sharded_update`` core — bit-exact vs the
+is the unmodified ``Optimizer._sharded_rules`` core — bit-exact vs the
 resident ZeRO path on identical gradients.
 
 Dataflow per step (tensor ``i`` of ``n``):
@@ -78,6 +78,10 @@ class ZeroOffloadUpdater:
     def depth(self) -> int:
         return self._depth
 
+    @property
+    def state_shardings(self) -> list:
+        return self._state_sh
+
     # -- construction from a paddle Optimizer ------------------------------
     @classmethod
     def for_optimizer(cls, optimizer, plist, shard_info: ZeroShardInfo,
@@ -90,12 +94,6 @@ class ZeroOffloadUpdater:
         pspecs = shard_info.param_specs or (None,) * len(plist)
         plrs = tuple(p.optimize_attr.get("learning_rate", 1.0)
                      for p in plist)
-        # pre-derive full-list metadata (e.g. AdamW's decay mask) so the
-        # per-tensor traces below see it complete, as the resident
-        # trainers do when they trace with the full param list
-        optimizer._prepare_functional(list(plist))
-        optimizer._prepare_functional(None)
-
         def tensor_update(i, val, grad, state, lr, step_t):
             si = shard_info.with_param_specs((pspecs[i],))
             optimizer._prepare_functional([plist[i]])

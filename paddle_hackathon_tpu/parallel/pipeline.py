@@ -538,60 +538,35 @@ class PipelineParallel:
             from .mp_layers import sharding_rule_from_model
             n_micro = None
             zero = 0
-            opt_kind, opt_kwargs = "adam", None
+            # the update rule is the user's optimizer, as it is: its class,
+            # hyper-parameters, decay mask and grad_clip go into the one
+            # compiled program.  A fleet wrapper (amp, gradient merge, ...)
+            # wraps the eager step() this trainer does not run; its inner
+            # optimizer is the rule
+            opt = getattr(optimizer, "inner_optimizer", optimizer)
             if self._strategy is not None:
                 n_micro = int(self._strategy.pipeline_configs.get(
                     "accumulate_steps", 0)) or None
                 if self._strategy.sharding:
                     zero = int((self._strategy.sharding_configs or {}).get(
                         "stage", 1))
-                # strategy.lamb/lars swap the in-step update rule here
-                # too (the eager-optimizer swap in fleet.
-                # distributed_optimizer cannot reach inside this one
-                # compiled program); their configs — and the swapped
-                # eager optimizer's hyperparameters — forward into the
-                # step, or the program would silently train with
-                # defaults the user never chose
-                if self._strategy.lamb:
-                    opt_kind = "lamb"
-                    from ..optimizer.optimizers import LAMB_DEFAULTS
-                    c = self._strategy.lamb_configs or {}
-                    opt_kwargs = {"lamb_weight_decay": float(
-                        c.get("lamb_weight_decay",
-                              LAMB_DEFAULTS["lamb_weight_decay"]))}
-                    if optimizer is not None and \
-                            hasattr(optimizer, "_beta1"):
-                        opt_kwargs.update(
-                            beta1=optimizer._beta1,
-                            beta2=optimizer._beta2)
-                        if hasattr(optimizer, "_eps"):
-                            opt_kwargs["epsilon"] = optimizer._eps
-                        if hasattr(optimizer, "_wd"):
-                            opt_kwargs["lamb_weight_decay"] = optimizer._wd
-                elif self._strategy.lars:
-                    opt_kind = "lars"
-                    from ..optimizer.optimizers import LARS_DEFAULTS
-                    c = self._strategy.lars_configs or {}
-                    opt_kwargs = {
-                        k: float(c.get(k, LARS_DEFAULTS[k]))
-                        for k in ("lars_coeff", "lars_weight_decay",
-                                  "epsilon")}
-                    if optimizer is not None and \
-                            hasattr(optimizer, "_momentum"):
-                        opt_kwargs["momentum"] = optimizer._momentum
-                    # a user-built Lars carries its own hyperparameters —
-                    # they beat the strategy-config defaults
-                    if optimizer is not None and \
-                            hasattr(optimizer, "_coeff"):
-                        opt_kwargs.update(
-                            lars_coeff=optimizer._coeff,
-                            lars_weight_decay=optimizer._lars_wd,
-                            epsilon=optimizer._eps)
+                if self._strategy.lamb or self._strategy.lars:
+                    # the eager-optimizer swap of fleet.distributed_optimizer
+                    # cannot reach inside this program, so a caller who
+                    # skipped it gets the same swap here: one mapping from
+                    # a strategy to a rule, fleet._swap_update_rule
+                    from .. import optimizer as _optim
+                    from .fleet import _swap_update_rule
+                    if opt is None:
+                        base = (_optim.Momentum if self._strategy.lars
+                                else _optim.Adam)
+                        opt = base(parameters=self._model.parameters())
+                    opt = _swap_update_rule(opt, self._strategy)
             rule = self._rule or sharding_rule_from_model(self._model)
             self._step, self._state = make_sharded_train_step(
                 self._model, self._mesh, rule=rule,
                 zero_stage=zero, pp_microbatches=n_micro,
-                optimizer=opt_kind, optimizer_kwargs=opt_kwargs)
+                optimizer="adam" if opt is None else opt)
         # lr read fresh every call: schedules stay live (the step takes lr
         # as a dynamic scalar, so this never recompiles); without an
         # optimizer, None lets the step use its own configured default

@@ -87,7 +87,9 @@ class ZeroShardInfo:
     and writes the master copy and the gathered param is its cast.
     """
     mesh: Mesh
-    axis: str
+    # None: the mesh has no ZeRO axis; every slot then sits on its
+    # parameter's own spec (how a master slot is carried without ZeRO)
+    axis: Optional[str]
     stage: int = 1
     master_weights: bool = False
     # per-param base specs (TP/placement), aligned with the positional
@@ -112,9 +114,11 @@ class ZeroShardInfo:
 def place_zero_state(shard_info: "ZeroShardInfo", param_values, states):
     """Place per-param optimizer slot dicts at their ZeRO moment
     sharding, adding the f32 ``"master"`` slot for floating params when
-    ``shard_info.master_weights`` — THE single owner of the placement
-    the hapi trainer and the Engine share (``make_sharded_train_step``
-    keeps its own pp-stacked-aware variant).  Returns the placed list."""
+    ``shard_info.master_weights`` — THE single owner of the placement,
+    shared by all three compiled trainers (a pp-stacked leaf's spec
+    comes in through ``param_specs`` like any other; a ``shard_info``
+    with no axis places on the parameter's own spec).  Returns the
+    placed list."""
     pspecs = shard_info.param_specs or (None,) * len(param_values)
     placed = []
     for v, st, ps in zip(param_values, states, pspecs):
@@ -252,7 +256,7 @@ def group_sharded_parallel(model: Layer, optimizer, level: str = "os_g",
 
     optimizer._init_accumulators = sharded_init
     # ...and the UPDATE runs through the same functional sharded path the
-    # compiled trainers use (``Optimizer._sharded_update``): grads pinned
+    # compiled trainers use (``Optimizer._sharded_rules``): grads pinned
     # to the moment sharding (reduce-scatter), shard-local rule, params
     # all-gathered back — eager and compiled ZeRO agree on the program,
     # instead of the old placement-only wrapping that let GSPMD

@@ -55,6 +55,13 @@ class _OptimizerWrapper:
         # strategy override points.
         return getattr(self._inner, name)
 
+    @property
+    def inner_optimizer(self) -> Optimizer:
+        """The wrapped optimizer, through any nesting — the update rule a
+        compiled trainer takes, which has no eager ``step()`` to wrap
+        (``PipelineParallel.train_batch``)."""
+        return getattr(self._inner, "inner_optimizer", self._inner)
+
     def step(self):
         raise NotImplementedError
 

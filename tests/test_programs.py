@@ -684,6 +684,59 @@ def test_phase_census_of_a_compiled_toy_train_step(toy_train_step):
     assert get_program_registry().phase_census("no.such.site") is None
 
 
+def _toy_fit_program(front_end):
+    """A two-layer MLP with a global-norm clip through ``Model.fit``'s
+    compiled path or the auto-parallel ``Engine``, built under the
+    analysis pass; the site its program is recorded under."""
+    import jax
+    from paddle_hackathon_tpu import hapi, io as pio, nn, parallel
+    from paddle_hackathon_tpu import optimizer as optim
+
+    class DS(pio.Dataset):
+        x = np.random.RandomState(0).randn(16, 8).astype(np.float32)
+
+        def __len__(self):
+            return len(self.x)
+
+        def __getitem__(self, i):
+            return self.x[i], np.int64(self.x[i].sum() > 0)
+
+    paddle.seed(0)
+    net = nn.Sequential(nn.Linear(8, 8), nn.ReLU(), nn.Linear(8, 2))
+    opt = optim.Adam(learning_rate=1e-2, parameters=net.parameters(),
+                     grad_clip=nn.ClipGradByGlobalNorm(1.0))
+    prev = parallel.get_mesh()
+    try:
+        with program_analysis():
+            if front_end == "hapi":
+                m = hapi.Model(net)
+                m.prepare(optimizer=opt, loss=nn.CrossEntropyLoss())
+                m.fit(DS(), epochs=1, batch_size=8, verbose=0,
+                      shuffle=False, jit_compile=True,
+                      steps_per_execution=2)
+                assert m._fit_used_compiled
+                return "hapi.compiled_trainer"
+            from paddle_hackathon_tpu.parallel.auto_parallel import (
+                Engine, ProcessMesh)
+            Engine(net, loss=nn.CrossEntropyLoss(), optimizer=opt,
+                   process_mesh=ProcessMesh([0], dim_names=["dp"])).fit(
+                       DS(), epochs=1, batch_size=8, verbose=0)
+            return "parallel.engine_train_step"
+    finally:
+        parallel.set_mesh(prev)
+
+
+@pytest.mark.parametrize("front_end", ["sharded", "hapi", "engine"])
+def test_every_front_end_names_clip_and_update(front_end, request):
+    """The ``clip`` / ``update`` scopes are written once, in
+    ``optimizer/``, so all three trainers' programs carry them."""
+    site = request.getfixturevalue("toy_train_step")["site"] \
+        if front_end == "sharded" else _toy_fit_program(front_end)
+    census = get_program_registry().phase_census(site)
+    counts = programs.phase_counts(census)
+    assert counts.get("clip", 0) > 0 and counts.get("update", 0) > 0, counts
+
+
 def test_build_record_says_where_the_seconds_went(toy_train_step):
     (rec,) = toy_train_step["new"]       # the second call recorded nothing
     parts = [rec[k] for k in programs.BUILD_CLOCK_KEYS]
