@@ -20,8 +20,14 @@ This kernel family keeps everything in the projection-native layout:
   out_proj directly.  Grouping keeps VMEM per cell bounded for any H, so
   gpt2-small (H*D=768) runs whole rows per cell while a 2048-hidden model
   splits into G=4-head groups without shrinking the 512-edge blocks;
-- backward mirrors it (dq kernel + dkdv kernel); the only XLA-side work
-  left is one lane concat of (dq, dk, dv) into the qkv cotangent.
+- backward mirrors it (dq kernel, then dkdv kernel).  delta =
+  rowsum(dO * O) is computed inside the dq kernel, from the dO block it
+  streams anyway and the O block beside it, in the first step of each q
+  row, and leaves it as a second result in the statistics' layout, which
+  the dkdv kernel reads: XLA computes nothing of the backward.  What it
+  is left with is the lane concat of (dq, dk, dv) into the qkv
+  cotangent, and that it fuses into the cotangent's three consumers
+  (``_bwd`` says in which form it does): no packed array is built.
 
 Stats (lse) live transposed as (b, H, 8, s) sublane-broadcast rows — the
 running max/sum also live transposed in VMEM ((G, 8, block) instead of
@@ -109,9 +115,13 @@ def _plan(sq, skv, heads, head_dim, dtype=jnp.bfloat16):
     The strips' temporaries are (c, b) at most, but a cell under the
     diagonal (and every cell of a non-causal call) still runs the whole
     (b, b) tile, so the worst case — and with it every group this has
-    picked so far — stands.  The autotune cache can override
-    (block_q, block_kv, group) per shape; the strip always follows from
-    the blocks."""
+    picked so far — stands.  The dq kernel is not the worst case: it
+    holds as many blocks (five inputs with O, one output), one
+    accumulator where dkdv has two, and its delta temporaries (the
+    (b, G*D) float32 product and its bf16 pieces) live in the first step
+    of a row alone, not beside a score tile.  The autotune cache can
+    override (block_q, block_kv, group) per shape; the strip always
+    follows from the blocks."""
     from ....core import autotune as _at
     cached = (_at.kernel_cache.get(_tune_key(sq, skv, heads, dtype))
               if _at.enabled() else None)
@@ -231,6 +241,31 @@ def _dot(a, b, b_dim, a_dim=1):
     return jax.lax.dot_general(a, b, (((a_dim,), (b_dim,)), ((), ())),
                                preferred_element_type=jnp.float32,
                                precision=_prec(a.dtype))
+
+
+def _head_row_sums(a, b, group, head_dim):
+    """``sum(a * b)`` over each head's lanes of two (rows, G*D) blocks, as
+    (G*8, rows) float32: head h's sums fill sublanes 8h to 8h + 7, the
+    rows along lanes.  The products are float32 and so is their sum, to
+    rounding: a product of two bf16 values carries 16 significant bits
+    and splits exactly into two bf16 pieces (three cover float16's 22),
+    and the MXU sums each piece's lanes in float32 against a 0 / 1
+    selector of the heads.  That is also the cheapest way found to turn a
+    lane reduction into rows (v5e, kernel alone, PERF.md §6, PR 31: a
+    float32 ones matmul a head, a lane reduce and a turn a head, and the
+    diagonal of dO . O^T all cost more)."""
+    prod = a.astype(jnp.float32) * b.astype(jnp.float32)
+    shape = (group * _SUB, group * head_dim)
+    sel = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) // _SUB ==
+           jax.lax.broadcasted_iota(jnp.int32, shape, 1) // head_dim
+           ).astype(jnp.bfloat16)
+    piece = prod.astype(jnp.bfloat16)
+    rows = _dot(sel, piece, 1)
+    for _ in range(1 if a.dtype == jnp.bfloat16 else 2):
+        prod = prod - piece.astype(jnp.float32)
+        piece = prod.astype(jnp.bfloat16)
+        rows = rows + _dot(sel, piece, 1)
+    return rows
 
 
 def _join_trailing(whole, part, combine):
@@ -397,15 +432,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, o_ref, lse_ref,
             lse_ref[0, h] = m_ref[h] + jnp.log(jnp.maximum(lt, 1e-30))
 
 
-def _kv_idx_packed(causal, bq, bkv, n_kv, part, n_groups):
+def _kv_idx_packed(causal, bq, bkv, n_kv, part, n_groups,
+                   descending=False):
     """kv index map into the packed (b, s, 3*H*D) qkv array, in G*D-lane
     block units: ``part`` selects q (0), k (1) or v (2); the group grid
     index picks the lane block within the part; causal clamps to the
-    diagonal so cells above it elide their DMA."""
+    diagonal so cells above it elide their DMA.  ``descending``: grid
+    step j holds kv block ``n_kv - 1 - j`` (the dq kernel's sweep)."""
     if not causal:
-        return lambda b, g, i, j: (b, j, part * n_groups + g)
+        return lambda b, g, i, j: (b, n_kv - 1 - j if descending else j,
+                                   part * n_groups + g)
 
     def idx(b, g, i, j):
+        if descending:
+            j = n_kv - 1 - j
         diag = jnp.minimum((i * bq + bq - 1) // bkv, n_kv - 1)
         return (b, jnp.minimum(j, diag), part * n_groups + g)
     return idx
@@ -559,19 +599,34 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
             dv_ref[0, :, h * D:(h + 1) * D] = dv_acc[h].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   seed_ref, dq_ref, dq_acc, *, sm_scale, causal, block_q,
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, seed_ref,
+                   dq_ref, delta_ref, dq_acc, *, sm_scale, causal, block_q,
                    block_kv, strip, n_kv, group, heads, head_dim,
                    dropout_p):
     bi = pl.program_id(0)
     gi = pl.program_id(1)
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    # the kv sweep runs from the last block down to the first: a causal
+    # row's cells above the diagonal, which compute nothing, then come
+    # first, and the next row's q, dO, O and statistics blocks (1.5 MiB
+    # and more at a 512-edge plan) are fetched under this row's last
+    # cell, a computing one.  Ascending, that fetch stood under an empty
+    # cell, where nothing hides it (PERF.md §6, PR 31)
+    step = pl.program_id(3)
+    ki = n_kv - 1 - step
     D = head_dim
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        # delta = rowsum(dO * O) a head, once a q block, born as the
+        # (8, block_q) rows it is stored in.  ``delta_ref`` is an output
+        # whose block index ignores the kv step: it stays in VMEM for the
+        # whole sweep, every cell of the sweep (this one too) reads it
+        # back, and the dkdv kernel takes the array as its input
+        rows = _head_row_sums(do_ref[0], o_ref[0], group, D)
+        for h in range(group):
+            delta_ref[0, h] = rows[h * _SUB:(h + 1) * _SUB]
 
     def _body(tiles, keeps):
         # same VPU economy as the forward: sm_scale folded into the small
@@ -642,7 +697,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         diag_cell=lambda: _diag_cell(qi, ki, block_q, block_kv, strip),
         whole=(0, block_q, block_kv))
 
-    @pl.when(ki == n_kv - 1)
+    @pl.when(step == n_kv - 1)
     def _finish():
         for h in range(group):
             dq_ref[0, :, h * D:(h + 1) * D] = dq_acc[h].astype(dq_ref.dtype)
@@ -652,7 +707,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     "heads", "causal", "sm_scale", "dropout_p", "plan", "interpret"))
 def _bwd(qkv, out, lse, seed, do, *, heads, causal, sm_scale, dropout_p,
          plan, interpret):
-    """The packed qkv cotangent ``(b, s, 3*H*D)``; jitted like ``_fwd``."""
+    """``(dqkv, delta)``: the packed qkv cotangent ``(b, s, 3*H*D)`` and
+    the rows ``delta = rowsum(dO * O)`` both kernels used, (b, H, 8, s)
+    float32 like lse; jitted like ``_fwd``."""
     from jax.experimental.pallas import tpu as pltpu
     b, sq, hd3 = qkv.shape
     hd = hd3 // 3
@@ -663,12 +720,38 @@ def _bwd(qkv, out, lse, seed, do, *, heads, causal, sm_scale, dropout_p,
     n_g = heads // G
     gd = G * D
 
-    # delta = rowsum(dO * O) per head, in the (b, H, 8, s) stats layout
-    do_h = do.reshape(b, sq, heads, D).astype(jnp.float32)
-    out_h = out.reshape(b, sq, heads, D).astype(jnp.float32)
-    delta_row = jnp.sum(do_h * out_h, axis=-1)            # (b, sq, H)
-    delta_t = jnp.broadcast_to(
-        jnp.swapaxes(delta_row, 1, 2)[:, :, None, :], (b, heads, _SUB, sq))
+    q_rows = pl.BlockSpec((1, bq, gd), lambda bb, g, i, j: (bb, i, g))
+    stat_rows = pl.BlockSpec((1, G, _SUB, bq),
+                             lambda bb, g, i, j: (bb, g, 0, i))
+    dqk = functools.partial(
+        _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
+        block_kv=bkv, strip=strip, n_kv=n_kv, group=G, heads=heads,
+        head_dim=D, dropout_p=dropout_p)
+    # dq first: it also writes delta = rowsum(dO * O), (b, H, 8, s) in the
+    # statistics' layout, from the dO and O blocks of its row's first step
+    dq, delta_t = pl.pallas_call(
+        dqk,
+        grid=(b, n_g, n_q, n_kv),
+        in_specs=[
+            q_rows,                                                 # q
+            pl.BlockSpec((1, bkv, gd), _kv_idx_packed(
+                causal, bq, bkv, n_kv, 1, n_g, descending=True)),
+            pl.BlockSpec((1, bkv, gd), _kv_idx_packed(
+                causal, bq, bkv, n_kv, 2, n_g, descending=True)),
+            q_rows,                                                 # dO
+            q_rows,                                                 # O
+            stat_rows,                                              # lse
+            _smem_spec(),
+        ],
+        out_specs=[q_rows, stat_rows],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, sq, hd), qkv.dtype),
+            jax.ShapeDtypeStruct((b, heads, _SUB, sq), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((G, bq, D), jnp.float32)],
+        interpret=interpret,
+        name="flash_packed_bwd_dq",
+    )(qkv, qkv, qkv, do, out, lse, seed)
 
     if causal:
         def q_idx(bb, g, j, i):
@@ -719,34 +802,18 @@ def _bwd(qkv, out, lse, seed, do, *, heads, causal, sm_scale, dropout_p,
         name="flash_packed_bwd_dkdv",
     )(qkv, qkv, qkv, do, lse, delta_t, seed)
 
-    dqk = functools.partial(
-        _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
-        block_kv=bkv, strip=strip, n_kv=n_kv, group=G, heads=heads,
-        head_dim=D, dropout_p=dropout_p)
-    dq = pl.pallas_call(
-        dqk,
-        grid=(b, n_g, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, bq, gd), lambda bb, g, i, j: (bb, i, g)),
-            pl.BlockSpec((1, bkv, gd),
-                         _kv_idx_packed(causal, bq, bkv, n_kv, 1, n_g)),
-            pl.BlockSpec((1, bkv, gd),
-                         _kv_idx_packed(causal, bq, bkv, n_kv, 2, n_g)),
-            pl.BlockSpec((1, bq, gd), lambda bb, g, i, j: (bb, i, g)),
-            pl.BlockSpec((1, G, _SUB, bq),
-                         lambda bb, g, i, j: (bb, g, 0, i)),
-            pl.BlockSpec((1, G, _SUB, bq),
-                         lambda bb, g, i, j: (bb, g, 0, i)),
-            _smem_spec(),
-        ],
-        out_specs=pl.BlockSpec((1, bq, gd), lambda bb, g, i, j: (bb, i, g)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, hd), qkv.dtype),
-        scratch_shapes=[pltpu.VMEM((G, bq, D), jnp.float32)],
-        interpret=interpret,
-        name="flash_packed_bwd_dq",
-    )(qkv, qkv, qkv, do, lse, delta_t, seed)
-
-    return jnp.concatenate([dq, dk, dv], axis=-1)   # (b, s, 3*H*D)
+    # (b, s, 3*H*D): the lane concat of (dq, dk, dv), written as the sum of
+    # the three parts padded with zeros (exact: x + 0).  XLA fuses that
+    # into each of its consumers (the qkv projection's two backward
+    # matmuls and its bias gradient) and no packed array exists; a
+    # ``concatenate`` of three kernel results that are all tuple elements
+    # (dq's call has two results now, as dkdv's always had) it builds in
+    # HBM instead, one dynamic-update-slice a part: 72 fusions, 4.4 ms a
+    # step of gpt2-medium (PERF.md §6, PR 31)
+    zero = jnp.zeros((), dq.dtype)
+    return sum(jax.lax.pad(part, zero,
+                           ((0, 0, 0), (0, 0, 0), (i * hd, (2 - i) * hd, 0)))
+               for i, part in enumerate((dq, dk, dv))), delta_t
 
 
 # ---------------------------------------------------------------------------
@@ -797,8 +864,8 @@ def _vjp_fwd(qkv, heads, causal, sm_scale, dropout_p=0.0, seed=None):
 
 def _vjp_bwd(heads, causal, sm_scale, dropout_p, res, do):
     qkv, out, lse, seed = res
-    dqkv = _bwd(qkv, out, lse, _seed_array(seed), do,
-                **_statics(qkv, heads, causal, sm_scale, dropout_p))
+    dqkv, _ = _bwd(qkv, out, lse, _seed_array(seed), do,
+                   **_statics(qkv, heads, causal, sm_scale, dropout_p))
     return (dqkv, None)                             # None: the int seed array
 
 

@@ -652,6 +652,113 @@ class TestPackedFlashAttention:
         v0 = np.asarray(qkv, np.float32)[0, 0, 2 * H * D:]
         np.testing.assert_allclose(out[0, 0], v0, rtol=2 ** -7)
 
+    @staticmethod
+    def _backward_with_xla_delta(qkv, out, lse, do, H, scale, causal,
+                                 dropout, seed):
+        """The backward this PR's parent ran, restated whole in plain
+        jnp with the kernels' roundings: ``delta`` by the formula that
+        stood in ``_bwd`` (float32 reshape, product, sum over head dim),
+        p from the stored lse, q scaled in the operand dtype, p and ds
+        cast to it for the accumulating products.  ``(dqkv, delta)`` with
+        delta (b, H, s)."""
+        from paddle_hackathon_tpu.incubate.nn.kernels.flash_attention \
+            import _dropout_keep
+        b, s, hd3 = qkv.shape
+        D = hd3 // 3 // H
+        f32 = jnp.float32
+
+        def heads(x):
+            return x.reshape(b, s, H, D).transpose(0, 2, 1, 3)
+        q, k, v = (heads(qkv[..., i * H * D:(i + 1) * H * D])
+                   for i in range(3))
+        do_h, out_h = heads(do), heads(out)
+        delta = jnp.sum(do_h.astype(f32) * out_h.astype(f32), axis=-1)
+        qs = q * jnp.asarray(scale, q.dtype)
+        st = jnp.einsum("bhqd,bhkd->bhqk", qs, k, preferred_element_type=f32)
+        p = jnp.exp(st - lse[:, :, 0, :, None])
+        if causal:
+            p = jnp.where(jnp.tril(jnp.ones((s, s), bool)), p, 0.0)
+        dp = jnp.einsum("bhqd,bhkd->bhqk", do_h, v,
+                        preferred_element_type=f32)
+        p_v = p
+        if dropout:
+            bh = (jnp.arange(b)[:, None] * H + jnp.arange(H)[None, :])
+            keep = _dropout_keep(
+                seed[0], bh[:, :, None, None].astype(jnp.int32),
+                jnp.arange(s, dtype=jnp.int32)[None, None, :, None],
+                jnp.arange(s, dtype=jnp.int32)[None, None, None, :],
+                1.0 - dropout)
+            p_v = jnp.where(keep, p / (1.0 - dropout), 0.0)
+            dp = jnp.where(keep, dp / (1.0 - dropout), 0.0)
+        ds = (p * (dp - delta[..., None])).astype(q.dtype)
+        dv = jnp.einsum("bhqk,bhqd->bhkd", p_v.astype(q.dtype), do_h,
+                        preferred_element_type=f32)
+        dq = jnp.einsum("bhqk,bhkd->bhqd", ds,
+                        k * jnp.asarray(scale, k.dtype),
+                        preferred_element_type=f32)
+        dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qs,
+                        preferred_element_type=f32)
+        dqkv = jnp.concatenate(
+            [x.astype(qkv.dtype).transpose(0, 2, 1, 3).reshape(b, s, H * D)
+             for x in (dq, dk, dv)], axis=-1)
+        return np.asarray(dqkv, np.float32), np.asarray(delta)
+
+    # (s, H, D, plan or None for ``_plan``'s own, causal, dropout, dtype):
+    # the dq kernel computes delta in the first step of a q row from the
+    # dO and O blocks it holds and every cell of the row's sweep (and the
+    # dkdv kernel) reads it back, so the cases span 1, 2 and 3 kv blocks
+    # a row
+    @pytest.mark.parametrize("S,H,D,plan,causal,dropout,dtype", [
+        (1024, 2, 64, None, True, 0.0, jnp.bfloat16),
+        (1024, 1, 128, None, True, 0.0, jnp.bfloat16),
+        (768, 2, 64, None, True, 0.0, jnp.bfloat16),
+        (256, 2, 64, (128, 128, 2, 0), False, 0.0, jnp.bfloat16),
+        (256, 2, 64, (128, 128, 2, 32), True, 0.3, jnp.bfloat16),
+        (256, 6, 64, None, True, 0.0, jnp.bfloat16),
+        (256, 2, 64, None, True, 0.0, jnp.float16),
+    ], ids=["strips-d64", "strips-d128", "edge256-whole-tile", "noncausal",
+            "dropout", "group6", "float16"])
+    def test_delta_is_computed_in_the_dq_kernel(self, monkeypatch, S, H, D,
+                                                plan, causal, dropout, dtype):
+        """delta = rowsum(dO * O) is born inside the dq kernel, in the
+        statistics' layout: a float32 sum of float32 products of the
+        operands, to float32 rounding (not to bf16), and the gradients
+        through ``flash_attention_packed`` are the ones the parent's
+        XLA-side formula gave."""
+        import jax
+        from paddle_hackathon_tpu.incubate.nn.kernels import (
+            flash_attention_packed as fap)
+        if plan is None:
+            plan = fap._plan(S, S, H, D, dtype)
+        rng = np.random.RandomState(S + H + D)
+        qkv = jnp.asarray(rng.randn(2, S, 3 * H * D) * 0.5, dtype)
+        do = jnp.asarray(rng.randn(2, S, H * D) * 0.5, dtype)
+        scale = 1.0 / np.sqrt(D)
+        seed = jnp.asarray([77], jnp.int32)
+        statics = dict(heads=H, causal=causal, sm_scale=scale,
+                       dropout_p=dropout, plan=plan, interpret=True)
+        out, lse = fap._fwd(qkv, seed, **statics)
+        dqkv, delta = fap._bwd(qkv, out, lse, seed, do, **statics)
+        want_dqkv, want_delta = self._backward_with_xla_delta(
+            qkv, out, lse, do, H, scale, causal, dropout, seed)
+        delta = np.asarray(delta)
+        assert delta.shape == (2, H, 8, S) and delta.dtype == np.float32
+        assert (delta == delta[:, :, :1]).all()
+        # 64 or 128 products of magnitude 0.05 a row: float32 rounding of
+        # the sum is 1e-7; a bf16 product or sum would miss by 1e-3
+        np.testing.assert_allclose(delta[:, :, 0], want_delta,
+                                   rtol=1e-5, atol=2e-6)
+        np.testing.assert_allclose(np.asarray(dqkv, np.float32), want_dqkv,
+                                   rtol=2 ** -7, atol=2e-3)
+
+        # and through the custom_vjp, for the cotangent of sum(o * w)
+        self._with_plan(monkeypatch, plan)
+        grad = jax.grad(lambda a: jnp.sum(
+            fap.flash_attention_packed(a, H, causal, scale, dropout, seed)
+            .astype(jnp.float32) * do.astype(jnp.float32)))(qkv)
+        np.testing.assert_allclose(np.asarray(grad, np.float32), want_dqkv,
+                                   rtol=2 ** -7, atol=2e-3)
+
     def test_forward_tile_helpers(self):
         from paddle_hackathon_tpu.incubate.nn.kernels import (
             flash_attention_packed as fap)

@@ -87,13 +87,16 @@ def one_described_chip():
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("b,s,heads,d,causal,dropout", [
+_DESCRIBED_V5E_SHAPES = [
     (16, 1024, 16, 64, True, 0.0),     # gpt2-medium.train-s1024's plan
     (6, 1024, 16, 128, True, 0.0),     # gpt3-1.3b.train-s1024's
     (16, 768, 16, 64, True, 0.0),      # 256-edge: whole-tile diagonal
     (64, 512, 12, 64, False, 0.0),     # ERNIE: one whole tile a row
     (32, 1024, 12, 64, True, 0.1),     # dropout, G = 6
-])
+]
+
+
+@pytest.mark.parametrize("b,s,heads,d,causal,dropout", _DESCRIBED_V5E_SHAPES)
 def test_packed_flash_forward_compiles_for_a_described_v5e(
         one_described_chip, b, s, heads, d, causal, dropout):
     """The kv-major forward slices statistics rows, contracts axis 0 of
@@ -110,6 +113,90 @@ def test_packed_flash_forward_compiles_for_a_described_v5e(
         qkv, seed, heads=heads, causal=causal, sm_scale=1.0 / math.sqrt(d),
         dropout_p=dropout, plan=plan, interpret=False).compile()
     assert "flash_packed_fwd" in compiled.as_text()
+
+
+@pytest.mark.parametrize("b,s,heads,d,causal,dropout", _DESCRIBED_V5E_SHAPES)
+def test_packed_flash_backward_compiles_for_a_described_v5e(
+        one_described_chip, b, s, heads, d, causal, dropout):
+    """The dq kernel reads an output block back (delta, written in a
+    row's first step and held in VMEM for the kv sweep) and contracts a
+    0 / 1 selector against the float32 products' bf16 pieces: questions
+    for Mosaic's compiler.  And the compiled backward is the two kernels and
+    the lane concat: XLA computes no delta, so no float32 array of
+    b x s x hidden elements exists and none is copied into another
+    layout (the parent's preamble wrote one, turned it and read it back,
+    24 times a step: PERF.md §6, PR 31)."""
+    import re
+    plan = fap._plan(s, s, heads, d, jnp.bfloat16)
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_described_chip)
+    hidden = heads * d
+    text = fap._bwd.lower(
+        aval((b, s, 3 * hidden), jnp.bfloat16),         # qkv
+        aval((b, s, hidden), jnp.bfloat16),             # out
+        aval((b, heads, 8, s), jnp.float32),            # lse
+        aval((1,), jnp.int32),                          # seed
+        aval((b, s, hidden), jnp.bfloat16),             # dO
+        heads=heads, causal=causal, sm_scale=1.0 / math.sqrt(d),
+        dropout_p=dropout, plan=plan, interpret=False).compile().as_text()
+    calls = {name: line for line in text.splitlines() for name in
+             re.findall(r"^\s*%(flash_packed_\w+?)[.\d]* = ", line)}
+    assert sorted(calls) == ["flash_packed_bwd_dkdv", "flash_packed_bwd_dq"]
+    # the mechanism: dq has two results (dq and the delta rows) and seven
+    # operands (q, k, v, dO, O, lse, seed)
+    result, operands = re.match(
+        r"\s*%\S+ = \((.*?)\) custom-call\((.*?)\), custom_call_target",
+        calls["flash_packed_bwd_dq"]).groups()
+    assert re.findall(r"(\w+)\[([\d,]+)\]", result) == [
+        ("bf16", f"{b},{s},{hidden}"), ("f32", f"{b},{heads},8,{s}")]
+    assert len(re.findall(r"%[\w.-]+", operands)) == 7
+    elements = b * s * hidden
+    for dims in re.findall(r"\bf32\[([\d,]+)\]", text):     # fused ones too
+        assert math.prod(int(n) for n in dims.split(",")) != elements, \
+            f"a float32 array of b x s x hidden elements: f32[{dims}]"
+    for line in text.splitlines():
+        if re.search(r" copy\(", line):
+            assert re.search(r"= s32\[1\]", line), line   # the seed to SMEM
+
+
+@pytest.mark.parametrize("b,s,heads,d", [
+    (16, 1024, 16, 64),                # gpt2-medium.train-s1024's layer
+    (6, 1024, 16, 128),                # gpt3-1.3b.train-s1024's
+])
+def test_packed_qkv_cotangent_is_not_built_in_hbm_on_a_described_v5e(
+        one_described_chip, b, s, heads, d):
+    """The qkv cotangent is the lane concat of (dq, dk, dv), and XLA is
+    meant to fuse it into its three consumers: the projection's weight
+    gradient, its input gradient and its bias gradient.  Whether it does
+    is decided where the consumers are, so this compiles a projection
+    and the attention behind it, not ``_bwd`` alone: when the dq call
+    gained its second result a ``concatenate`` was built in HBM instead,
+    three dynamic-update-slice fusions a layer, which cost more than the
+    change had won (PERF.md §6, PR 31)."""
+    import re
+    hidden = heads * d
+
+    def loss(x, w, bias, g):
+        qkv = jnp.einsum("bsh,hk->bsk", x, w) + bias
+        out = fap.flash_attention_packed(qkv, heads, True,
+                                         1.0 / math.sqrt(d))
+        return jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32))
+
+    def aval(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                    sharding=one_described_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        aval(b, s, hidden), aval(hidden, 3 * hidden), aval(3 * hidden),
+        aval(b, s, hidden)).compile().as_text()
+    entry = text[text.index("ENTRY "):]
+    packed = re.findall(
+        r"^\s*(?:ROOT )?%%(\S+) = bf16\[%d,%d,%d\]" % (b, s, 3 * hidden),
+        entry, flags=re.M)
+    # the forward projection's output (and the compiler's asynchronous
+    # copy of it to its other memory), nothing the backward wrote
+    assert packed and all(
+        re.match(r"convolution|copy-done", name) for name in packed), packed
 
 
 @pytest.mark.parametrize("bh,s,d,causal,dtype", [
