@@ -76,7 +76,7 @@ def main(argv=None):
             "gaps": gaps, "worst": worst, "losses": losses, **more}),
             flush=True)
 
-    ref_mod = harness.load_module("reference", config["reference"])
+    ref_mod = harness.config_module(config, "reference", "reference")
     spec = ref_mod.param_spec(config)
     segments = ref_mod.leaf_segments(config)
     dtype = config["training"]["param_dtype"]

@@ -193,7 +193,7 @@ def checks_of(gaps, limits, exact=()):
 
 def reference_readings(cell, steps, quant=None, rows=None):
     """The plain reference over the first ``steps`` batches of the seed."""
-    ref = cell.load_module("reference", cell.config["reference"])
+    ref = cell.config_module("reference", "reference")
     traffic, cfg = cell.workload["traffic"], cell.config
     spec = ref.param_spec(cfg)
     batches = weights.make_batches(cell.seed, steps, traffic["batch"],
@@ -204,6 +204,14 @@ def reference_readings(cell, steps, quant=None, rows=None):
             lambda: weights.make_params(cell.seed, spec,
                                         cfg["training"]["param_dtype"]),
             batches, quant=quant, rows=rows)
+
+
+def kernels_missing(required: dict, census: dict) -> int:
+    """Calls of Mosaic kernels that the executable has to hold
+    (``program.mosaic_kernels`` of the configuration: name -> calls) and
+    ``census`` (name -> calls found) does not: 0 where every kernel
+    stands as often as required or more."""
+    return sum(max(0, n - census.get(k, 0)) for k, n in required.items())
 
 
 def _free(*trees):
@@ -226,7 +234,11 @@ def run(cell) -> dict:
     def mark(name):
         marks.append((name, time.perf_counter()))
 
-    ref = cell.load_module("reference", cfg["reference"])
+    ref = cell.config_module("reference", "reference")
+    flops_per_token = cell.config_module(
+        "op_count", "op_counts").train_flops_per_token(cfg, seqlen)
+    cell.note(f"operations a token at s{seqlen}: {flops_per_token!r} "
+              f"(op_counts/{cfg['op_count']}.py)")
     spec = ref.param_spec(cfg)
     params = weights.make_params(cell.seed, spec,
                                  cfg["training"]["param_dtype"])
@@ -264,10 +276,10 @@ def run(cell) -> dict:
                 for k, n in ((h.get("analysis") or {})
                              .get("mosaic_kernels") or {}).items():
                     census[k] = max(census.get(k, 0), n)
-        missing = sum(max(0, cfg["num_layers"] - census.get(k, 0))
-                      for k in prog["mosaic_kernels_per_layer"])
-        cell.note(f"mosaic kernels in the step: {census}; builds in "
-                  f"set-up: {builds_setup}")
+        missing = kernels_missing(prog["mosaic_kernels"], census)
+        cell.note(f"mosaic kernels in the step: {census}, required "
+                  f"{prog['mosaic_kernels']}; builds in set-up: "
+                  f"{builds_setup}")
 
     # ------------------------------------------------------------ window --
     t_open = time.perf_counter()
@@ -345,6 +357,7 @@ def run(cell) -> dict:
         "end_to_end": {"train_tokens_per_s": rate, "setup_s": setup_s},
         "checks": checks,
         "facts": {"batch": batch, "seqlen": seqlen, "tokens_per_s": rate,
+                  "train_flops_per_token": flops_per_token,
                   "step_gaps_s": step_gaps,
                   "memory_peak_bytes": memory_peak, "traced": traced},
     }

@@ -1,34 +1,14 @@
-"""Operations and bytes computed from shapes: the numerators of every
-utilisation and roofline share.  Nothing here looks at the program, so a
-PR that swaps a kernel or a fusion leaves the work it is measured against
-unchanged.
+"""Operations and bytes computed from shapes, the part that every
+architecture shares: one layer's causal attention, and the least time the
+chip could take for a piece of work.  Nothing here looks at the program,
+so a PR that swaps a kernel or a fusion leaves the work it is measured
+against unchanged.
 
-Training operations per token are a GPT's: both configurations are
-``GPTForCausalLM``.  An architecture that counts otherwise brings a module
-of its own, named by its configuration file as ``reference`` is.
+What a whole training step costs a token is the configuration's to name:
+``op_counts/<name>.py``, found through the ``op_count`` key of its file.
 """
 
 from __future__ import annotations
-
-
-# --------------------------------------------------------------------- gpt --
-
-def gpt_matmul_params(cfg: dict) -> int:
-    """Parameters that sit in a matmul of the forward pass: per block
-    qkv (3h^2), out (h^2), fc_in and fc_out (2 h f), and the tied head
-    (vocab x h).  Embedding lookups, biases and norms are not matmuls."""
-    h = cfg["hidden_size"]
-    f = cfg.get("intermediate_size") or 4 * h
-    return cfg["num_layers"] * (4 * h * h + 2 * h * f) + cfg["vocab_size"] * h
-
-
-def train_flops_per_token(cfg: dict, seqlen: int) -> float:
-    """Forward + backward operations one token requires: 6 per matmul
-    parameter (2 forward, 4 backward) plus attention's score and context
-    matmuls, 12 x layers x hidden x seqlen (the PaLM appendix-B count: the
-    full square, not the causal half, and nothing recomputed)."""
-    return 6.0 * gpt_matmul_params(cfg) \
-        + 12.0 * cfg["num_layers"] * cfg["hidden_size"] * seqlen
 
 
 # --------------------------------------------------------------- attention --

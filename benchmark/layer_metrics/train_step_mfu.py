@@ -1,14 +1,13 @@
-"""Whole step's share of the chip's bf16 peak, in %: operations per token
-from shapes (``flops.py``) x the window's tokens per second, over the
-peak of the run's ``device_kind`` (``peaks.json``) x the chips used."""
-
-from benchmark import flops
+"""Whole step's share of the chip's bf16 peak, in %: the operations a token
+costs, as the module that the configuration names counts them from shapes
+(``op_count`` -> ``op_counts/<name>.py``; the driver calls it at the
+cell's sequence length) x the window's tokens per second, over the peak
+of the run's ``device_kind`` (``peaks.json``) x the chips used."""
 
 
 def read(run):
     if run["peaks"] is None:
         return None
     f = run["facts"]
-    per_token = flops.train_flops_per_token(run["config"], f["seqlen"])
-    return 100.0 * per_token * f["tokens_per_s"] / (
+    return 100.0 * f["train_flops_per_token"] * f["tokens_per_s"] / (
         run["peaks"]["bf16_flops_per_s"] * run["chips"])

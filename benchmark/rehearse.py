@@ -52,7 +52,7 @@ def main(argv=None):
                                         topology_name=args.topology)
 
     cell = harness.Cell(workload, config, seed=0)
-    ref = harness.load_module("reference", config["reference"])
+    ref = harness.config_module(config, "reference", "reference")
     spec = ref.param_spec(config)
     dtype = jnp.dtype(config["training"]["param_dtype"])
     params = {k: jnp.zeros(shape, dtype) for k, shape in spec.items()}
@@ -100,10 +100,15 @@ def main(argv=None):
     print(f"  arguments + outputs - aliased + temporaries: {live / gib:.3f}"
           " GiB of the chip's 16")
     calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
-    for k in config["program"]["mosaic_kernels_per_layer"]:
-        print(f"  {k}: {sum(k in line for line in calls)} Mosaic calls "
-              f"in the compiled HLO ({config['num_layers']} layers)")
-    return 0
+    required = config["program"]["mosaic_kernels"]
+    found = {k: sum(k in line for line in calls) for k in required}
+    for k, n in required.items():
+        print(f"  {k}: {found[k]} Mosaic calls in the compiled HLO, "
+              f"{n} required")
+    missing = train_steps.kernels_missing(required, found)
+    print(f"  mosaic_kernels_missing: {missing} ({len(calls)} Mosaic "
+          "calls in all)")
+    return 1 if missing else 0
 
 
 if __name__ == "__main__":

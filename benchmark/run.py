@@ -28,7 +28,8 @@ EXIT_NO_CHIP = 3
 
 class Cell:
     """What a driver is handed: the cell's files, the arguments, and the
-    harness's two services (find a module by name; keep a note)."""
+    harness's two services (find a module that the configuration names;
+    keep a note)."""
 
     def __init__(self, workload, config, seed, seconds=0.0, trace=False,
                  t_start=0.0):
@@ -42,9 +43,8 @@ class Cell:
     def note(self, text):
         self.notes.append(text)
 
-    @staticmethod
-    def load_module(kind, name):
-        return load_module(kind, name)
+    def config_module(self, key, kind):
+        return config_module(self.config, key, kind)
 
 
 def load_json(kind, name):
@@ -67,6 +67,17 @@ def load_module(kind, name):
     sys.modules[modname] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def config_module(config, key, kind):
+    """The module of ``<kind>/`` that a configuration's file names under
+    ``key``: its ``reference`` (``reference/``), its ``op_count``
+    (``op_counts/``).  There is no default: a file that names none ends
+    the run as a name without a file does."""
+    if not config.get(key):
+        raise SystemExit(f"benchmark: configs/{config['name']}.json names "
+                         f"no {key}: no {kind}/<name>.py")
+    return load_module(kind, config[key])
 
 
 def enable_compile_cache():

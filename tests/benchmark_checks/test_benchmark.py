@@ -8,9 +8,13 @@ They load no libtpu and report no device number.  The harness runs end to
 end on the rehearsal cell; the yardstick's arithmetic is held to hand
 counts; the plain reference is held to the program's float32 forward and
 to ``jax.grad`` of itself; the control (float8) and each fault a training
-cell can have come out as not correct.
+cell can have come out as not correct.  A second rehearsal cell runs a
+language model that is no GPT (``gated_conv_lm.py`` here, its reference,
+count, configuration and workload under ``benchmark/``): the harness
+takes an architecture by its files alone.
 """
 
+import glob
 import json
 import math
 import os
@@ -26,6 +30,8 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 BENCH = os.path.join(ROOT, "benchmark")
 TINY = "gpt-tiny-rehearsal.train-s64"
+TOY = "gated-conv-tiny-rehearsal.train-s64"   # a model that is no GPT
+REHEARSALS = (TINY, TOY)
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -39,6 +45,17 @@ def _manifest():
 def _load(kind, name):
     with open(os.path.join(BENCH, kind, name + ".json")) as f:
         return json.load(f)
+
+
+def _names_what_exists(cfg):
+    """A configuration's file names its reference and its operation count
+    by files that are there, and the Mosaic kernels its step has to hold
+    as name -> calls."""
+    for key, kind in (("reference", "reference"), ("op_count", "op_counts")):
+        assert os.path.isfile(os.path.join(BENCH, kind, cfg[key] + ".py"))
+    kernels = cfg["program"]["mosaic_kernels"]
+    assert all(NAME.match(k) and type(n) is int and n > 0
+               for k, n in kernels.items())
 
 
 # ------------------------------------------------------------- the manifest
@@ -73,6 +90,8 @@ def test_manifest_names_units_and_files():
         on_file = _load("configs", c["name"])
         assert on_file["reduced"] == c["reduced"]
         assert on_file["source"] == c["source"]
+        _names_what_exists(on_file)
+        assert on_file["program"]["mosaic_kernels"]   # a cell's step has some
     assert {w["config"] for w in m["workloads"]} == set(configs)
     pairs = [(w["config"], w["traffic"]) for w in m["workloads"]]
     assert len(set(pairs)) == len(pairs)
@@ -88,10 +107,32 @@ def test_manifest_names_units_and_files():
         assert all(isinstance(v, float) and v > 0 for v in limits.values())
 
 
-def test_rehearsal_cell_is_found_by_name_and_is_no_cell():
-    names = {w["name"] for w in _manifest()["workloads"]}
-    assert TINY not in names
-    assert _load("workloads", TINY)["rehearsal"] is True
+@pytest.mark.parametrize("workload", REHEARSALS)
+def test_rehearsal_cell_is_found_by_name_and_is_no_cell(workload):
+    m = _manifest()
+    assert workload not in {w["name"] for w in m["workloads"]}
+    cell = _load("workloads", workload)
+    assert cell["rehearsal"] is True
+    assert cell["config"] not in {c["name"] for c in m["configs"]}
+    _names_what_exists(_load("configs", cell["config"]))
+
+
+def test_the_harness_names_no_architecture():
+    """What is general takes an architecture through the names in its
+    configuration's file: no general file names one itself."""
+    words = {os.path.splitext(f)[0]
+             for f in os.listdir(os.path.join(BENCH, "op_counts"))
+             if f.endswith(".py")}
+    assert {"gpt", "gated_conv"} <= words
+    general = glob.glob(os.path.join(BENCH, "*.py")) \
+        + glob.glob(os.path.join(BENCH, "drivers", "*.py")) + [
+        os.path.join(BENCH, "layer_metrics", m["name"] + ".py")
+        for m in _manifest()["per_layer"] if "workloads" not in m]
+    assert len(general) >= 14
+    for path in general:
+        with open(path) as f:
+            text = f.read().lower()
+        assert not [w for w in words if w in text], path
 
 
 # ------------------------------------------------------------ the yardstick
@@ -99,15 +140,73 @@ def test_rehearsal_cell_is_found_by_name_and_is_no_cell():
 @pytest.mark.parametrize("config, gflop, params_m", [
     ("gpt3-1.3b", 8.47, 1313.7), ("gpt2-medium", 2.42, 354.9)])
 def test_flops_per_token_hand_counts(config, gflop, params_m):
-    from benchmark import flops
+    from benchmark import run as harness
     from benchmark.reference import gpt_f32
     cfg = _load("configs", config)
     h, L, v, s = cfg["hidden_size"], cfg["num_layers"], cfg["vocab_size"], 1024
     by_hand = 6 * (L * 12 * h * h + v * h) + 12 * L * h * s
-    assert flops.train_flops_per_token(cfg, s) == by_hand
+    count = harness.config_module(cfg, "op_count", "op_counts")
+    assert count.train_flops_per_token(cfg, s) == by_hand
     assert round(by_hand / 1e9, 2) == gflop
     n = sum(math.prod(shape) for shape in gpt_f32.param_spec(cfg).values())
     assert round(n / 1e6, 1) == params_m == cfg["parameters_millions"]
+
+
+TOY_FLOPS = 497664   # by hand, below
+
+
+def test_the_toys_count_is_its_own_hand_count():
+    """h 64, inner 128, 2 blocks, 4 taps, vocabulary 512: per block the
+    fused input projection 64 x 256 and the output projection 128 x 64,
+    the untied head 64 x 512; nothing for the embedding's lookup, nothing
+    that grows with the sequence.  A GPT's count of the same file would
+    need ``num_heads`` and a tied head."""
+    from benchmark import run as harness
+    cfg = _load("configs", _load("workloads", TOY)["config"])
+    count = harness.config_module(cfg, "op_count", "op_counts")
+    by_hand = 6 * (2 * (64 * 256 + 128 * 64) + 64 * 512) + 6 * 2 * 4 * 128
+    assert by_hand == TOY_FLOPS
+    assert count.train_flops_per_token(cfg, 64) == by_hand \
+        == count.train_flops_per_token(cfg, 1024)
+    ref = harness.config_module(cfg, "reference", "reference")
+    n = sum(math.prod(shape) for shape in ref.param_spec(cfg).values())
+    assert round(n / 1e6, 1) == cfg["parameters_millions"]
+
+
+@pytest.mark.parametrize("key, value", [("op_count", None),
+                                        ("op_count", "no_such_count"),
+                                        ("reference", None)])
+def test_a_configuration_that_names_no_module_ends_by_the_harness(
+        monkeypatch, key, value):
+    """No default and no ``KeyError``: the run ends in set-up, before any
+    weight is drawn, by the harness's own message."""
+    from benchmark import run as harness
+    real = harness.load_json
+
+    def load_json(kind, name):
+        got = real(kind, name)
+        if kind == "configs":
+            got = {k: v for k, v in got.items() if k != key}
+            if value is not None:
+                got[key] = value
+        return got
+    monkeypatch.setattr(harness, "load_json", load_json)
+    with pytest.raises(SystemExit) as end:
+        harness.main(["--workload", TINY, "--seed", "1", "--seconds", "0.1"])
+    kind = {"op_count": "op_counts", "reference": "reference"}[key]
+    assert f"no {kind}/" in str(end.value)
+    assert str(end.value).startswith("benchmark: ")
+
+
+@pytest.mark.parametrize("required, census, missing", [
+    ({"a": 2, "b": 6}, {"a": 2, "b": 6}, 0),
+    ({"a": 2, "b": 6}, {"a": 2, "b": 5}, 1),
+    ({"a": 2, "b": 6}, {"b": 6}, 2),              # an absent name: all of it
+    ({"a": 2, "b": 6}, {"a": 9, "b": 4, "c": 7}, 2),   # a surplus pays nothing
+    ({}, {"c": 7}, 0)])                           # a step that needs none
+def test_kernels_missing_counts_calls_by_name(required, census, missing):
+    from benchmark.drivers import train_steps
+    assert train_steps.kernels_missing(required, census) == missing
 
 
 def test_attention_work_and_roofline_side():
@@ -242,8 +341,12 @@ def _run(capsys, *extra, workload=TINY, seed=3000000019, seconds="0.3"):
     return rc, out
 
 
-def test_run_end_to_end_on_the_rehearsal_cell(capsys):
-    rc, out = _run(capsys, "--trace", "0")
+@pytest.mark.parametrize("workload, flops_per_token", [
+    (TINY, 6 * (2 * 12 * 64 * 64 + 512 * 64) + 12 * 2 * 64 * 64),
+    (TOY, TOY_FLOPS)])
+def test_run_end_to_end_on_the_rehearsal_cell(capsys, workload,
+                                              flops_per_token):
+    rc, out = _run(capsys, "--trace", "0", workload=workload)
     line = json.loads(out.out.strip().splitlines()[-1])
     assert rc == 0 and line["correct"] is True
     assert list(line)[-1] == "checks"
@@ -257,6 +360,9 @@ def test_run_end_to_end_on_the_rehearsal_cell(capsys):
         assert c["value"] <= c["limit"], name
         assert f"check {name} = " in out.err   # each number beside its limit
     assert out.err.strip().splitlines()[-1].startswith("benchmark: check ")
+    # the count is the one the configuration's file names
+    assert f"operations a token at s64: {float(flops_per_token)!r} " \
+        in out.err
 
 
 def test_a_real_cell_refuses_to_run_without_the_chip(capsys):
@@ -291,12 +397,14 @@ def _half_batch(real_build):
     return build
 
 
+@pytest.mark.parametrize("workload", REHEARSALS)
 @pytest.mark.parametrize("fault", [_state_unchanged, _half_batch])
-def test_a_broken_timed_path_comes_out_not_correct(capsys, monkeypatch, fault):
+def test_a_broken_timed_path_comes_out_not_correct(capsys, monkeypatch, fault,
+                                                   workload):
     from benchmark import run as harness
     driver = harness.load_module("drivers", "train_steps")
     monkeypatch.setattr(driver, "build_program", fault(driver.build_program))
-    rc, out = _run(capsys, "--trace", "0")
+    rc, out = _run(capsys, "--trace", "0", workload=workload)
     line = json.loads(out.out.strip().splitlines()[-1])
     assert rc != 0 and line["correct"] is False
     failed = [k for k, c in line["checks"].items()
@@ -304,23 +412,30 @@ def test_a_broken_timed_path_comes_out_not_correct(capsys, monkeypatch, fault):
     assert set(failed) & {"grad_norm_gap", "change_norm_gap"}, failed
 
 
-def test_control_gives_the_harness_verdict_on_program_control_and_fault(capsys):
+@pytest.mark.parametrize("workload, control_fails", [(TINY, True),
+                                                     (TOY, False)])
+def test_control_gives_the_harness_verdict_on_program_control_and_fault(
+        capsys, workload, control_fails):
     """``control.py`` at a size a test run can hold: the program on two
     seeds comes out correct; the reference put in its place in float8 (the
     control) and with half of the batch left out (the fault) comes out not
     correct on three, by the harness's own verdict under the rehearsal
-    cell's limits."""
+    cell's limits.  The model that is no GPT goes through the same file;
+    at its toy size the float8 control does not separate (its workload's
+    ``limits_note`` has the readings), so there only the fault is held."""
     from benchmark import control
-    rc = control.main(["--workload", TINY, "--seeds", "4,5",
+    rc = control.main(["--workload", workload, "--seeds", "4,5",
                        "--control-seeds", "5,6,3000000007"])
     lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
-    assert rc == 0
     got = {}
     for rec in lines:
         got.setdefault(rec["kind"], []).append(rec["correct"])
         assert set(rec["checks"]) == {"grad_norm_gap", "change_norm_gap"}
-    assert got == {"program": [True] * 2, "control_fp8": [False] * 3,
-                   "fault_half_batch": [False] * 3}
+    assert got["program"] == [True] * 2
+    assert got["fault_half_batch"] == [False] * 3
+    assert len(got["control_fp8"]) == 3 and len(got) == 3
+    if control_fails:
+        assert rc == 0 and got["control_fp8"] == [False] * 3
 
 
 def test_compare_leaves_dead_leaves_out_and_takes_the_worst_live_leaf():

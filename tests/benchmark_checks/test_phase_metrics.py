@@ -180,12 +180,36 @@ def test_manifest_gives_the_new_readers_to_both_training_cells():
         == {"setup_s"}
 
 
-def test_rehearsal_cell_runs_traced_and_reads_no_time_on_the_cpu(capsys):
+@pytest.mark.parametrize("workload", [
+    TINY, "gated-conv-tiny-rehearsal.train-s64"])
+def test_rehearsal_cell_runs_traced_and_reads_no_time_on_the_cpu(capsys,
+                                                                 workload):
+    """Every per-layer reader of the manifest is asked, the attention
+    readers too, on a GPT and on a model that has no heads to name."""
     from benchmark import run as harness
-    rc = harness.main(["--workload", TINY, "--seed", "3000000023",
+    rc = harness.main(["--workload", workload, "--seed", "3000000023",
                        "--seconds", "0.3", "--trace", "1"])
     out = capsys.readouterr()
     line = json.loads(out.out.strip().splitlines()[-1])
     assert rc == 0 and line["correct"] is True
     assert line["metrics"] == {}
     assert not any(n in k for k in line["rehearsal"] for n in READERS)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_train_step_mfu_is_count_times_rate_over_peak(chips):
+    """The reader multiplies what the driver put in ``facts``: the count
+    of the module the configuration names (here the rehearsal's model that
+    is no GPT, 497,664 operations a token) and the window's rate."""
+    from benchmark import run as harness
+    cfg = harness.load_json("configs", "gated-conv-tiny-rehearsal")
+    count = harness.config_module(cfg, "op_count", "op_counts")
+    run = {"config": cfg, "peaks": {"bf16_flops_per_s": 1e12},
+           "chips": chips, "notes": [],
+           "facts": {"tokens_per_s": 1e6, "seqlen": 64,
+                     "train_flops_per_token":
+                         count.train_flops_per_token(cfg, 64)}}
+    assert _read("train_step_mfu", run) == pytest.approx(
+        49.7664 / chips, rel=1e-12)
+    run["peaks"] = None   # a rehearsal: no share of a peak from a CPU run
+    assert _read("train_step_mfu", run) is None
