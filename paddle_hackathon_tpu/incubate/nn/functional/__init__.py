@@ -22,6 +22,8 @@ from ....core.tensor import Tensor
 from ..kernels import flash_attention as _fa
 from ..kernels import flash_attention_packed as _fap
 from ..kernels import mesh as _mesh
+from .gated_delta_rule import (causal_depthwise_conv,  # noqa: F401
+                               gated_delta_rule, gated_delta_rule_chunked)
 
 
 def _t(x):

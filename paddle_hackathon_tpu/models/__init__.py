@@ -7,3 +7,5 @@ from .bert import (BertConfig, BertForPretraining,  # noqa: F401
                    ErnieForPretraining, ErnieForSequenceClassification,
                    bert_config, bert_mlm_pipeline, bert_param_sharding_spec,
                    ernie_config, masked_mlm_loss)
+from .qwen3_next import (Qwen3NextConfig, Qwen3NextForCausalLM,  # noqa: F401
+                         qwen3_next_sharding_spec)

@@ -9,6 +9,7 @@ fused layernorm+residual+dropout (incubate/) covers the transformer hot path.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ...core.autograd import apply_op
@@ -184,15 +185,21 @@ def local_response_norm(x, size, alpha=1e-4, beta=0.75, k=1.0,
     return apply_op("local_response_norm", fn, [_t(x)])
 
 
+def rms_norm_f32(v, weight=None, epsilon=1e-6):
+    """RMSNorm of an array over its last axis, in float32 whatever the
+    input's dtype (a bfloat16 mean of squares is 8 bits of a sum), and
+    left in float32 for the caller to round once."""
+    v32 = v.astype(jnp.float32)
+    out = v32 * jax.lax.rsqrt(
+        jnp.mean(jnp.square(v32), axis=-1, keepdims=True) + epsilon)
+    return out if weight is None else out * weight.astype(jnp.float32)
+
+
 def rms_norm(x, weight=None, epsilon=1e-6, name=None):
     """RMSNorm — not in the reference (2022-era) but required by modern LM
     parity; the Pallas fused version lives in incubate/."""
     def fn(v, *rest):
-        ms = jnp.mean(jnp.square(v), axis=-1, keepdims=True)
-        out = v / jnp.sqrt(ms + epsilon)
-        if rest:
-            out = out * rest[0]
-        return out
+        return rms_norm_f32(v, *rest, epsilon=epsilon).astype(v.dtype)
     args = [_t(x)]
     if weight is not None:
         args.append(_t(weight))

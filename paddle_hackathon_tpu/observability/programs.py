@@ -74,7 +74,7 @@ from .sanitizers import make_lock
 __all__ = ["ProgramRegistry", "get_program_registry", "capture_signature",
            "diff_signatures", "signature_from_spec_key", "program_analysis",
            "mosaic_kernels", "phase_census", "phase_counts",
-           "PHASE_COMPONENTS", "PHASES", "start_build_clock",
+           "PHASE_COMPONENTS", "PHASE_SUBCOMPONENTS", "PHASES", "start_build_clock",
            "read_build_clock", "BUILD_CLOCK_KEYS", "note_kernel_fact",
            "read_kernel_facts",
            "analysis_enabled", "observe_static_build",
@@ -322,10 +322,17 @@ def mosaic_kernels(hlo_text: str) -> Dict[str, int]:
     return out
 
 
-# The train step's scopes (``parallel/api.py``, ``models/gpt.py``): the
-# names a component can take in the phase census.
+# The train step's scopes (``parallel/api.py``, ``models/gpt.py``,
+# ``models/qwen3_next.py``): the names a component can take in the phase
+# census.  A scope of PHASE_SUBCOMPONENTS names a part of the component it
+# is nested in (``gdn_rule`` inside ``gdn``, ``experts`` inside ``moe``):
+# an instruction under both reads ``"gdn/gdn_rule"``, so a part's time can
+# be told from its parent's, and a parent's is the sum over
+# ``component.split("/")[0]``.
 PHASE_COMPONENTS = ("embed", "attn", "mlp", "ln_f", "lm_head", "ce",
-                    "clip", "update")
+                    "clip", "update", "gdn", "moe")
+PHASE_SUBCOMPONENTS = ("gdn_conv", "gdn_rule", "router", "experts",
+                       "shared_expert")
 PHASES = ("fwd", "bwd", "clip", "update", "other")
 
 _COMPUTATION_RE = re.compile(r'^(?:ENTRY )?%?([^\s(]+) \(.*\{$')
@@ -363,6 +370,11 @@ def _phase_of(op_name: str) -> Tuple[str, str]:
     """``(phase, component)`` of one instruction's ``op_name``."""
     scopes = _scopes(op_name)
     component = next((s for s in scopes if s in PHASE_COMPONENTS), "")
+    if component:
+        inner = scopes[scopes.index(component) + 1:]
+        part = next((s for s in inner if s in PHASE_SUBCOMPONENTS), "")
+        if part:
+            component += "/" + part
     if "transpose(jvp(" in op_name:
         return "bwd", component
     if "jvp(" in op_name:
@@ -387,8 +399,9 @@ def phase_census(hlo_text: str) -> Dict[str, Tuple[str, str, bool]]:
     ``bwd`` where it holds ``transpose(jvp(``, else ``fwd`` where it
     holds ``jvp(``, else ``clip`` / ``update`` under those scopes, else
     ``other`` (no metadata, or a program without autodiff);
-    ``component`` is the first of :data:`PHASE_COMPONENTS` on the stack,
-    else ``""``.  A fusion takes its own ``op_name``; where the
+    ``component`` is the first of :data:`PHASE_COMPONENTS` on the stack
+    (``"gdn/gdn_rule"`` where a scope of :data:`PHASE_SUBCOMPONENTS` is
+    nested in it), else ``""``.  A fusion takes its own ``op_name``; where the
     instructions fused into it belong to more than one phase it is
     ``mixed``, and goes to the phase and component of the single
     ``convolution`` / ``dot`` inside it if there is exactly one (the
@@ -644,12 +657,14 @@ class ProgramRegistry:
     """Process-wide program-build ledger, one :class:`_Site` per site
     label.  Lock-disciplined (:func:`sanitizers.make_lock`; the lock is
     a leaf — flight/metrics/tracing emission happens outside it) and
-    build-path-only: nothing here runs on a steady-state call."""
+    build-path-only: nothing here runs on a steady-state call but
+    :meth:`note_counters`, which swaps one reference."""
 
     def __init__(self, history: int = HISTORY_PER_SITE):
         self._lock = make_lock("observability.programs")
         self._sites: Dict[str, _Site] = {}
         self._history = int(history)
+        self._counters: Dict[str, Any] = {}
 
     # -- build-path reporting ----------------------------------------------
 
@@ -791,6 +806,26 @@ class ProgramRegistry:
                           int(t_end_ns - float(compile_s) * 1e9),
                           int(t_end_ns), _tid=COMPILES_LANE_TID, **attrs)
 
+    # -- program counters --------------------------------------------------
+
+    def note_counters(self, site: str, counters) -> None:
+        """Keep what ``site``'s newest call returned beside its result: a
+        pytree of device values that the program counted while it ran
+        (an expert layer's routed rows).  The one steady-state call of
+        this class: a reference is swapped, nothing is read and no lock
+        is taken, so the step that hands it over stays asynchronous."""
+        self._counters[site] = counters
+
+    def counters(self, site: str):
+        """The newest :meth:`note_counters` of ``site`` as host numbers
+        (lists for vectors), read from the device now; ``None`` where the
+        site has noted none."""
+        tree = self._counters.get(site)
+        if tree is None:
+            return None
+        import jax
+        return jax.tree.map(lambda a: a.tolist(), jax.device_get(tree))
+
     # -- snapshots ---------------------------------------------------------
 
     def phase_census(self, site: str) -> Optional[dict]:
@@ -868,6 +903,7 @@ class ProgramRegistry:
         """Drop every site (test isolation)."""
         with self._lock:
             self._sites.clear()
+        self._counters.clear()
 
 
 # ---------------------------------------------------------------------------

@@ -37,8 +37,8 @@ from .pipeline import (LayerDesc, PipelineLayer,  # noqa: F401
 from .sequence import (disable_sequence_parallel,  # noqa: F401
                        enable_sequence_parallel, ring_attention,
                        ulysses_attention)
-from .moe import (GShardGate, MoELayer, NaiveGate, SwitchGate,  # noqa: F401
-                  moe_active_params, moe_all_to_all)
+from .moe import (DroplessMoELayer, GShardGate, MoELayer,  # noqa: F401
+                  NaiveGate, SwitchGate, moe_active_params, moe_all_to_all)
 from .multislice import (create_multislice_mesh,  # noqa: F401
                          dcn_traffic_axes)
 from .sharding import (ZeroShardInfo,  # noqa: F401

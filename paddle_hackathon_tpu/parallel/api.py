@@ -657,6 +657,7 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
     if grad_overlap and not zero_on:
         grad_overlap = False  # nothing to scatter onto — inert
 
+    default_loss = loss_fn is None and pp_degree == 1
     if pp_degree > 1:
         loss_fn = _make_pipeline_loss(
             mesh, pp_spec, pp_degree,
@@ -742,9 +743,18 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
     step_no = jax.device_put(jnp.zeros((), jnp.int32),
                              NamedSharding(mesh, P()))
 
+    # the default loss traces the model's forward in this frame, so what
+    # its expert layers left on themselves (their routing counters) can
+    # leave the program beside the loss; a custom or pipelined loss owns
+    # its forward, and what it traced inside may not escape it
+    from .moe import collect_router_counters
+    counters_of = collect_router_counters if default_loss else \
+        (lambda _model: {})
+
     def loss_of(batch, rng):
         def pure_loss(p):
-            return loss_fn(model, p, buffers, batch, rng)
+            loss = loss_fn(model, p, buffers, batch, rng)
+            return loss, counters_of(model)
 
         if recompute:
             # remat the whole forward (ref recompute meta-optimizer /
@@ -760,20 +770,22 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
     # PR 30: three frames, +0.85 to +3.0 s of set-up).
 
     def train_step(params, opt_state, step_no, batch, rng, lr):
-        loss_grads = jax.value_and_grad(loss_of(batch, rng))(params)
+        (loss, counters), grads = jax.value_and_grad(
+            loss_of(batch, rng), has_aux=True)(params)
         # the shared body: [overlap pins] -> clip -> update
         body = make_functional_train_step(
-            opt, plist, order, lambda *_: loss_grads, shard_info=update_si,
-            grad_overlap=grad_overlap)
+            opt, plist, order, lambda *_: (loss, grads),
+            shard_info=update_si, grad_overlap=grad_overlap)
         new_params, new_states, new_step, loss = body(
             params, [_full(opt_state[k]) for k in order], step_no, lr, batch)
         return (new_params, dict(zip(order, map(_short, new_states))),
-                new_step, loss)
+                new_step, loss, counters)
 
     def grads_step(params, step_no, batch, rng, lr):
         # offload's device half: forward + backward + the grad preamble on
         # the replicated gradients (the resident ZeRO step's own), no update
-        loss, grads = jax.value_and_grad(loss_of(batch, rng))(params)
+        (loss, _), grads = jax.value_and_grad(
+            loss_of(batch, rng), has_aux=True)(params)
         gs = [grads[k] for k in order]
         if grad_overlap:
             gs = [jax.lax.with_sharding_constraint(g, sh)
@@ -811,7 +823,8 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
                 # XLA may pick a different layout for the updated params,
                 # forcing a re-jit (and a second full compile) on the next
                 # step.
-                out_shardings=(param_sh, opt_sh, scalar_sh, scalar_sh),
+                out_shardings=(param_sh, opt_sh, scalar_sh, scalar_sh,
+                               scalar_sh),
             ), site=site), donate_argnums=(0, 1, 2), site=site)
         grads_jitted = _obs.instrument_jit(jax.jit(
             grads_step,
@@ -825,7 +838,7 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
                 [params[k] for k in order], gs,
                 [_full(opt_state[k]) for k in order], lr, t)
             return (dict(zip(order, new_vals)),
-                    dict(zip(order, map(_short, new_host))), t, loss)
+                    dict(zip(order, map(_short, new_host))), t, loss, {})
 
         streamed._jit_fn = grads_jitted._jit_fn
         return streamed
@@ -878,9 +891,15 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
             # ambient mesh at trace time (_smap.run_shard_map); harmless
             # otherwise
             with _tracing.span("train.dispatch"), _set_mesh(mesh):
-                new_params, new_opt, new_step, loss = fn(
+                new_params, new_opt, new_step, loss, counters = fn(
                     state["params"], state["opt_state"], state["step"],
                     (ids, labels), rng, lr_now)
+            if counters:
+                # device values, not read here: the observatory keeps the
+                # newest step's and reads them when asked
+                from ..observability.programs import get_program_registry
+                get_program_registry().note_counters(
+                    "parallel.sharded_train_step", counters)
             # The old param buffers were donated; rebind the live model's
             # tensors to the updated arrays so the Layer stays usable
             # (eval, jit.save, checkpointing) throughout training.  Stacked

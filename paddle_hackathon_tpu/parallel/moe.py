@@ -65,6 +65,20 @@ def _one_hot(idx, n, dtype=jnp.float32):
     return jax.nn.one_hot(idx, n, dtype=dtype)
 
 
+def softmax_topk(logits, topk: int, renormalize: bool):
+    """The router's choice, shared by every expert layer of this file:
+    ``probs`` = softmax over all experts in the logits' dtype (a caller
+    that wants a float32 router hands float32 logits), the ``topk``
+    largest and their indices, renormalised to sum 1 where asked.
+    Returns ``(probs, gate_vals (n, k), idx (n, k))``."""
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, idx = jax.lax.top_k(probs, topk)
+    if renormalize:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
+    return probs, gate_vals, idx
+
+
 def _balance_loss(probs, idx, num_experts):
     """GShard/Switch load-balance aux: E * sum_e mean(gate_e) * frac_e."""
     me = probs.mean(0)
@@ -94,11 +108,8 @@ class NaiveGate(Layer):
         renormalization would pin the weight at 1.0 and starve the
         router of any gradient except the aux loss (PR 9 fix, pinned by
         tests/test_moe.py::test_top1_router_gradient_flows)."""
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, idx = jax.lax.top_k(probs, self.topk)
-        if self.topk > 1:
-            gate_vals = gate_vals / jnp.maximum(
-                gate_vals.sum(-1, keepdims=True), 1e-9)
+        probs, gate_vals, idx = softmax_topk(logits, self.topk,
+                                             self.topk > 1)
         aux = (_balance_loss(probs, idx, self.num_experts) if self.aux
                else jnp.zeros((), jnp.float32))
         return gate_vals, idx, aux
@@ -314,6 +325,245 @@ class MoELayer(Layer):
             self.router_stats = None
         self.l_aux = aux
         return y
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k experts, told which experts this chip holds
+# ---------------------------------------------------------------------------
+
+# what a DroplessMoELayer's forward leaves on ``router_counters``, in order
+ROUTER_COUNTERS = ("rows_routed_here", "row_bound", "rows_largest_expert",
+                   "rows_mean_expert")
+
+
+def _narrow(dtype):
+    """The package's default matmul precision is "highest" (float32 means
+    float32); operands narrower than that have one pass to take, and the
+    chip's grouped-matmul kernel refuses to be asked for more."""
+    return None if jnp.dtype(dtype).itemsize >= 4 \
+        else jax.lax.Precision.DEFAULT
+
+
+def _row_slice(n, k, count, num_experts):
+    """Rows the grouped products take at a time: twice the even share.
+    A router whose ``n * k`` choices fall evenly on ``num_experts`` sends
+    ``count`` of them ``n k count / num_experts`` rows, give or take a
+    few per cent (5,120 for 8,192 tokens, 10 choices and 32 of 512
+    experts; a router drawn N(0, 0.02) read 4,674-5,324 a layer on the
+    chip, ``PERF.md`` PR 33); systems that do drop size an expert's
+    buffer at 1.25 (this file's ``MoELayer``, Switch) to 2 (GShard)
+    times the even share, and a chip's load is the mean of ``count``
+    such experts'.  So one slice holds what a chip is sent under any
+    routing such a system would accept; whatever is beyond it runs as
+    further slices, each at a slice's whole cost.  In whole tiles of the
+    grouped-matmul kernel, never more than can be routed here."""
+    even = -(-n * k * count // num_experts)
+    return min(-(-2 * even // 512) * 512, n * min(k, count))
+
+
+def held_expert_outputs(tokens, gate_vals, idx, w_gate_up, w_down, first,
+                        num_experts):
+    """What the experts ``[first, first + count)`` of ``num_experts`` add
+    to every token, and the layer's counters.  ``tokens`` (n, d);
+    ``gate_vals`` / ``idx`` (n, k) the router's weights and choices over
+    ALL experts; ``w_gate_up`` (count, d, 2 * width) = [gate | up],
+    ``w_down`` (count, width, d).
+
+    Dropless and exact for any routing.  The (token, slot) pairs are
+    sorted by held expert (pairs of absent experts last); their rows are
+    gathered, go through the grouped products (``jax.lax.ragged_dot``),
+    are weighted by their pair's gate and summed into their tokens
+    (``segment_sum``, in float32).  All shapes are static: as many as
+    ``n * min(k, count)`` rows can be routed here (an expert stands at
+    most once among a token's k), sixteen times the usual traffic where a
+    chip holds a sixteenth of the experts, and a row costs its gather and
+    its masks whether routed or not.  So the sorted rows are taken a
+    slice at a time (:func:`_row_slice`) under a ``lax.scan`` over the
+    worst case's slices, and a slice that starts past the last routed row
+    is skipped (``lax.cond``): usually one slice runs.  No capacity, no
+    drop; what the absent experts would add is left out.
+
+    Rows past the last group belong to no expert: the grouped kernels do
+    not write them (forward or transposed), so each product's input and
+    output is masked to the rows routed -- the mask's transpose keeps
+    what the transposed kernels left there out of the gradients."""
+    n, d = tokens.shape
+    k, count = idx.shape[1], w_gate_up.shape[0]
+    width = w_down.shape[1]
+    local = idx.reshape(-1) - first
+    here = (local >= 0) & (local < count)
+    key = jnp.where(here, local, count)          # absent experts sort last
+    order = jnp.argsort(key)                     # stable: pairs keep order
+    group_sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], 0,
+                          dtype=jnp.int32)
+    ends = jnp.cumsum(group_sizes)
+    routed = ends[-1]
+    pair_weights = jnp.where(here, gate_vals.reshape(-1), 0.0)
+    precision = _narrow(tokens.dtype)
+    step = _row_slice(n, k, count, num_experts)
+    slices = -(-n * min(k, count) // step)
+    order = jnp.pad(order, (0, max(0, slices * step - order.shape[0])))
+
+    def rows_from(lo):
+        """The sorted rows [lo, lo + step) through the experts."""
+        sel = jax.lax.dynamic_slice(order, (lo,), (step,))
+        tok = sel // k
+        valid = (lo + jnp.arange(step) < routed)[:, None]
+        # each expert's rows that fall inside this slice
+        sizes = jnp.diff(jnp.clip(ends, lo, lo + step), prepend=lo)
+        rows = jnp.where(valid, jnp.take(tokens, tok, axis=0), 0)
+        hidden = jnp.where(valid, jax.lax.ragged_dot(
+            rows, w_gate_up, sizes, precision=precision), 0)
+        act = jax.nn.silu(hidden[:, :width]) * hidden[:, width:]
+        out = jnp.where(valid, jax.lax.ragged_dot(
+            act, w_down, sizes, precision=precision), 0)
+        out = out.astype(jnp.float32) * jnp.take(pair_weights, sel)[:, None]
+        return jax.ops.segment_sum(out, tok, num_segments=n) \
+            .astype(tokens.dtype)
+
+    # Under ``jax.checkpoint``, the ``cond`` inside it: the backward
+    # rebuilds a slice from the layer's inputs.  (The other way round the
+    # ``cond``'s operands -- the tokens, both weight stacks -- would be
+    # kept once a slice by the scan.)
+    @jax.checkpoint
+    def slice_or_nothing(lo):
+        return jax.lax.cond(lo < routed, rows_from,
+                            lambda lo: jnp.zeros_like(tokens), lo)
+
+    def further_slices():
+        return jax.lax.scan(
+            lambda total, lo: (total + slice_or_nothing(lo), None),
+            jnp.zeros_like(tokens),
+            step * jnp.arange(1, slices, dtype=jnp.int32))[0]
+
+    # The first slice always runs.  The others stand behind one more
+    # ``cond``: a skipped slice still costs its backward a zero gradient
+    # for both weight stacks, written and added; with the scan as a whole
+    # skipped that is paid once a layer and not once a slice.
+    combined = jax.checkpoint(rows_from)(jnp.int32(0))
+    if slices > 1:
+        combined = combined + jax.checkpoint(lambda: jax.lax.cond(
+            routed > step, further_slices,
+            lambda: jnp.zeros_like(tokens)))()
+    spanned = step * jnp.maximum(1, -(-routed // step))
+    counters = jnp.stack([
+        routed.astype(jnp.float32), spanned.astype(jnp.float32),
+        jnp.max(group_sizes).astype(jnp.float32),
+        routed.astype(jnp.float32) / count])
+    return combined, jax.lax.stop_gradient(counters)
+
+
+class DroplessMoELayer(Layer):
+    """Top-k mixture of SwiGLU experts beside one shared expert, for a chip
+    that holds ``experts_held = (first, count)`` of the ``num_experts``
+    the router chooses among (expert parallelism's layer on one chip: the
+    router keeps its full width, the chip computes its own experts' part
+    for the tokens routed to them, without the exchange).
+
+    ``y = sum_{e in top-k(x), e held} p_e(x) expert_e(x)
+    + sigmoid(w_s . x) shared(x)``, with ``p = softmax(x W_r)`` over all
+    experts in float32, the top-k renormalised to sum 1 where
+    ``norm_topk_prob``.
+
+    *The router's gradient.*  It comes from the k returns of every token.
+    A chip that holds all the experts has them and trains its router.  A
+    chip that holds a part, without the exchange, has only its own
+    experts' returns: that part alone says "my experts help", Adam gives
+    it the step of the whole, and the router sends this chip twice its
+    share within fifty steps and most of the tokens soon after
+    (``PERF.md``, PR 33).  So where ``count < num_experts`` the weights
+    ``p_e`` are constants to the backward pass and the router's weight
+    gets no gradient from this layer; the exchange, when it comes
+    (ROADMAP B11), brings the other returns and the gradient with them.
+
+    After each forward ``router_counters`` holds :data:`ROUTER_COUNTERS`
+    as a float32 vector (the rows the grouped products were routed, the
+    rows of the slices they ran, the busiest held expert's rows and the
+    mean): :func:`collect_router_counters` hands them to the train step,
+    which returns them with the loss."""
+
+    def __init__(self, d_model: int, d_hidden: int, num_experts: int,
+                 topk: int, experts_held, shared_hidden: int,
+                 norm_topk_prob: bool = True):
+        super().__init__()
+        from ..nn.layers.common import Linear
+        first, count = experts_held
+        if not (0 <= first and count > 0 and first + count <= num_experts):
+            raise ValueError(f"experts_held={experts_held} is no range of "
+                             f"the {num_experts} experts")
+        self.num_experts, self.topk = num_experts, topk
+        self.experts_held = (int(first), int(count))
+        self.norm_topk_prob = norm_topk_prob
+        init = ParamAttr(initializer=I.Normal(0.0, 0.02))
+        self.router = Linear(d_model, num_experts, weight_attr=init,
+                             bias_attr=False)
+        self.experts_gate_up = self.create_parameter(
+            [count, d_model, 2 * d_hidden], attr=init)
+        self.experts_down = self.create_parameter(
+            [count, d_hidden, d_model], attr=init)
+        for p in (self.experts_gate_up, self.experts_down):
+            p.pspec = ("ep", None, None)
+            p.is_distributed = True
+        self.shared_hidden = shared_hidden
+        self.shared_gate_up = Linear(d_model, 2 * shared_hidden,
+                                     weight_attr=init, bias_attr=False)
+        self.shared_down = Linear(shared_hidden, d_model,
+                                  weight_attr=init, bias_attr=False)
+        self.shared_gate = Linear(d_model, 1, weight_attr=init,
+                                  bias_attr=False)
+        self.router_counters = None
+
+    def forward(self, x):
+        xt = x if isinstance(x, Tensor) else Tensor(x)
+        shape = tuple(xt._value.shape)
+        topk, norm = self.topk, self.norm_topk_prob
+        (first, count), shared = self.experts_held, self.shared_hidden
+        num_experts = self.num_experts
+
+        def moe_fn(x_in, router_w, w_gate_up, w_down, gate_up, down, gate):
+            tokens = x_in.reshape(-1, shape[-1])
+            with jax.named_scope("router"):
+                logits = jnp.matmul(tokens.astype(jnp.float32),
+                                    router_w.astype(jnp.float32))
+                _, gate_vals, idx = softmax_topk(logits, topk, norm)
+                if count < num_experts:
+                    gate_vals = jax.lax.stop_gradient(gate_vals)
+            with jax.named_scope("experts"):
+                out, counters = held_expert_outputs(
+                    tokens, gate_vals, idx, w_gate_up, w_down, first,
+                    num_experts)
+            with jax.named_scope("shared_expert"):
+                h = tokens @ gate_up
+                y = (jax.nn.silu(h[:, :shared]) * h[:, shared:]) @ down
+                # one output column: float32 like the router costs
+                # nothing and keeps 2,048-long sums out of bfloat16
+                opened = jax.nn.sigmoid(jnp.matmul(
+                    tokens.astype(jnp.float32), gate.astype(jnp.float32)))
+                out = out + (y.astype(jnp.float32)
+                             * opened).astype(out.dtype)
+            return out.reshape(shape), counters
+
+        y, counters = apply_op("dropless_moe_layer", moe_fn, [
+            xt, self.router.weight, self.experts_gate_up, self.experts_down,
+            self.shared_gate_up.weight, self.shared_down.weight,
+            self.shared_gate.weight], n_outputs=2)
+        self.router_counters = counters
+        return y
+
+
+def collect_router_counters(model) -> dict:
+    """``{layer path: float32 vector of ROUTER_COUNTERS}`` over every layer
+    whose forward, just traced, left ``router_counters`` (the
+    ``collect_router_stats`` pattern); ``{}`` where none did.  Raw jax
+    values: the train step returns them as program outputs beside the
+    loss, and the program observatory keeps the newest
+    (``ProgramRegistry.note_counters``)."""
+    out = {}
+    for name, layer in model.named_sublayers(include_self=True):
+        c = getattr(layer, "router_counters", None)
+        if c is not None:
+            out[name] = c._value if isinstance(c, Tensor) else c
+    return out
 
 
 def moe_all_to_all(x, mesh, axis: str = "ep", split_axis: int = 0,
