@@ -22,11 +22,18 @@ run to tens of nats across a chunk and its inverse overflows.
 
 Backward: autodiff, with the chunk body under ``jax.checkpoint``: the scan
 keeps its carry -- one (dk, dv) float32 state a chunk and head, not one a
-token -- and recomputes a chunk's four matmuls in the backward sweep.  What
-the scan reads (the solve, the decays) is under ``jax.checkpoint`` too and
-rebuilt from ``q, k, v, g, beta``.  The triangular inverse has its own
-rule (``d inv(M) = -inv(M) dM inv(M)``) so that the doubling steps that
-build it keep nothing.
+token -- and recomputes a chunk's four matmuls in the backward sweep.
+Kept beside the five inputs: the float32 triangular inverse, one
+(CHUNK, CHUNK) a chunk and head = 4 * CHUNK bytes a token and head (67 MB a
+layer at 8,192 tokens x 32 heads, a quarter of the scan's states there).
+It is built once a step, outside any checkpoint, and is the residual of its
+own rule (``d inv(M) = -inv(M) dM inv(M)``, so the doubling steps that
+build it keep nothing).  Rebuilt in the backward, under ``jax.checkpoint``,
+from ``q, k, v, g, beta`` and that inverse: the system matrix in front of
+it (decays, ``k_beta k^T``) and everything the scan reads behind it (``qd,
+kd, w, u, attn``: bfloat16 matmuls and elementwise passes).  Rebuilding the
+inverse instead would repeat its ten batched float32 products, the dearest
+of the rule, for the sake of those cheap arrays.
 """
 
 from __future__ import annotations
@@ -67,7 +74,6 @@ def _unit_lower_inverse(a):
     return inv
 
 
-
 def _unit_lower_inverse_fwd(a):
     inv = _unit_lower_inverse(a)
     return inv, inv
@@ -104,25 +110,46 @@ def _chunk_body(state, xs):
     return state, out.astype(dt)
 
 
-@jax.checkpoint
-def _chunk_inputs(q, k, v, g, beta):
-    """What the scan reads, from the chunked inputs (n, b, h, c, ...):
-    the triangular solve and the decays of every chunk at once.  Under
-    ``jax.checkpoint``: the backward rebuilds these (a dozen arrays the
-    size of ``q``, several of them float32) from the five inputs instead
-    of keeping them for every layer."""
-    f32, dt, chunk = jnp.float32, v.dtype, g.shape[-1]
-    gc = jnp.cumsum(g, axis=-1)                          # (n, b, h, c)
+def _decays(g):
+    """The log decay ``g`` (n, b, h, c) cumulated from each chunk's start,
+    and the decay from position j to position i >= j of the same chunk
+    (0 above the diagonal): exp of a difference <= 0."""
+    chunk = g.shape[-1]
+    gc = jnp.cumsum(g, axis=-1)
     rows = jnp.arange(chunk)[:, None]
     cols = jnp.arange(chunk)[None, :]
     diff = gc[..., :, None] - gc[..., None, :]
-    # decay from position j to position i >= j: exp of a difference <= 0
-    decay = jnp.exp(jnp.where(rows >= cols, diff, -jnp.inf))
-    k_beta = (k.astype(f32) * beta[..., None]).astype(dt)
+    return gc, jnp.exp(jnp.where(rows >= cols, diff, -jnp.inf))
+
+
+@jax.checkpoint
+def _chunk_system(k, g, beta):
+    """The strictly lower-triangular ``a`` of every chunk's system
+    ``(I + a) U = diag(beta) V``, float32 (n, b, h, c, c), from the
+    chunked inputs (n, b, h, c, ...).  Under ``jax.checkpoint``: nothing
+    of that shape is kept from here, the backward rebuilds the decays
+    and ``k_beta k^T`` from ``k, g, beta``."""
+    f32 = jnp.float32
+    _, decay = _decays(g)
+    k_beta = (k.astype(f32) * beta[..., None]).astype(k.dtype)
     a = jnp.einsum("nbhik,nbhjk->nbhij", k_beta, k,
                    preferred_element_type=f32)
-    a = jnp.where(rows > cols, a * decay, 0.0)
-    t = _unit_lower_inverse(a).astype(dt)
+    return jnp.tril(a * decay, -1)
+
+
+@jax.checkpoint
+def _chunk_inputs(q, k, v, g, beta, inv):
+    """What the scan reads, from the chunked inputs (n, b, h, c, ...) and
+    the float32 inverse ``inv`` of every chunk's system: the triangular
+    solve and the decays of every chunk at once.  Under
+    ``jax.checkpoint``: the backward rebuilds these (a dozen arrays the
+    size of ``q``, several of them float32) from the six arguments
+    instead of keeping them for every layer.  ``inv`` is an argument and
+    not built here, so that rebuilding them does not rebuild it."""
+    f32, dt = jnp.float32, v.dtype
+    gc, decay = _decays(g)
+    k_beta = (k.astype(f32) * beta[..., None]).astype(dt)
+    t = inv.astype(dt)
     v_beta = (v.astype(f32) * beta[..., None]).astype(dt)
     u = jnp.einsum("nbhij,nbhjv->nbhiv", t, v_beta,
                    preferred_element_type=f32).astype(dt)
@@ -157,8 +184,11 @@ def gated_delta_rule_chunked(q, k, v, g, beta):
         x = x.reshape((b, n, chunk) + x.shape[2:])
         return jnp.moveaxis(x, (1, 3), (0, 2))
 
-    xs = _chunk_inputs(chunks(q), chunks(k), chunks(v),
-                       chunks(g.astype(f32)), chunks(beta.astype(f32)))
+    q, k, v = chunks(q), chunks(k), chunks(v)
+    g, beta = chunks(g.astype(f32)), chunks(beta.astype(f32))
+    # outside any checkpoint: the inverse's residual (itself) is stored
+    inv = _unit_lower_inverse(_chunk_system(k, g, beta))
+    xs = _chunk_inputs(q, k, v, g, beta, inv)
     state = jnp.zeros((b, h, dk, v.shape[-1]), f32)
     _, out = jax.lax.scan(jax.checkpoint(_chunk_body), state, xs)
     out = jnp.moveaxis(out, (0, 2), (1, 3))              # (b, n, c, h, dv)

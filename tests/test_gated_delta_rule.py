@@ -4,7 +4,9 @@ benchmark's plain reference, forward and gradients, at sequence lengths on
 both sides of a chunk's edge and with decays near 0 and near -0.7 a token
 (44 nats across a chunk: ``exp(-cumsum)`` would overflow)."""
 
+import importlib
 import os
+import re
 import sys
 
 import jax
@@ -19,6 +21,10 @@ if ROOT not in sys.path:
 from benchmark.reference import qwen3_next_f32 as ref  # noqa: E402
 from paddle_hackathon_tpu.incubate.nn.functional import (  # noqa: E402
     causal_depthwise_conv, gated_delta_rule, gated_delta_rule_chunked)
+
+# the package's name is bound to the taped op; the module's parts by path
+rule = importlib.import_module(
+    "paddle_hackathon_tpu.incubate.nn.functional.gated_delta_rule")
 
 
 def _inputs(s, g_mean, seed=0, b=2, h=3, dk=16, dv=8, dtype=jnp.float32):
@@ -64,19 +70,105 @@ def test_bfloat16_operands_stay_close_and_finite_under_strong_decay():
     assert float(err.max()) < 0.05 * float(jnp.abs(want).max())
 
 
+def _largest_array(fn, args):
+    """Elements of the largest array any equation of ``fn``'s jaxpr makes."""
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    return max(int(np.prod(v.aval.shape)) for eqn in jaxpr.eqns
+               for v in eqn.outvars if hasattr(v.aval, "shape"))
+
+
 def test_the_scan_keeps_one_state_a_chunk():
     """The backward's residuals grow with the chunks, not the tokens: no
     array of the jaxpr holds a (dk, dv) state for every token."""
     args = _inputs(256, -0.1)
     s, dk, dv = 256, 16, 8
-    jaxpr = jax.make_jaxpr(jax.grad(
-        lambda *a: jnp.sum(gated_delta_rule_chunked(*a))))(*args)
-    per_token = s * dk * dv
-    sizes = [int(np.prod(v.aval.shape)) for eqn in jaxpr.eqns
-             for v in eqn.outvars if hasattr(v.aval, "shape")]
+    grad = jax.grad(lambda *a: jnp.sum(gated_delta_rule_chunked(*a)))
     # the largest things are (chunks, b, h, ...) stacks; a state a token
     # would be b * h * s * dk * dv
-    assert max(sizes) < 2 * 3 * per_token
+    assert _largest_array(grad, args) < 2 * 3 * s * dk * dv
+
+
+def _inverse_products(fn, args):
+    """``dot_general``s of the lowered ``fn`` whose two operands are both
+    float32 (.., CHUNK, CHUNK): the doubling steps of the triangular
+    inverse and the two products of its rule, nothing else in the file."""
+    square = f"{rule.CHUNK}x{rule.CHUNK}xf32"
+    count = 0
+    for line in jax.jit(fn).lower(*args).as_text().splitlines():
+        if "dot_general" not in line:
+            continue
+        operands = re.findall(r"tensor<([^>]*)>",
+                              line.rsplit(" : ", 1)[-1].split("->")[0])
+        count += len(operands) == 2 and all(
+            o.endswith(square) for o in operands)
+    return count
+
+
+def test_the_backward_reads_the_inverse_and_does_not_rebuild_it():
+    """The inverse stands outside every checkpoint: the gradient program
+    holds its ten doubling products once (the forward's) and the two of
+    its own rule; ten more would mean it is rebuilt in the backward."""
+    args = _inputs(2 * rule.CHUNK, -0.1, dtype=jnp.bfloat16)
+    doubling = 2 * (rule.CHUNK.bit_length() - 2)         # 5 squarings, 5 sums
+    assert _inverse_products(gated_delta_rule_chunked, args) == doubling
+
+    def loss(*a):
+        return jnp.sum(gated_delta_rule_chunked(*a).astype(jnp.float32))
+    grad = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    assert _inverse_products(grad, args) == doubling + 2
+
+
+def _rule_with_the_inverse_rebuilt(q, k, v, g, beta):
+    """``gated_delta_rule_chunked`` from the module's own parts, with the
+    system, its inverse and what the scan reads inside one checkpoint:
+    the backward keeps the five inputs and rebuilds the inverse."""
+    b, s, h, dk = q.shape
+    c = rule.CHUNK
+    n = s // c
+
+    def chunks(x):
+        x = x.reshape((b, n, c) + x.shape[2:])
+        return jnp.moveaxis(x, (1, 3), (0, 2))
+
+    @jax.checkpoint
+    def inputs(q, k, v, g, beta):
+        inv = rule._unit_lower_inverse(rule._chunk_system(k, g, beta))
+        return rule._chunk_inputs(q, k, v, g, beta, inv)
+
+    xs = inputs(chunks(q), chunks(k), chunks(v), chunks(g), chunks(beta))
+    state = jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32)
+    _, out = jax.lax.scan(jax.checkpoint(rule._chunk_body), state, xs)
+    return jnp.moveaxis(out, (0, 2), (1, 3)).reshape(b, s, h, -1)
+
+
+def test_the_kept_inverse_gives_the_gradient_of_the_rebuilt_one():
+    """What is kept is the float32 inverse, one (CHUNK, CHUNK) a chunk and
+    head: the gradient equals, within a unit in the last place, that of
+    the same parts with the inverse rebuilt in the backward, and still no
+    array of its jaxpr holds a (dk, dv) state for every token."""
+    s, dk, dv = 256, 16, 8
+    args = _inputs(s, -0.1, dtype=jnp.bfloat16)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(jnp.sin(fn(*a).astype(jnp.float32)))
+    with jax.default_matmul_precision("highest"):
+        assert bool((gated_delta_rule_chunked(*args)
+                     == _rule_with_the_inverse_rebuilt(*args)).all())
+        grad = jax.grad(loss(gated_delta_rule_chunked),
+                        argnums=(0, 1, 2, 3, 4))
+        got = grad(*args)
+        want = jax.grad(loss(_rule_with_the_inverse_rebuilt),
+                        argnums=(0, 1, 2, 3, 4))(*args)
+        largest = _largest_array(grad, args)
+    for name, a, w in zip("q k v g beta".split(), got, want):
+        assert a.dtype == w.dtype, name
+        a, w = np.asarray(a), np.asarray(w)
+        # k, g, beta take cotangents from two functions where the rebuilt
+        # form sums them inside one: another order, a rounding apart
+        ulp = np.spacing(np.abs(w).max())                # of w's own dtype
+        gap = np.abs(a.astype(np.float32) - w.astype(np.float32)).max()
+        assert gap <= float(ulp), name
+    assert largest < 2 * 3 * s * dk * dv
 
 
 def test_tensor_op_is_taped():
