@@ -24,6 +24,8 @@ from ..kernels import flash_attention_packed as _fap
 from ..kernels import mesh as _mesh
 from .gated_delta_rule import (causal_depthwise_conv,  # noqa: F401
                                gated_delta_rule, gated_delta_rule_chunked)
+from .kimi_delta_rule import (kimi_delta_rule,  # noqa: F401
+                              kimi_delta_rule_chunked)
 
 
 def _t(x):

@@ -34,17 +34,26 @@ it (decays, ``k_beta k^T``) and everything the scan reads behind it (``qd,
 kd, w, u, attn``: bfloat16 matmuls and elementwise passes).  Rebuilding the
 inverse instead would repeat its ten batched float32 products, the dearest
 of the rule, for the sake of those cheap arrays.
+
+``chunked_rule`` -- the cut into chunks, the inverse and the scan -- also
+serves the rule with a decay a key channel (``kimi_delta_rule.py``), which
+brings its own two within-chunk functions; ``_chunk_body`` takes its
+``decay_end`` a head or a head and key channel.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ....core.autograd import apply_op
 from ....core.tensor import Tensor
 
 CHUNK = 64
+# the name the kept inverse carries (``jax.ad_checkpoint.checkpoint_name``):
+# a caller that rebuilds a whole mixer in its backward keeps it by this name
+KEPT_INVERSE = "delta_rule_inverse"
 
 
 def causal_depthwise_conv(x, taps):
@@ -105,7 +114,9 @@ def _chunk_body(state, xs):
                      preferred_element_type=f32) \
         + jnp.einsum("bhcj,bhjv->bhcv", attn, v_new_dt,
                      preferred_element_type=f32)
-    state = state * decay_end[..., None, None] + jnp.einsum(
+    # one decay a head (b, h), or one a head and key channel (b, h, dk)
+    over = (None,) * (state.ndim - decay_end.ndim)
+    state = state * decay_end[(...,) + over] + jnp.einsum(
         "bhck,bhcv->bhkv", kd, v_new_dt, preferred_element_type=f32)
     return state, out.astype(dt)
 
@@ -165,13 +176,13 @@ def _chunk_inputs(q, k, v, g, beta, inv):
     return qd, kd, w, u, attn, jnp.exp(g_end[..., 0])
 
 
-def gated_delta_rule_chunked(q, k, v, g, beta):
-    """The rule over a whole sequence.  ``q``, ``k`` (b, s, h, dk), already
-    normalised and scaled as the model wants them; ``v`` (b, s, h, dv);
-    ``g`` (log decay, <= 0) and ``beta`` (b, s, h), taken to float32.
-    Returns ``o`` (b, s, h, dv) in ``v``'s dtype.  Any ``s``: the tail of
-    the last chunk is padded with tokens that neither decay nor write
-    (g = 0, beta = 0)."""
+def chunked_rule(q, k, v, g, beta, system, inputs):
+    """A delta rule over a whole sequence, ``CHUNK`` tokens at a time:
+    ``system(k, g, beta)`` gives every chunk's strictly lower-triangular
+    matrix, ``inputs(q, k, v, g, beta, inv)`` what :func:`_chunk_body`
+    reads, both from the chunked arrays (n, b, h, c, ...).  Any ``s``: the
+    tail of the last chunk is padded with tokens that neither decay nor
+    write (g = 0, beta = 0)."""
     b, s, h, dk = q.shape
     f32, chunk = jnp.float32, CHUNK
     pad = -s % chunk
@@ -187,12 +198,21 @@ def gated_delta_rule_chunked(q, k, v, g, beta):
     q, k, v = chunks(q), chunks(k), chunks(v)
     g, beta = chunks(g.astype(f32)), chunks(beta.astype(f32))
     # outside any checkpoint: the inverse's residual (itself) is stored
-    inv = _unit_lower_inverse(_chunk_system(k, g, beta))
-    xs = _chunk_inputs(q, k, v, g, beta, inv)
+    inv = checkpoint_name(_unit_lower_inverse(system(k, g, beta)),
+                          KEPT_INVERSE)
+    xs = inputs(q, k, v, g, beta, inv)
     state = jnp.zeros((b, h, dk, v.shape[-1]), f32)
     _, out = jax.lax.scan(jax.checkpoint(_chunk_body), state, xs)
     out = jnp.moveaxis(out, (0, 2), (1, 3))              # (b, n, c, h, dv)
     return out.reshape(b, n * chunk, h, -1)[:, :s]
+
+
+def gated_delta_rule_chunked(q, k, v, g, beta):
+    """The rule over a whole sequence.  ``q``, ``k`` (b, s, h, dk), already
+    normalised and scaled as the model wants them; ``v`` (b, s, h, dv);
+    ``g`` (log decay, <= 0) and ``beta`` (b, s, h), taken to float32.
+    Returns ``o`` (b, s, h, dv) in ``v``'s dtype."""
+    return chunked_rule(q, k, v, g, beta, _chunk_system, _chunk_inputs)
 
 
 def gated_delta_rule(q, k, v, g, beta):

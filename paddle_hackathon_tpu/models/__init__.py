@@ -9,3 +9,6 @@ from .bert import (BertConfig, BertForPretraining,  # noqa: F401
                    ernie_config, masked_mlm_loss)
 from .qwen3_next import (Qwen3NextConfig, Qwen3NextForCausalLM,  # noqa: F401
                          qwen3_next_sharding_spec)
+from .bailing_hybrid import (BailingHybridConfig,  # noqa: F401
+                             BailingHybridForCausalLM,
+                             bailing_hybrid_sharding_spec)

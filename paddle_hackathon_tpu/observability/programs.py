@@ -323,16 +323,16 @@ def mosaic_kernels(hlo_text: str) -> Dict[str, int]:
 
 
 # The train step's scopes (``parallel/api.py``, ``models/gpt.py``,
-# ``models/qwen3_next.py``): the names a component can take in the phase
-# census.  A scope of PHASE_SUBCOMPONENTS names a part of the component it
+# ``models/qwen3_next.py``, ``models/bailing_hybrid.py``): the names a
+# component can take in the phase census.  A scope of PHASE_SUBCOMPONENTS names a part of the component it
 # is nested in (``gdn_rule`` inside ``gdn``, ``experts`` inside ``moe``):
 # an instruction under both reads ``"gdn/gdn_rule"``, so a part's time can
 # be told from its parent's, and a parent's is the sum over
 # ``component.split("/")[0]``.
 PHASE_COMPONENTS = ("embed", "attn", "mlp", "ln_f", "lm_head", "ce",
-                    "clip", "update", "gdn", "moe")
+                    "clip", "update", "gdn", "moe", "kda", "mla")
 PHASE_SUBCOMPONENTS = ("gdn_conv", "gdn_rule", "router", "experts",
-                       "shared_expert")
+                       "shared_expert", "kda_conv", "kda_rule")
 PHASES = ("fwd", "bwd", "clip", "update", "other")
 
 _COMPUTATION_RE = re.compile(r'^(?:ENTRY )?%?([^\s(]+) \(.*\{$')

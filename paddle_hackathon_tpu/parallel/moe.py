@@ -71,12 +71,46 @@ def softmax_topk(logits, topk: int, renormalize: bool):
     that wants a float32 router hands float32 logits), the ``topk``
     largest and their indices, renormalised to sum 1 where asked.
     Returns ``(probs, gate_vals (n, k), idx (n, k))``."""
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, idx = jax.lax.top_k(probs, topk)
+    return router_topk(logits, topk, renormalize)
+
+
+def router_topk(logits, topk: int, renormalize: bool,
+                score_function: str = "softmax", bias=None, n_group: int = 1,
+                topk_group: int = 1, scaling: float = 1.0):
+    """A top-k router's choice from ``logits`` (n, experts), in their
+    dtype: ``scores`` = softmax over the experts or an elementwise
+    sigmoid (``score_function``); the selection by ``scores + bias``
+    where a ``bias`` (experts,) is given (DeepSeek-V3's ``noaux_tc``: it
+    steers the load and never weighs an output) and, where ``n_group`` >
+    1, among the experts of the ``topk_group`` best of ``n_group`` equal
+    groups only, a group scored by the sum of its two largest; the chosen
+    experts' *unbiased* scores, renormalised to sum 1 where asked, times
+    ``scaling``.  Returns ``(scores, gate_vals (n, k), idx (n, k))``."""
+    if score_function not in ("softmax", "sigmoid"):
+        raise ValueError(f"score_function {score_function!r}: "
+                         "'softmax' or 'sigmoid'")
+    scores = jax.nn.softmax(logits, axis=-1) \
+        if score_function == "softmax" else jax.nn.sigmoid(logits)
+    choice = scores if bias is None else scores + bias
+    if n_group > 1:
+        n, experts = choice.shape
+        grouped = choice.reshape(n, n_group, experts // n_group)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], -1)
+        _, kept = jax.lax.top_k(group_score, topk_group)
+        kept = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+        choice = jnp.where(kept[:, :, None], grouped,
+                           -jnp.inf).reshape(n, experts)
+    if choice is scores:
+        gate_vals, idx = jax.lax.top_k(scores, topk)
+    else:
+        _, idx = jax.lax.top_k(choice, topk)
+        gate_vals = jnp.take_along_axis(scores, idx, axis=-1)
     if renormalize:
         gate_vals = gate_vals / jnp.maximum(
             gate_vals.sum(-1, keepdims=True), 1e-9)
-    return probs, gate_vals, idx
+    if scaling != 1.0:
+        gate_vals = gate_vals * scaling
+    return scores, gate_vals, idx
 
 
 def _balance_loss(probs, idx, num_experts):
@@ -463,7 +497,14 @@ class DroplessMoELayer(Layer):
     ``y = sum_{e in top-k(x), e held} p_e(x) expert_e(x)
     + sigmoid(w_s . x) shared(x)``, with ``p = softmax(x W_r)`` over all
     experts in float32, the top-k renormalised to sum 1 where
-    ``norm_topk_prob``.
+    ``norm_topk_prob``.  The router's other forms are
+    :func:`router_topk`'s, each a constructor argument that a model's
+    config fills: ``score_function`` "sigmoid", a selection among the
+    ``topk_group`` best of ``n_group`` groups, a ``selection_bias``
+    (``router_bias``, one an expert, added for the selection only: no
+    gradient ever reaches it; its deployment moves it by the experts'
+    load), ``routed_scaling_factor``; ``shared_gated=False`` adds the
+    shared expert without its gate.
 
     *The router's gradient.*  It comes from the k returns of every token.
     A chip that holds all the experts has them and trains its router.  A
@@ -484,16 +525,28 @@ class DroplessMoELayer(Layer):
 
     def __init__(self, d_model: int, d_hidden: int, num_experts: int,
                  topk: int, experts_held, shared_hidden: int,
-                 norm_topk_prob: bool = True):
+                 norm_topk_prob: bool = True,
+                 score_function: str = "softmax", n_group: int = 1,
+                 topk_group: int = 1, routed_scaling_factor: float = 1.0,
+                 selection_bias: bool = False, shared_gated: bool = True):
         super().__init__()
         from ..nn.layers.common import Linear
         first, count = experts_held
         if not (0 <= first and count > 0 and first + count <= num_experts):
             raise ValueError(f"experts_held={experts_held} is no range of "
                              f"the {num_experts} experts")
+        if num_experts % n_group or not 0 < topk_group <= n_group \
+                or topk > topk_group * (num_experts // n_group):
+            raise ValueError(
+                f"{topk} of {num_experts} experts cannot be chosen among "
+                f"{topk_group} of {n_group} equal groups")
         self.num_experts, self.topk = num_experts, topk
         self.experts_held = (int(first), int(count))
         self.norm_topk_prob = norm_topk_prob
+        self.router_form = dict(
+            score_function=score_function, n_group=n_group,
+            topk_group=topk_group, scaling=float(routed_scaling_factor))
+        self.shared_gated, self.selection_bias = shared_gated, selection_bias
         init = ParamAttr(initializer=I.Normal(0.0, 0.02))
         self.router = Linear(d_model, num_experts, weight_attr=init,
                              bias_attr=False)
@@ -509,8 +562,12 @@ class DroplessMoELayer(Layer):
                                      weight_attr=init, bias_attr=False)
         self.shared_down = Linear(shared_hidden, d_model,
                                   weight_attr=init, bias_attr=False)
-        self.shared_gate = Linear(d_model, 1, weight_attr=init,
-                                  bias_attr=False)
+        if shared_gated:
+            self.shared_gate = Linear(d_model, 1, weight_attr=init,
+                                      bias_attr=False)
+        if selection_bias:
+            self.router_bias = self.create_parameter(
+                [num_experts], default_initializer=I.Constant(0.0))
         self.router_counters = None
 
     def forward(self, x):
@@ -519,13 +576,23 @@ class DroplessMoELayer(Layer):
         topk, norm = self.topk, self.norm_topk_prob
         (first, count), shared = self.experts_held, self.shared_hidden
         num_experts = self.num_experts
+        form = self.router_form
+        gated, biased = self.shared_gated, self.selection_bias
+        # the leaves a router's form may add, in the order moe_fn reads them
+        optional = ([self.shared_gate.weight] if gated else []) \
+            + ([self.router_bias] if biased else [])
 
-        def moe_fn(x_in, router_w, w_gate_up, w_down, gate_up, down, gate):
+        def moe_fn(x_in, router_w, w_gate_up, w_down, gate_up, down, *rest):
+            gate = rest[0] if gated else None
+            bias = rest[-1] if biased else None
             tokens = x_in.reshape(-1, shape[-1])
             with jax.named_scope("router"):
                 logits = jnp.matmul(tokens.astype(jnp.float32),
                                     router_w.astype(jnp.float32))
-                _, gate_vals, idx = softmax_topk(logits, topk, norm)
+                if bias is not None:
+                    bias = jax.lax.stop_gradient(bias.astype(jnp.float32))
+                _, gate_vals, idx = router_topk(logits, topk, norm,
+                                                bias=bias, **form)
                 if count < num_experts:
                     gate_vals = jax.lax.stop_gradient(gate_vals)
             with jax.named_scope("experts"):
@@ -535,18 +602,21 @@ class DroplessMoELayer(Layer):
             with jax.named_scope("shared_expert"):
                 h = tokens @ gate_up
                 y = (jax.nn.silu(h[:, :shared]) * h[:, shared:]) @ down
-                # one output column: float32 like the router costs
-                # nothing and keeps 2,048-long sums out of bfloat16
-                opened = jax.nn.sigmoid(jnp.matmul(
-                    tokens.astype(jnp.float32), gate.astype(jnp.float32)))
-                out = out + (y.astype(jnp.float32)
-                             * opened).astype(out.dtype)
+                if gate is None:
+                    out = out + y
+                else:
+                    # one output column: float32 like the router costs
+                    # nothing and keeps 2,048-long sums out of bfloat16
+                    opened = jax.nn.sigmoid(jnp.matmul(
+                        tokens.astype(jnp.float32), gate.astype(jnp.float32)))
+                    out = out + (y.astype(jnp.float32)
+                                 * opened).astype(out.dtype)
             return out.reshape(shape), counters
 
         y, counters = apply_op("dropless_moe_layer", moe_fn, [
             xt, self.router.weight, self.experts_gate_up, self.experts_down,
-            self.shared_gate_up.weight, self.shared_down.weight,
-            self.shared_gate.weight], n_outputs=2)
+            self.shared_gate_up.weight, self.shared_down.weight, *optional],
+            n_outputs=2)
         self.router_counters = counters
         return y
 
