@@ -26,14 +26,23 @@ token -- and recomputes a chunk's four matmuls in the backward sweep.
 Kept beside the five inputs: the float32 triangular inverse, one
 (CHUNK, CHUNK) a chunk and head = 4 * CHUNK bytes a token and head (67 MB a
 layer at 8,192 tokens x 32 heads, a quarter of the scan's states there).
-It is built once a step, outside any checkpoint, and is the residual of its
-own rule (``d inv(M) = -inv(M) dM inv(M)``, so the doubling steps that
-build it keep nothing).  Rebuilt in the backward, under ``jax.checkpoint``,
-from ``q, k, v, g, beta`` and that inverse: the system matrix in front of
-it (decays, ``k_beta k^T``) and everything the scan reads behind it (``qd,
-kd, w, u, attn``: bfloat16 matmuls and elementwise passes).  Rebuilding the
-inverse instead would repeat its ten batched float32 products, the dearest
-of the rule, for the sake of those cheap arrays.
+It is built once a step, outside this module's checkpoints, and is the
+residual of its own rule (``d inv(M) = -inv(M) dM inv(M)``, so the doubling
+steps that build it keep nothing).  Rebuilt in the backward, under
+``jax.checkpoint``, from ``q, k, v, g, beta`` and that inverse: the system
+matrix in front of it (decays, ``k_beta k^T``) and everything the scan reads
+behind it (``qd, kd, w, u, attn``: bfloat16 matmuls and elementwise passes).
+Rebuilding the inverse instead would repeat its ten batched float32
+products, the dearest of the rule, for the sake of those cheap arrays.
+
+A caller that puts a whole mixer under a ``jax.checkpoint`` of its own keeps
+the inverse by adding ``KEPT_INVERSE`` to its policy's names.  The name sits
+on the value the inverse's forward rule returns, which is its output and
+its residual at once: a name on the output alone (in ``chunked_rule``)
+kept the copy that ``_chunk_inputs`` reads and left the residual unnamed,
+so such a caller's backward built the system and the inverse a second time
+for the inverse's own rule.  With no checkpoint around the caller the name
+is an identity and the residual is kept like any other.
 
 ``chunked_rule`` -- the cut into chunks, the inverse and the scan -- also
 serves the rule with a decay a key channel (``kimi_delta_rule.py``), which
@@ -51,8 +60,9 @@ from ....core.autograd import apply_op
 from ....core.tensor import Tensor
 
 CHUNK = 64
-# the name the kept inverse carries (``jax.ad_checkpoint.checkpoint_name``):
-# a caller that rebuilds a whole mixer in its backward keeps it by this name
+# the name the kept inverse carries (``jax.ad_checkpoint.checkpoint_name``,
+# given in ``_unit_lower_inverse_fwd``): a caller that rebuilds a whole mixer
+# in its backward keeps it by this name
 KEPT_INVERSE = "delta_rule_inverse"
 
 
@@ -84,7 +94,10 @@ def _unit_lower_inverse(a):
 
 
 def _unit_lower_inverse_fwd(a):
-    inv = _unit_lower_inverse(a)
+    # named here, where output and residual are still one value: a policy
+    # that saves KEPT_INVERSE then saves what ``_unit_lower_inverse_bwd``
+    # reads, not only what the caller reads
+    inv = checkpoint_name(_unit_lower_inverse(a), KEPT_INVERSE)
     return inv, inv
 
 
@@ -197,9 +210,9 @@ def chunked_rule(q, k, v, g, beta, system, inputs):
 
     q, k, v = chunks(q), chunks(k), chunks(v)
     g, beta = chunks(g.astype(f32)), chunks(beta.astype(f32))
-    # outside any checkpoint: the inverse's residual (itself) is stored
-    inv = checkpoint_name(_unit_lower_inverse(system(k, g, beta)),
-                          KEPT_INVERSE)
+    # outside this module's checkpoints: the inverse's residual (itself,
+    # under the name KEPT_INVERSE) is stored
+    inv = _unit_lower_inverse(system(k, g, beta))
     xs = inputs(q, k, v, g, beta, inv)
     state = jnp.zeros((b, h, dk, v.shape[-1]), f32)
     _, out = jax.lax.scan(jax.checkpoint(_chunk_body), state, xs)

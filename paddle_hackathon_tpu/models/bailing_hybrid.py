@@ -26,8 +26,9 @@ float32, ``g = kda_lower_bound * sigmoid(exp(A_log_h) (f + dt_bias))``, so
 needs); the rule; then per head ``w * o / rms(o) * sigmoid(gate)`` and a
 bias-free output projection.  (The published weights keep the five
 projections apart; fused they are the same function.)  The layer keeps
-its input and the rule's triangular inverse for the backward and rebuilds
-the rest (``BailingKimiDeltaAttention`` says why).
+its input, the rule's triangular inverse and the two wide projections'
+outputs for the backward and rebuilds the rest
+(``BailingKimiDeltaAttention`` says why).
 
 *MLA mixer.*  ``q_proj`` to heads x ``[nope | rope]``; ``kv_a_proj`` to
 ``[c | k_rope]`` (``kv_lora_rank`` + rope), ``c`` through an RMSNorm,
@@ -58,6 +59,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..core.autograd import apply_op
 from ..nn import functional as F
@@ -109,6 +111,12 @@ class BailingHybridConfig:
         return layer_idx < self.first_k_dense_replace
 
 
+# the names of what a KDA layer keeps for its backward beside the rule's
+# inverse (``BailingKimiDeltaAttention``): the two wide projections' outputs
+KEPT_QKV = "kda_qkv_projection"
+KEPT_FG = "kda_fg_projection"
+
+
 def _init(config):
     return ParamAttr(initializer=I.Normal(0.0, config.initializer_range))
 
@@ -138,19 +146,30 @@ def _rule_inputs(qkv, f, b, conv, a_log, dt_bias, heads, head_dim, floor):
     return q.astype(dt), k.astype(dt), v, g, beta
 
 
+def _chunk_inputs_of_one_inverse(q, k, v, g, beta, inv):
+    """``kimi_delta_rule._chunk_inputs`` behind a barrier on ``inv``: the
+    inverse is ``I - a`` plus five products, and with nothing in the way
+    XLA fuses that sum into every op that reads it, so that what a layer
+    held for its backward was the five products and ``a``, six float32
+    (chunks, b, h, 64, 64) arrays, not the one it names."""
+    from ..incubate.nn.functional.kimi_delta_rule import _chunk_inputs
+    return _chunk_inputs(q, k, v, g, beta, jax.lax.optimization_barrier(inv))
+
+
 def _kimi_delta_attention(x, w_qkv, w_fg, w_b, conv, a_log, dt_bias, norm_w,
                           w_o, *, heads, head_dim, floor, eps):
     """The whole KDA mixer on the normed ``x`` (b, s, hidden), on arrays."""
-    from ..incubate.nn.functional.kimi_delta_rule import \
-        kimi_delta_rule_chunked
+    from ..incubate.nn.functional.gated_delta_rule import chunked_rule
+    from ..incubate.nn.functional.kimi_delta_rule import _chunk_system
     bsz, s, _ = x.shape
     width = heads * head_dim
-    fg = x @ w_fg
-    q, k, v, g, beta = _rule_inputs(x @ w_qkv, fg[..., :width], x @ w_b,
-                                    conv, a_log, dt_bias, heads, head_dim,
-                                    floor)
+    qkv = checkpoint_name(x @ w_qkv, KEPT_QKV)
+    fg = checkpoint_name(x @ w_fg, KEPT_FG)
+    q, k, v, g, beta = _rule_inputs(qkv, fg[..., :width], x @ w_b, conv,
+                                    a_log, dt_bias, heads, head_dim, floor)
     with jax.named_scope("kda_rule"):
-        o = kimi_delta_rule_chunked(q, k, v, g, beta)
+        o = chunked_rule(q, k, v, g, beta, _chunk_system,
+                         _chunk_inputs_of_one_inverse)
     gate = fg[..., width:].reshape(bsz, s, heads, head_dim)
     o = (_rms(o, norm_w, eps)
          * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(o.dtype)
@@ -158,13 +177,19 @@ def _kimi_delta_attention(x, w_qkv, w_fg, w_b, conv, a_log, dt_bias, norm_w,
 
 
 class BailingKimiDeltaAttention(Layer):
-    """Kept for the backward, a layer: its input and the rule's float32
-    triangular inverse (``gated_delta_rule.KEPT_INVERSE``).  Everything
-    else -- the five projections' outputs (48 KB a token), the rule's
-    inputs (40 KB, the decay a channel in float32 among them), a state a
-    chunk (32 KB) -- is rebuilt in the backward, one layer at a time: kept
-    by every layer they are 0.5 GB a layer at 4,096 tokens, and six layers
-    of them beside 884 M parameters' state do not fit a 16 GB chip."""
+    """Kept for the backward, a layer, beside its input (5 KB a token):
+    the rule's float32 triangular inverse (``gated_delta_rule.
+    KEPT_INVERSE``, 8 KB a token, 16 as the chip lays its 64-wide rows
+    out) and the outputs of the two wide projections, ``x @ w_qkv`` (24 KB)
+    and ``x @ w_fg`` (16 KB): 48 KB a token, 0.20 GB a layer and 1.2 GB
+    for six at 4,096 tokens.  They are what is dear to build again: the
+    inverse's ten batched float32 products and the system in front of it,
+    and 0.43 TFLOP of matmul a layer.  Everything else -- the rule's
+    inputs (40 KB a token, the decay a channel in float32 among them),
+    what the scan reads, a state a chunk (32 KB), ``o`` -- is rebuilt in
+    the backward, one layer at a time: elementwise passes and narrow
+    matmuls that would take another 0.5 GB a layer, and six layers of
+    them beside 884 M parameters' state do not fit a 16 GB chip."""
 
     def __init__(self, config: BailingHybridConfig):
         super().__init__()
@@ -194,7 +219,7 @@ class BailingKimiDeltaAttention(Layer):
                 _kimi_delta_attention, heads=heads, head_dim=c.head_dim,
                 floor=float(c.kda_lower_bound), eps=c.rms_norm_eps),
             policy=jax.checkpoint_policies.save_only_these_names(
-                KEPT_INVERSE))
+                KEPT_INVERSE, KEPT_QKV, KEPT_FG))
 
     def forward(self, x):
         return apply_op("kimi_delta_attention", self._core, [

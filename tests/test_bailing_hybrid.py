@@ -6,10 +6,15 @@ the packed flash kernel with ``v`` padded against the reference's blocked
 softmax with ``v`` as it is; the grouped selection against a sort; the
 shares of a cut expert layer add up to the uncut layer; the layer rule from
 the config; the dropless layer with another model's arguments unchanged;
-the census knows the new scopes."""
+the census knows the new scopes; what a KDA layer keeps for its backward and
+how often that backward builds the rule's inverse."""
 
+import contextlib
+import functools
+import io
 import json
 import os
+import re
 import sys
 
 import jax
@@ -29,6 +34,7 @@ from paddle_hackathon_tpu.models import (BailingHybridConfig,  # noqa: E402
 from paddle_hackathon_tpu.models import bailing_hybrid as prog  # noqa: E402
 from paddle_hackathon_tpu.nn.layer import functional_call  # noqa: E402
 from paddle_hackathon_tpu.parallel import moe  # noqa: E402
+from test_gated_delta_rule import _inverse_products, rule  # noqa: E402
 
 TINY = "ling3-tiny-rehearsal"
 
@@ -183,6 +189,97 @@ def test_latent_attention_through_the_flash_kernel_with_v_padded():
         assert float(err) < 0.05 * float(jnp.abs(want_g[k]).max()), k
     err = jnp.abs(got_dx.astype(jnp.float32) - want_dx).max()
     assert float(err) < 0.05 * float(jnp.abs(want_dx).max())
+
+
+def _kda_layer(b=1, s=128, hidden=32, heads=2, head_dim=16):
+    """One KDA mixer's function under the layer's own checkpoint, its nine
+    bfloat16 arguments (the normed input first) and the same function with
+    no checkpoint around it; two chunks of the rule."""
+    c = BailingHybridConfig(hidden_size=hidden, num_attention_heads=heads,
+                            head_dim=head_dim)
+    layer = prog.BailingKimiDeltaAttention(c)
+    names = ("in_proj_qkv.weight", "in_proj_fg.weight", "in_proj_b.weight",
+             "conv", "A_log", "dt_bias", "norm.weight", "o_proj.weight")
+    spec = {k: tuple(p.shape) for k, p in layer.named_parameters()}
+    assert sorted(spec) == sorted(names)
+    leaves = weights.make_params(11, spec, jnp.bfloat16)
+    x = jax.random.normal(jax.random.key(6), (b, s, hidden)) \
+        .astype(jnp.bfloat16)
+    plain = functools.partial(
+        prog._kimi_delta_attention, heads=heads, head_dim=head_dim,
+        floor=float(c.kda_lower_bound), eps=c.rms_norm_eps)
+    return layer._core, plain, (x,) + tuple(leaves[k] for k in names)
+
+
+def _summed(fn):
+    return lambda *a: jnp.sum(jnp.sin(fn(*a).astype(jnp.float32)))
+
+
+def test_the_kda_layers_backward_does_not_rebuild_the_inverse():
+    """Under the layer's checkpoint the gradient program holds the
+    forward's ten doubling products and the two of the inverse's own rule:
+    the policy keeps the value that rule reads.  Ten more (22, what the
+    layer read while the name sat on the rule's output alone) would be the
+    inverse and the system in front of it built again in the backward."""
+    core, plain, args = _kda_layer()
+    assert _inverse_products(core, args) == 10
+    every = tuple(range(len(args)))
+    assert _inverse_products(jax.grad(_summed(core), every), args) == 12
+    assert _inverse_products(jax.grad(_summed(plain), every), args) == 12
+
+
+def test_what_a_kda_layer_keeps_for_its_backward():
+    """The residuals of the layer's function: its nine arguments, the
+    float32 inverse (chunks, b, h, 64, 64) = 256 B a token and head, and
+    the two wide projections' outputs, [q | k | v] at 6 B and [f | gate]
+    at 4 B a token and channel.  At the cell's 32 heads x 128: 8 + 24 + 16
+    = 48 KB a token.  Nothing else: not ``g``'s float32 (b, s, h, dk), no
+    other input of the rule, no state a chunk."""
+    b, s, heads, head_dim = 1, 128, 2, 16
+    core, plain, args = _kda_layer(b, s, heads=heads, head_dim=head_dim)
+    width, chunk = heads * head_dim, rule.CHUNK
+
+    def kept(fn):
+        """(shape, short dtype) of every line ``print_saved_residuals``
+        prints, as ``bf16[1,128,32] from the argument x``."""
+        out = io.StringIO()
+        with contextlib.redirect_stdout(out):
+            jax.ad_checkpoint.print_saved_residuals(fn, *args)
+        lines = [re.match(r"(\w+)\[([\d,]*)\] ", line)
+                 for line in out.getvalue().splitlines()]
+        return sorted((tuple(int(n) for n in m.group(2).split(",")),
+                       m.group(1)) for m in lines)
+    arguments = [(a.shape, "bf16") for a in args]
+    inverse = ((s // chunk, b, heads, chunk, chunk), "f32")
+    projections = [((b, s, 3 * width), "bf16"), ((b, s, 2 * width), "bf16")]
+    kept_by_the_layer = kept(core)
+    assert kept_by_the_layer == sorted(arguments + [inverse] + projections)
+    a_token = sum(int(np.prod(shape)) * {"f32": 4, "bf16": 2}[dt]
+                  for shape, dt in [inverse] + projections) // (b * s)
+    assert a_token == heads * (4 * chunk + 10 * head_dim)
+    assert 32 * (4 * chunk + 10 * 128) == 48 * 1024   # the cell's heads
+    assert ((b, s, heads, head_dim), "f32") not in kept_by_the_layer
+    # the names are what keeps them: a policy without them keeps the
+    # arguments, and the backward builds the inverse again
+    bare = jax.checkpoint(
+        plain, policy=jax.checkpoint_policies.save_only_these_names())
+    assert kept(bare) == sorted(arguments)
+    assert _inverse_products(
+        jax.grad(_summed(bare), tuple(range(len(args)))), args) == 22
+
+
+def test_the_kda_layers_gradients_equal_those_without_a_checkpoint():
+    core, plain, args = _kda_layer()
+    every = tuple(range(len(args)))
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(_summed(core), every)(*args)
+        want = jax.grad(_summed(plain), every)(*args)
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert g.dtype == w.dtype == jnp.bfloat16
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        scale = float(jnp.abs(w).max())
+        assert scale > 0, i
+        assert float(jnp.abs(g - w).max()) < 2e-4 * scale, i
 
 
 GROUPED = {"groups": 8, "groups_kept": 4, "topk": 8, "renorm": True,

@@ -118,6 +118,24 @@ def test_the_backward_reads_the_inverse_and_does_not_rebuild_it():
     assert _inverse_products(grad, args) == doubling + 2
 
 
+def test_a_caller_that_rebuilds_its_mixer_keeps_the_inverse_by_name():
+    """Under a caller's ``jax.checkpoint`` whose policy saves
+    ``KEPT_INVERSE`` alone the gradient still holds 12: the name sits on
+    the value the inverse's own rule reads, so the policy keeps it.  (On
+    the rule's output alone it kept a copy for ``_chunk_inputs`` and the
+    backward built the inverse again for its rule: 22.)"""
+    args = _inputs(2 * rule.CHUNK, -0.1, dtype=jnp.bfloat16)
+    wrapped = jax.checkpoint(
+        gated_delta_rule_chunked,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            rule.KEPT_INVERSE))
+
+    def loss(*a):
+        return jnp.sum(wrapped(*a).astype(jnp.float32))
+    grad = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    assert _inverse_products(grad, args) == 12
+
+
 def _rule_with_the_inverse_rebuilt(q, k, v, g, beta):
     """``gated_delta_rule_chunked`` from the module's own parts, with the
     system, its inverse and what the scan reads inside one checkpoint:
