@@ -44,10 +44,11 @@ output projection.
 count)``, ``vocab_size`` the slice held, the mixers whole, no router
 gradient where a part of the experts is held.  No MTP module.
 
-The scopes ``embed``, ``kda`` (with ``kda_conv``, ``kda_rule`` inside),
-``mla``, ``mlp``, ``moe`` (with ``router``, ``experts``,
-``shared_expert``), ``ln_f``, ``lm_head`` name the step's parts for the
-phase census (``observability/programs.py``).
+The scopes ``embed``, ``kda`` (with ``kda_proj``, ``kda_conv``,
+``kda_gates``, ``kda_rule`` inside: siblings, which leave only the layer's
+norm and residual outside a part), ``mla``, ``mlp``, ``moe`` (with
+``router``, ``experts``, ``shared_expert``), ``ln_f``, ``lm_head`` name
+the step's parts for the phase census (``observability/programs.py``).
 """
 
 from __future__ import annotations
@@ -123,7 +124,8 @@ def _init(config):
 
 def _rule_inputs(qkv, f, b, conv, a_log, dt_bias, heads, head_dim, floor):
     """``q, k, v, g, beta`` of the rule from the projected [q | k | v],
-    ``f`` and ``b``: the convolution and SiLU, the L2 norms, the gates."""
+    ``f`` and ``b``: the convolution and SiLU (scope ``kda_conv``), the L2
+    norms and the gates (``kda_gates``)."""
     from ..incubate.nn.functional.gated_delta_rule import \
         causal_depthwise_conv
     f32, dt = jnp.float32, qkv.dtype
@@ -131,19 +133,20 @@ def _rule_inputs(qkv, f, b, conv, a_log, dt_bias, heads, head_dim, floor):
     width = heads * head_dim
     with jax.named_scope("kda_conv"):
         mixed = jax.nn.silu(causal_depthwise_conv(qkv, conv))
-    q = mixed[..., :width].reshape(bsz, s, heads, head_dim).astype(f32)
-    k = mixed[..., width:2 * width].reshape(bsz, s, heads, head_dim) \
-        .astype(f32)
-    v = mixed[..., 2 * width:].reshape(bsz, s, heads, head_dim)
-    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
-        * head_dim ** -0.5
-    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
-    beta = jax.nn.sigmoid(b.astype(f32))
-    g = floor * jax.nn.sigmoid(
-        jnp.exp(a_log.astype(f32))[:, None]
-        * (f.reshape(bsz, s, heads, head_dim).astype(f32)
-           + dt_bias.astype(f32).reshape(heads, head_dim)))
-    return q.astype(dt), k.astype(dt), v, g, beta
+    with jax.named_scope("kda_gates"):
+        q = mixed[..., :width].reshape(bsz, s, heads, head_dim).astype(f32)
+        k = mixed[..., width:2 * width].reshape(bsz, s, heads, head_dim) \
+            .astype(f32)
+        v = mixed[..., 2 * width:].reshape(bsz, s, heads, head_dim)
+        q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
+            * head_dim ** -0.5
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+        beta = jax.nn.sigmoid(b.astype(f32))
+        g = floor * jax.nn.sigmoid(
+            jnp.exp(a_log.astype(f32))[:, None]
+            * (f.reshape(bsz, s, heads, head_dim).astype(f32)
+               + dt_bias.astype(f32).reshape(heads, head_dim)))
+        return q.astype(dt), k.astype(dt), v, g, beta
 
 
 def _chunk_inputs_of_one_inverse(q, k, v, g, beta, inv):
@@ -158,22 +161,32 @@ def _chunk_inputs_of_one_inverse(q, k, v, g, beta, inv):
 
 def _kimi_delta_attention(x, w_qkv, w_fg, w_b, conv, a_log, dt_bias, norm_w,
                           w_o, *, heads, head_dim, floor, eps):
-    """The whole KDA mixer on the normed ``x`` (b, s, hidden), on arrays."""
+    """The whole KDA mixer on the normed ``x`` (b, s, hidden), on arrays.
+    Its parts are sibling scopes: ``kda_proj`` (the four projections),
+    ``kda_conv``, ``kda_gates`` (norms, gates, casts and head reshapes),
+    ``kda_rule``."""
     from ..incubate.nn.functional.gated_delta_rule import chunked_rule
     from ..incubate.nn.functional.kimi_delta_rule import _chunk_system
     bsz, s, _ = x.shape
     width = heads * head_dim
-    qkv = checkpoint_name(x @ w_qkv, KEPT_QKV)
-    fg = checkpoint_name(x @ w_fg, KEPT_FG)
-    q, k, v, g, beta = _rule_inputs(qkv, fg[..., :width], x @ w_b, conv,
-                                    a_log, dt_bias, heads, head_dim, floor)
+    with jax.named_scope("kda_proj"):
+        qkv = checkpoint_name(x @ w_qkv, KEPT_QKV)
+        fg = checkpoint_name(x @ w_fg, KEPT_FG)
+        b = x @ w_b
+    with jax.named_scope("kda_gates"):
+        f = fg[..., :width]
+    q, k, v, g, beta = _rule_inputs(qkv, f, b, conv, a_log, dt_bias, heads,
+                                    head_dim, floor)
     with jax.named_scope("kda_rule"):
         o = chunked_rule(q, k, v, g, beta, _chunk_system,
                          _chunk_inputs_of_one_inverse)
-    gate = fg[..., width:].reshape(bsz, s, heads, head_dim)
-    o = (_rms(o, norm_w, eps)
-         * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(o.dtype)
-    return o.reshape(bsz, s, width) @ w_o
+    with jax.named_scope("kda_gates"):
+        gate = fg[..., width:].reshape(bsz, s, heads, head_dim)
+        o = (_rms(o, norm_w, eps)
+             * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(o.dtype)
+        o = o.reshape(bsz, s, width)
+    with jax.named_scope("kda_proj"):
+        return o @ w_o
 
 
 class BailingKimiDeltaAttention(Layer):

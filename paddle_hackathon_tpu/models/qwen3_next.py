@@ -37,10 +37,11 @@ the vocabulary held (ids, logits and loss are over the slice).  The mixers
 are whole.  A layer that holds a part of the experts does not train its
 router (``parallel/moe.py DroplessMoELayer`` says why).  No MTP module.
 
-The scopes ``embed``, ``gdn`` (with ``gdn_conv``, ``gdn_rule`` inside),
-``attn``, ``moe`` (with ``router``, ``experts``, ``shared_expert``),
-``ln_f``, ``lm_head`` name the step's parts for the phase census
-(``observability/programs.py``).
+The scopes ``embed``, ``gdn`` (with ``gdn_proj``, ``gdn_conv``,
+``gdn_gates``, ``gdn_rule`` inside: siblings, which leave only the layer's
+norm and residual outside a part), ``attn``, ``moe`` (with ``router``,
+``experts``, ``shared_expert``), ``ln_f``, ``lm_head`` name the step's
+parts for the phase census (``observability/programs.py``).
 """
 
 from __future__ import annotations
@@ -113,43 +114,53 @@ def _rule_inputs(qkv, ba, conv, a_log, dt_bias, key_heads, value_heads,
     kq = key_heads * key_dim
     with jax.named_scope("gdn_conv"):
         mixed = jax.nn.silu(causal_depthwise_conv(qkv, conv))
-    q = mixed[..., :kq].reshape(b, s, key_heads, key_dim).astype(f32)
-    k = mixed[..., kq:2 * kq].reshape(b, s, key_heads, key_dim).astype(f32)
-    v = mixed[..., 2 * kq:].reshape(b, s, value_heads, value_dim)
-    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
-        * key_dim ** -0.5
-    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
-    rep = value_heads // key_heads
-    q = jnp.repeat(q.astype(dt), rep, axis=2)
-    k = jnp.repeat(k.astype(dt), rep, axis=2)
-    beta = jax.nn.sigmoid(ba[..., :value_heads].astype(f32))
-    g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
-        ba[..., value_heads:].astype(f32) + dt_bias.astype(f32))
-    return q, k, v, g, beta
+    with jax.named_scope("gdn_gates"):
+        q = mixed[..., :kq].reshape(b, s, key_heads, key_dim).astype(f32)
+        k = mixed[..., kq:2 * kq].reshape(b, s, key_heads, key_dim) \
+            .astype(f32)
+        v = mixed[..., 2 * kq:].reshape(b, s, value_heads, value_dim)
+        q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
+            * key_dim ** -0.5
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+        rep = value_heads // key_heads
+        q = jnp.repeat(q.astype(dt), rep, axis=2)
+        k = jnp.repeat(k.astype(dt), rep, axis=2)
+        beta = jax.nn.sigmoid(ba[..., :value_heads].astype(f32))
+        g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+            ba[..., value_heads:].astype(f32) + dt_bias.astype(f32))
+        return q, k, v, g, beta
 
 
 @functools.partial(jax.checkpoint, static_argnums=(3,))
 def _gated_norm(o, z, weight, eps):
     """``w * o / rms(o) * SiLU(z)`` over each head's dims, in float32."""
-    return (_rms(o, weight, eps)
-            * jax.nn.silu(z.astype(jnp.float32))).astype(o.dtype)
+    with jax.named_scope("gdn_gates"):
+        return (_rms(o, weight, eps)
+                * jax.nn.silu(z.astype(jnp.float32))).astype(o.dtype)
 
 
 def _gated_delta_net(qkvz, ba, conv, a_log, dt_bias, norm_w, *, key_heads,
                      value_heads, key_dim, value_dim, eps):
     """Everything of the DeltaNet mixer between its input projections and
-    its output projection, on arrays."""
+    its output projection, on arrays: the sibling scopes ``gdn_conv``,
+    ``gdn_gates`` (norms, gates, casts, the heads' repeat and reshapes)
+    and ``gdn_rule``."""
     from ..incubate.nn.functional.gated_delta_rule import \
         gated_delta_rule_chunked
     b, s, _ = qkvz.shape
     split = 2 * key_heads * key_dim + value_heads * value_dim
+    with jax.named_scope("gdn_gates"):
+        qkv = qkvz[..., :split]
     q, k, v, g, beta = _rule_inputs(
-        qkvz[..., :split], ba, conv, a_log, dt_bias, key_heads, value_heads,
-        key_dim, value_dim)
+        qkv, ba, conv, a_log, dt_bias, key_heads, value_heads, key_dim,
+        value_dim)
     with jax.named_scope("gdn_rule"):
         o = gated_delta_rule_chunked(q, k, v, g, beta)
-    z = qkvz[..., split:].reshape(b, s, value_heads, value_dim)
-    return _gated_norm(o, z, norm_w, eps).reshape(b, s, -1)
+    with jax.named_scope("gdn_gates"):
+        z = qkvz[..., split:].reshape(b, s, value_heads, value_dim)
+    out = _gated_norm(o, z, norm_w, eps)
+    with jax.named_scope("gdn_gates"):
+        return out.reshape(b, s, -1)
 
 
 class Qwen3NextGatedDeltaNet(Layer):
@@ -180,10 +191,13 @@ class Qwen3NextGatedDeltaNet(Layer):
             value_dim=c.linear_value_head_dim, eps=c.rms_norm_eps)
 
     def forward(self, x):
+        with jax.named_scope("gdn_proj"):
+            qkvz, ba = self.in_proj_qkvz(x), self.in_proj_ba(x)
         mixed = apply_op("gated_delta_net", self._core, [
-            self.in_proj_qkvz(x), self.in_proj_ba(x), self.conv, self.A_log,
-            self.dt_bias, self.norm.weight])
-        return self.out_proj(mixed)
+            qkvz, ba, self.conv, self.A_log, self.dt_bias,
+            self.norm.weight])
+        with jax.named_scope("gdn_proj"):
+            return self.out_proj(mixed)
 
 
 def _rotate(x, rotary_dim, theta):

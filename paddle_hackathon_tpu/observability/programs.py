@@ -38,7 +38,9 @@ calls never touch it — with:
   (:func:`mosaic_kernels`) and the phase census (:func:`phase_census`:
   every instruction that can run as a device op -> forward / backward
   / clip / update and the ``jax.named_scope`` component it sits
-  under; the join key to a device trace's ``XLA Ops`` events).  The
+  under; the join key to a device trace's ``XLA Ops`` events), with
+  what the compiler made itself and gave no name stack placed by the
+  arrays it moves (:func:`placed_census`, the same parse).  The
   deeper pass asks jax to lower and compile the program once more per
   build; the build record times it (``analysis_s``,
   ``analysis_split``) — read that before paying it in a serving hot
@@ -61,6 +63,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import heapq
 import inspect
 import os
 import re
@@ -74,6 +77,7 @@ from .sanitizers import make_lock
 __all__ = ["ProgramRegistry", "get_program_registry", "capture_signature",
            "diff_signatures", "signature_from_spec_key", "program_analysis",
            "mosaic_kernels", "phase_census", "phase_counts",
+           "placed_census", "placed_counts", "PLACED_VIA",
            "PHASE_COMPONENTS", "PHASE_SUBCOMPONENTS", "PHASES", "start_build_clock",
            "read_build_clock", "BUILD_CLOCK_KEYS", "note_kernel_fact",
            "read_kernel_facts",
@@ -324,15 +328,22 @@ def mosaic_kernels(hlo_text: str) -> Dict[str, int]:
 
 # The train step's scopes (``parallel/api.py``, ``models/gpt.py``,
 # ``models/qwen3_next.py``, ``models/bailing_hybrid.py``): the names a
-# component can take in the phase census.  A scope of PHASE_SUBCOMPONENTS names a part of the component it
-# is nested in (``gdn_rule`` inside ``gdn``, ``experts`` inside ``moe``):
-# an instruction under both reads ``"gdn/gdn_rule"``, so a part's time can
-# be told from its parent's, and a parent's is the sum over
-# ``component.split("/")[0]``.
+# component can take in the phase census.  A scope of PHASE_SUBCOMPONENTS
+# names a part of the component it is nested in (``gdn_rule`` inside
+# ``gdn``, ``experts`` inside ``moe``): an instruction under both reads
+# ``"gdn/gdn_rule"``, so a part's time can be told from its parent's, and a
+# parent's is the sum over ``component.split("/")[0]``.  Parts are
+# siblings, one level deep.  Where XLA merged instructions of two parts
+# into one it joins their name stacks with ``;``: the instruction goes to
+# the part listed first here, so the four parts PR 37 added (the mixers'
+# projections and gates, which between them leave only a layer's norm and
+# residual outside a part) stand last and take nothing from the parts that
+# were there.
 PHASE_COMPONENTS = ("embed", "attn", "mlp", "ln_f", "lm_head", "ce",
                     "clip", "update", "gdn", "moe", "kda", "mla")
 PHASE_SUBCOMPONENTS = ("gdn_conv", "gdn_rule", "router", "experts",
-                       "shared_expert", "kda_conv", "kda_rule")
+                       "shared_expert", "kda_conv", "kda_rule",
+                       "gdn_proj", "gdn_gates", "kda_proj", "kda_gates")
 PHASES = ("fwd", "bwd", "clip", "update", "other")
 
 _COMPUTATION_RE = re.compile(r'^(?:ENTRY )?%?([^\s(]+) \(.*\{$')
@@ -372,7 +383,7 @@ def _phase_of(op_name: str) -> Tuple[str, str]:
     component = next((s for s in scopes if s in PHASE_COMPONENTS), "")
     if component:
         inner = scopes[scopes.index(component) + 1:]
-        part = next((s for s in inner if s in PHASE_SUBCOMPONENTS), "")
+        part = next((s for s in PHASE_SUBCOMPONENTS if s in inner), "")
         if part:
             component += "/" + part
     if "transpose(jvp(" in op_name:
@@ -386,27 +397,12 @@ def _phase_of(op_name: str) -> Tuple[str, str]:
     return "other", component
 
 
-def phase_census(hlo_text: str) -> Dict[str, Tuple[str, str, bool]]:
-    """``{instruction name: (phase, component, mixed)}`` over the
-    instructions of a COMPILED program's HLO text that can run as device
-    ops: the entry computation and, through them, the bodies of its
-    ``while`` / ``conditional`` / ``call`` instructions -- not the
-    insides of fused computations.  The name is the instruction's own
-    (no leading ``%``): what a device trace's ``XLA Ops`` event carries
-    before `` = ``.
-
-    ``phase`` comes from the name stack jax wrote into ``op_name``:
-    ``bwd`` where it holds ``transpose(jvp(``, else ``fwd`` where it
-    holds ``jvp(``, else ``clip`` / ``update`` under those scopes, else
-    ``other`` (no metadata, or a program without autodiff);
-    ``component`` is the first of :data:`PHASE_COMPONENTS` on the stack
-    (``"gdn/gdn_rule"`` where a scope of :data:`PHASE_SUBCOMPONENTS` is
-    nested in it), else ``""``.  A fusion takes its own ``op_name``; where the
-    instructions fused into it belong to more than one phase it is
-    ``mixed``, and goes to the phase and component of the single
-    ``convolution`` / ``dot`` inside it if there is exactly one (the
-    matmul sets its time: a weight-gradient matmul that carries the
-    clip's sum of squares is backward), else keeps its own."""
+def _parse_hlo(hlo_text: str) -> Tuple[Dict[str, list], Optional[str]]:
+    """``({computation: [(name, opcode, op_name, called, tail)]}, entry)``
+    of an HLO module's text, the instructions of each computation in the
+    text's order (a compiled module is scheduled: text order is run
+    order); ``tail`` is the line after the opcode's ``(``: the operands
+    by name, then the attributes."""
     comps: Dict[str, list] = {}
     entry, cur = None, None
     for line in hlo_text.splitlines():
@@ -430,13 +426,59 @@ def phase_census(hlo_text: str) -> Dict[str, Tuple[str, str, bool]]:
             called += [b.strip().lstrip("%") for b in branches.split(",")]
         on = _OP_NAME_RE.search(rest)
         cur.append((name, op.group(1) if op else "",
-                    on.group(1) if on else "", called))
+                    on.group(1) if on else "", called,
+                    rest[op.end():] if op else ""))
+    return comps, entry
 
+
+def _device_computations(comps: Dict[str, list], entry: Optional[str]):
+    """The computations whose instructions can run as device ops: the
+    entry and, through them, the bodies of its ``while`` / ``conditional``
+    / ``call`` instructions."""
+    todo, seen = [entry] if entry else [], {entry}
+    while todo:
+        comp = todo.pop()
+        yield comp
+        for _, opcode, _, called, _ in comps.get(comp, ()):
+            if opcode in _CONTROL_FLOW:
+                for c in called:
+                    if c not in seen:
+                        seen.add(c)
+                        todo.append(c)
+
+
+def phase_census(hlo_text: str) -> Dict[str, Tuple[str, str, bool]]:
+    """``{instruction name: (phase, component, mixed)}`` over the
+    instructions of a COMPILED program's HLO text that can run as device
+    ops: the entry computation and, through them, the bodies of its
+    ``while`` / ``conditional`` / ``call`` instructions -- not the
+    insides of fused computations.  The name is the instruction's own
+    (no leading ``%``): what a device trace's ``XLA Ops`` event carries
+    before `` = ``.
+
+    ``phase`` comes from the name stack jax wrote into ``op_name``:
+    ``bwd`` where it holds ``transpose(jvp(``, else ``fwd`` where it
+    holds ``jvp(``, else ``clip`` / ``update`` under those scopes, else
+    ``other`` (no name stack: an instruction the compiler made itself,
+    which :func:`placed_census` places by the arrays it moves; or a
+    program without autodiff);
+    ``component`` is the first of :data:`PHASE_COMPONENTS` on the stack
+    (``"gdn/gdn_rule"`` where a scope of :data:`PHASE_SUBCOMPONENTS` is
+    nested in it), else ``""``.  A fusion takes its own ``op_name``; where the
+    instructions fused into it belong to more than one phase it is
+    ``mixed``, and goes to the phase and component of the single
+    ``convolution`` / ``dot`` inside it if there is exactly one (the
+    matmul sets its time: a weight-gradient matmul that carries the
+    clip's sum of squares is backward), else keeps its own."""
+    return _census(*_parse_hlo(hlo_text))
+
+
+def _census(comps, entry) -> Dict[str, Tuple[str, str, bool]]:
     def fused(comp, seen):
         """``(phase, component, is_matmul)`` of every instruction with
         metadata inside a fused computation, nested fusions included."""
         out = []
-        for _, opcode, op_name, called in comps.get(comp, ()):
+        for _, opcode, op_name, called, _ in comps.get(comp, ()):
             # an argument's op_name is its path (params['...']), no stack
             if "/" in op_name:
                 out.append(_phase_of(op_name)
@@ -449,9 +491,8 @@ def phase_census(hlo_text: str) -> Dict[str, Tuple[str, str, bool]]:
         return out
 
     census: Dict[str, Tuple[str, str, bool]] = {}
-    todo, seen = [entry] if entry else [], {entry}
-    while todo:
-        for name, opcode, op_name, called in comps.get(todo.pop(), ()):
+    for comp in _device_computations(comps, entry):
+        for name, opcode, op_name, called, _ in comps.get(comp, ()):
             phase, component = _phase_of(op_name)
             mixed = False
             if opcode == "fusion":
@@ -460,13 +501,150 @@ def phase_census(hlo_text: str) -> Dict[str, Tuple[str, str, bool]]:
                 matmuls = [x for x in inner if x[2]]
                 if mixed and len(matmuls) == 1:
                     phase, component = matmuls[0][:2]
-            elif opcode in _CONTROL_FLOW:
-                for c in called:
-                    if c not in seen:
-                        seen.add(c)
-                        todo.append(c)
             census[name] = (phase, component, mixed)
     return census
+
+
+# The unnamed instructions that compute nothing and only hand an array
+# on: the compiler's own copies between its memories and layouts (any
+# asynchronous ``*-start`` / ``*-done`` pair among them) and the views
+# (``ConcatBitcast`` joins a prefetch's slices back into their array).
+# placed_census walks through them, and places them by who waits.
+_MOVERS = ("bitcast", "get-tuple-element", "tuple", "copy", "reshape",
+           "transpose")
+_VIEW_CALL = 'custom_call_target="ConcatBitcast"'
+# never a device op of their own: no entry in the placed map
+_NEVER_RUN = ("parameter", "constant", "tuple", "get-tuple-element",
+              "bitcast")
+_OPERAND_RE = re.compile(r'%([^\s,(){}]+)')
+PLACED_VIA = ("consumer", "producer", "unplaced")
+
+
+def placed_census(hlo_text: str, census: Optional[dict] = None
+                  ) -> Dict[str, Tuple[str, str, str]]:
+    """``{instruction name: (phase, component, via)}`` over the
+    instructions of :func:`phase_census` that have no name stack
+    (``("other", "")`` there) and can run as a device op of their own:
+    what the compiler made itself -- the asynchronous copies between its
+    two memories (``copy-start`` / ``copy-done``, ``slice-start`` /
+    ``slice-done``), layout copies, the kernels ``ragged_dot`` expands
+    to -- placed by the arrays they move, within their own computation
+    (a compiled module is scheduled: text order is run order):
+
+    - an instruction that only moves an array (``copy``, ``reshape``,
+      ``transpose``, an asynchronous pair, which is one: a ``*-done`` is
+      placed as its ``*-start`` is) goes ``via="consumer"`` to the place
+      of the first instruction in the computation's order that reads its
+      result and has a place, looking through the unnamed instructions
+      that hand the array on (``bitcast``, ``get-tuple-element``,
+      ``tuple``, another unnamed mover, ``ConcatBitcast``): a prefetch's
+      wait belongs to the op that waits for it; else ``via="producer"``
+      to the nearest instruction with a place that made its operand,
+      looked through in the same way;
+    - an instruction that computes (a ``ragged-dot`` kernel, a fusion
+      whose root lost its metadata) goes to its producer first, then to
+      its consumer: its operands were made where the op it is a part of
+      was named, while its first reader may be anything (a weight
+      gradient's is the clip's sum of squares).  Once placed it is a
+      place for the others, so the prefetch of an expert's weights goes
+      with the kernel that reads them;
+    - else ``("other", "", "unplaced")``: a parameter or the root ends
+      the walk, which never leaves its computation.
+
+    ``census`` is :func:`phase_census` of the same text where the caller
+    has it; it is read, never changed."""
+    comps, entry = _parse_hlo(hlo_text)
+    return _placed(comps, entry,
+                   _census(comps, entry) if census is None else census)
+
+
+def _placed(comps, entry, census) -> Dict[str, Tuple[str, str, str]]:
+    placed: Dict[str, Tuple[str, str, str]] = {}
+    for comp in _device_computations(comps, entry):
+        instrs = comps.get(comp, ())
+        at = {ins[0]: i for i, ins in enumerate(instrs)}
+        operands = [[at[o] for o in _OPERAND_RE.findall(
+            ins[4][:max(ins[4].find(")"), 0)]) if o in at]
+            for ins in instrs]
+        users: List[list] = [[] for _ in instrs]
+        for i, ops in enumerate(operands):
+            for o in ops:
+                users[o].append(i)
+        # (phase, component) of the instructions that have one: by name
+        # stack now, the unnamed that compute as they are placed
+        place: List[Optional[tuple]] = [
+            None if census[ins[0]][:2] == ("other", "")
+            else census[ins[0]][:2] for ins in instrs]
+        mover = [place[i] is None and (
+            op in _MOVERS or op.endswith(("-start", "-done"))
+            or (op == "custom-call" and _VIEW_CALL in tail))
+            for i, (_, op, _, _, tail) in enumerate(instrs)]
+
+        def walk(start, edges, sign):
+            """The place nearest to ``start`` along ``edges`` in the
+            computation's order, through the movers."""
+            heap = [sign * j for j in edges[start]]
+            heapq.heapify(heap)
+            seen = set(heap)
+            while heap:
+                j = sign * heapq.heappop(heap)
+                if place[j] is not None:
+                    return place[j]
+                if mover[j]:
+                    for k in edges[j]:
+                        if sign * k not in seen:
+                            seen.add(sign * k)
+                            heapq.heappush(heap, sign * k)
+            return None
+
+        def find(first, last, order):
+            for via in order:
+                found = walk(last, users, 1) if via == "consumer" \
+                    else walk(first, operands, -1)
+                if found is not None:
+                    return found, via
+            return None, "unplaced"
+
+        todo = [i for i, ins in enumerate(instrs)
+                if place[i] is None and ins[1] not in _NEVER_RUN]
+        # what computes: producer first; again while a sweep places any,
+        # since each one placed is a place for its neighbours
+        computes = [i for i in todo if not mover[i]]
+        while computes:
+            left = []
+            for i in computes:
+                place[i], via = find(i, i, ("producer", "consumer"))
+                if place[i] is None:
+                    left.append(i)
+                else:
+                    placed[instrs[i][0]] = place[i] + (via,)
+            if len(left) == len(computes):
+                break
+            computes = left
+        for i in computes:
+            placed[instrs[i][0]] = ("other", "", "unplaced")
+        # what moves: the pair as one, consumer first
+        for i in todo:
+            name, opcode = instrs[i][:2]
+            if not mover[i] or name in placed:
+                continue
+            pair = [i]
+            if opcode.endswith("-start"):
+                pair += [j for j in users[i]
+                         if instrs[j][1] == opcode[:-5] + "done"]
+            found, via = find(pair[0], pair[-1], ("consumer", "producer"))
+            for j in pair:
+                placed[instrs[j][0]] = (found or ("other", "")) + (via,)
+    return placed
+
+
+def placed_counts(placed: Dict[str, Tuple[str, str, str]]) -> Dict[str, int]:
+    """Instructions of :func:`placed_census` by how they were placed:
+    what the registry's snapshots carry in place of the names."""
+    out = dict.fromkeys(PLACED_VIA, 0)
+    for _, _, via in placed.values():
+        out[via] += 1
+    return out
 
 
 def phase_counts(census: Dict[str, Tuple[str, str, bool]]) -> Dict[str, int]:
@@ -481,11 +659,15 @@ def phase_counts(census: Dict[str, Tuple[str, str, bool]]) -> Dict[str, int]:
 
 
 def _harvest_analysis(fn, args, kwargs) -> Tuple[Optional[dict],
+                                                Optional[dict],
                                                 Optional[dict]]:
-    """``(analysis, census)``: per-program ``memory_analysis()`` bytes,
-    ``cost_analysis()`` flops, the Mosaic kernel census and the phase
-    census (:func:`phase_census`; the analysis carries its per-phase
-    counts, the map itself goes to the registry) via the AOT ``lower()``
+    """``(analysis, census, placed)``: per-program ``memory_analysis()``
+    bytes, ``cost_analysis()`` flops, the Mosaic kernel census, the phase
+    census and what it leaves unnamed placed by dataflow
+    (:func:`phase_census`, :func:`placed_census`: one parse of the text;
+    the analysis carries their counts, ``phases`` and ``placed``, and the
+    seconds of both, ``census_s``; the maps themselves go to the
+    registry) via the AOT ``lower()``
     handle (the ``parallel/planner.py`` harvesting shape).  Re-lowers
     and re-compiles once — the stated cost of ``PHT_PROGRAM_ANALYSIS``
     (with the persistent compile cache on, the re-compile is a cache
@@ -494,20 +676,23 @@ def _harvest_analysis(fn, args, kwargs) -> Tuple[Optional[dict],
     the analyses."""
     lower = getattr(fn, "lower", None)
     if lower is None:
-        return None, None
+        return None, None, None
     try:
         compiled = lower(*args, **(kwargs or {})).compile()
     except Exception:  # noqa: BLE001 — analysis is best-effort evidence
-        return None, None
+        return None, None, None
     out: Dict[str, Any] = {}
-    census = None
+    census = placed = None
     try:
         text = compiled.as_text()
         out["mosaic_kernels"] = mosaic_kernels(text)
         t0 = time.perf_counter()
-        census = phase_census(text) or None
+        comps, entry = _parse_hlo(text)
+        census = _census(comps, entry) or None
         if census:
+            placed = _placed(comps, entry, census)
             out["phases"] = phase_counts(census)
+            out["placed"] = placed_counts(placed)
             out["census_s"] = round(time.perf_counter() - t0, 6)
     except Exception:  # noqa: BLE001
         pass
@@ -528,7 +713,7 @@ def _harvest_analysis(fn, args, kwargs) -> Tuple[Optional[dict],
             out["flops"] = flops
     except Exception:  # noqa: BLE001
         pass
-    return out or None, census
+    return out or None, census, placed
 
 
 # ---------------------------------------------------------------------------
@@ -638,7 +823,7 @@ def read_kernel_facts() -> Dict[str, list]:
 class _Site:
     __slots__ = ("kind", "builds", "evictions", "compile_seconds_total",
                  "signatures", "last_signature", "history", "last_ts",
-                 "analysis", "phase_census")
+                 "analysis", "phase_census", "placed_census")
 
     def __init__(self, kind: str, history: int):
         self.kind = kind
@@ -651,6 +836,7 @@ class _Site:
         self.last_ts = 0.0
         self.analysis: Optional[dict] = None
         self.phase_census: Optional[dict] = None
+        self.placed_census: Optional[dict] = None
 
 
 class ProgramRegistry:
@@ -702,12 +888,12 @@ class ProgramRegistry:
         are the analysis pass's cost, not the build's."""
         sig = tuple(signature) if signature is not None \
             else capture_signature(args, kwargs, fn=fn, donated=donated)
-        analysis = census = None
+        analysis = census = placed = None
         timing = dict(build_clock or ())
         if analysis_enabled() and fn is not None:
             start_build_clock()
             t0 = time.perf_counter()
-            analysis, census = _harvest_analysis(fn, args, kwargs)
+            analysis, census, placed = _harvest_analysis(fn, args, kwargs)
             timing["analysis_s"] = round(time.perf_counter() - t0, 6)
             timing["analysis_split"] = read_build_clock()
         now = time.time()
@@ -726,6 +912,7 @@ class ProgramRegistry:
             if analysis is not None:
                 rec.analysis = analysis
                 rec.phase_census = census
+                rec.placed_census = placed
             record = {"build": n, "ts": now,
                       "compile_s": round(float(compile_s), 6), **timing,
                       "cause": cause, "analysis": analysis}
@@ -836,6 +1023,16 @@ class ProgramRegistry:
         with self._lock:
             rec = self._sites.get(site)
             return rec.phase_census if rec is not None else None
+
+    def placed_census(self, site: str) -> Optional[dict]:
+        """:func:`placed_census` of ``site``'s newest analysed build:
+        ``{instruction name: (phase, component, via)}`` over the
+        instructions :meth:`phase_census` leaves ``("other", "")``, or
+        ``None`` where no build of the site was analysed.  The snapshots
+        carry its counts only (``analysis["placed"]``)."""
+        with self._lock:
+            rec = self._sites.get(site)
+            return rec.placed_census if rec is not None else None
 
     def snapshot(self) -> dict:
         """JSON-able registry dump — the ``/debug/programs`` body and

@@ -630,6 +630,110 @@ def test_phase_census_on_a_hand_written_executable():
     assert programs.phase_census("no computation here") == {}
 
 
+# a scheduled step cut to what the placement tells apart: the compiler's
+# own instructions (no metadata) between named ones, text order = run order
+_PLACED_HLO = """HloModule jit_train_step, is_scheduled=true
+
+%fused_computation.1 (param_0.1: bf16[8,64]) -> bf16[8,64] {
+  %param_0.1 = bf16[8,64]{1,0} parameter(0)
+  ROOT %tanh.1 = bf16[8,64]{1,0} tanh(%param_0.1)
+}
+
+%body.1 (arg.1: (s32[], bf16[8,64])) -> (s32[], bf16[8,64]) {
+  %arg.1 = (s32[], bf16[8,64]{1,0}) parameter(0)
+  %get-tuple-element.1 = bf16[8,64]{1,0} get-tuple-element(%arg.1), index=1
+  %copy-start.5 = (bf16[8,64]{1,0:S(1)}, bf16[8,64]{1,0}, u32[]{:S(2)}) copy-start(%get-tuple-element.1)
+  %get-tuple-element.2 = s32[]{:T(128)} get-tuple-element(%arg.1), index=0
+  %copy.6 = s32[]{:T(128)} copy(%get-tuple-element.2)
+  %copy-done.5 = bf16[8,64]{1,0:S(1)} copy-done(%copy-start.5)
+  %convolution.5 = bf16[8,64]{1,0} convolution(%copy-done.5, %copy-done.5), dim_labels=bf_io->bf, metadata={op_name="jit(train_step)/jvp(kda)/kda_proj/while/body/dot_general"}
+  ROOT %tuple.5 = (s32[]{:T(128)}, bf16[8,64]{1,0}) tuple(%copy.6, %convolution.5)
+}
+
+%cond.1 (arg.2: (s32[], bf16[8,64])) -> pred[] {
+  %arg.2 = (s32[], bf16[8,64]{1,0}) parameter(0)
+  ROOT %lt.1 = pred[] constant(true)
+}
+
+ENTRY %main.1 (p.1: bf16[8,64], q.1: bf16[8,64]) -> (bf16[8,64], bf16[8,64], bf16[8,64]) {
+  %p.1 = bf16[8,64]{1,0:T(8,128)(2,1)} parameter(0), metadata={op_name="params[\\'w\\']"}
+  %q.1 = bf16[8,64]{1,0:T(8,128)(2,1)} parameter(1)
+  %fusion.1 = bf16[8,64]{1,0} fusion(%p.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(train_step)/jvp(mlp)/tanh"}
+  %copy-start.1 = (bf16[8,64]{1,0:S(1)}, bf16[8,64]{1,0}, u32[]{:S(2)}) copy-start(%fusion.1)
+  %slice-start.1 = ((bf16[8,64]{1,0}), bf16[4,64]{1,0:S(1)}, s32[]{:S(2)}) slice-start(%p.1), slice={[0:4], [0:64]}
+  %slice-start.2 = ((bf16[8,64]{1,0}), bf16[4,64]{1,0:S(1)}, s32[]{:S(2)}) slice-start(%p.1), slice={[4:8], [0:64]}
+  %copy-done.1 = bf16[8,64]{1,0:S(1)} copy-done(%copy-start.1)
+  %fusion.2 = bf16[8,64]{1,0} fusion(%copy-done.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(train_step)/transpose(jvp(attn))/mul"}
+  %copy.2 = bf16[8,64]{0,1} copy(%fusion.2)
+  %copy.3 = bf16[8,64]{1,0} copy(%copy.2)
+  %fusion.3 = bf16[8,64]{1,0} fusion(%copy.3), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(train_step)/jvp(moe)/experts/add"}
+  %fusion.4 = bf16[8,64]{1,0} fusion(%copy.3), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(train_step)/update/sub"}
+  %slice-done.1 = bf16[4,64]{1,0:S(1)} slice-done(%slice-start.1)
+  %slice-done.2 = bf16[4,64]{1,0:S(1)} slice-done(%slice-start.2)
+  %custom-call.2 = bf16[8,64]{1,0:S(1)} custom-call(%slice-done.1, %slice-done.2), custom_call_target="ConcatBitcast"
+  %ragged-dot-none.1 = bf16[8,64]{1,0} custom-call(%fusion.3, /*index=1*/%custom-call.2), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %multiply_reduce_fusion.5 = bf16[8,64]{1,0} fusion(%ragged-dot-none.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(train_step)/clip/reduce_sum"}
+  %custom-call.1 = bf16[8,64]{1,0} custom-call(%q.1), custom_call_target="Mystery"
+  %copy.4 = bf16[8,64]{1,0} copy(%fusion.4)
+  %tuple.0 = (s32[], bf16[8,64]{1,0}) tuple(%constant.0, %multiply_reduce_fusion.5)
+  %while.1 = (s32[], bf16[8,64]{1,0}) while(%tuple.0), condition=%cond.1, body=%body.1, metadata={op_name="jit(train_step)/jvp(kda)/kda_proj/while"}
+  %get-tuple-element.9 = bf16[8,64]{1,0} get-tuple-element(%while.1), index=1
+  %transpose.9 = bf16[8,64]{0,1} transpose(%get-tuple-element.9), dimensions={1,0}
+  ROOT %tuple.9 = (bf16[8,64]{1,0}, bf16[8,64]{1,0}, bf16[8,64]{0,1}) tuple(%copy.4, %custom-call.1, %transpose.9)
+}
+"""
+
+
+@pytest.mark.parametrize("name, want", [
+    # made in fwd/mlp, read in bwd/attn: a prefetch's wait is its reader's
+    ("copy-start.1", ("bwd", "attn", "consumer")),
+    ("copy-done.1", ("bwd", "attn", "consumer")),       # as its start
+    # a chain of two copies, the second read in two components: the
+    # first reader in the text's order, for both
+    ("copy.2", ("fwd", "moe/experts", "consumer")),
+    ("copy.3", ("fwd", "moe/experts", "consumer")),
+    # only the root reads it: what made its operand
+    ("copy.4", ("update", "update", "producer")),
+    # nothing named reads it or made its operand
+    ("custom-call.1", ("other", "", "unplaced")),
+    # a kernel computes: where its operands were made, though the clip's
+    # sum of squares reads it first
+    ("ragged-dot-none.1", ("fwd", "moe/experts", "producer")),
+    # the sliced prefetch of a parameter that only the kernel reads goes
+    # with the kernel, through the view that joins the slices
+    ("slice-start.2", ("fwd", "moe/experts", "consumer")),
+    ("slice-done.1", ("fwd", "moe/experts", "consumer")),
+    ("custom-call.2", ("fwd", "moe/experts", "consumer")),
+    # a loop's body: its parameter's element prefetched for a named matmul
+    ("copy-start.5", ("fwd", "kda/kda_proj", "consumer")),
+    ("copy-done.5", ("fwd", "kda/kda_proj", "consumer")),
+    # carried from the body's parameter to its root: the walk ends there
+    ("copy.6", ("other", "", "unplaced")),
+    # past a named loop's element to the root: the loop made it
+    ("transpose.9", ("fwd", "kda/kda_proj", "producer")),
+])
+def test_placed_census_on_a_hand_written_executable(name, want):
+    assert programs.placed_census(_PLACED_HLO)[name] == want
+
+
+def test_placed_census_holds_the_unnamed_that_run_and_leaves_the_census():
+    census = programs.phase_census(_PLACED_HLO)
+    before = dict(census)
+    placed = programs.placed_census(_PLACED_HLO, census)
+    assert census == before
+    # the unnamed instructions only, and none that never runs as an op
+    assert all(census[k][:2] == ("other", "") for k in placed)
+    assert not {"p.1", "q.1", "tuple.0", "get-tuple-element.9", "fusion.2",
+                "while.1", "tanh.1", "lt.1"} & set(placed)
+    assert len(placed) == 16
+    assert programs.placed_counts(placed) == {
+        "consumer": 11, "producer": 3, "unplaced": 2}
+    # the hand-written step of the census test: its one pair feeds nothing
+    assert programs.placed_census(_PHASE_HLO)["copy-start.1"] == \
+        ("other", "", "unplaced")
+    assert programs.placed_census("no computation here") == {}
+
+
 @pytest.fixture(scope="module")
 def toy_train_step():
     """One toy GPT step through ``make_sharded_train_step``, built under
@@ -685,6 +789,76 @@ def test_phase_census_of_a_compiled_toy_train_step(toy_train_step):
     assert get_program_registry().phase_census("no.such.site") is None
 
 
+def test_placed_census_of_a_compiled_toy_train_step(toy_train_step):
+    registry = get_program_registry()
+    census = registry.phase_census(toy_train_step["site"])
+    placed = registry.placed_census(toy_train_step["site"])
+    # a map beside the census, over what the census could not name
+    assert placed and set(placed) <= set(census)
+    assert all(census[k][:2] == ("other", "") for k in placed)
+    assert {via for _, _, via in placed.values()} <= set(programs.PLACED_VIA)
+    assert any(p != ("other", "") for *p, _ in placed.values())
+    counts = toy_train_step["snap"]["analysis"]["placed"]
+    assert counts == programs.placed_counts(placed)
+    assert sum(counts.values()) == len(placed)
+    assert registry.placed_census("no.such.site") is None
+
+
+# what an RMSNorm and a residual are made of, forward and backward (the
+# backward's holds the call of the mixer's checkpoint and the views that
+# cost nothing)
+_NORM_AND_RESIDUAL = {"add", "add_any", "broadcast_in_dim",
+                      "convert_element_type", "div", "mul", "reduce_sum",
+                      "rsqrt", "square", "reshape", "remat2"}
+
+
+@pytest.mark.parametrize("workload, scope", [
+    ("ling3-tiny-rehearsal.train-s64", "kda"),
+    ("qwen3-next-tiny-rehearsal.train-s64", "gdn")])
+def test_a_delta_rule_mixer_names_its_projections_and_gates(workload, scope):
+    """The lowered step of the model's rehearsal configuration: the four
+    parts are on the name stacks in both phases, and what stands under the
+    mixer's scope outside every part is its layer's norm and residual."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import run as harness
+    from benchmark.drivers import train_steps
+    cell_file = harness.load_json("workloads", workload)
+    config = harness.load_json("configs", cell_file["config"])
+    cell = harness.Cell(cell_file, config, seed=0)
+    spec = harness.config_module(config, "reference", "reference") \
+        .param_spec(config)
+    dtype = jnp.dtype(config["training"]["param_dtype"])
+    step, state, _ = train_steps.build_program(
+        cell, {k: jnp.zeros(shape, dtype) for k, shape in spec.items()})
+    mesh = jax.tree.leaves(state["params"])[0].sharding.mesh
+    ids = jnp.zeros((cell_file["traffic"]["batch"],
+                     cell_file["traffic"]["seqlen"]), jnp.int32)
+    with jax.set_mesh(mesh):
+        text = step._jitted.lower(
+            state["params"], state["opt_state"], state["step"], (ids, ids),
+            jax.random.key(0), jnp.float32(1e-4)).as_text(debug_info=True)
+    made_of = {}
+    for stack in set(re.findall(r'loc\("(jit\([^"]*)"', text)):
+        made_of.setdefault(programs._phase_of(stack), set()).add(
+            stack.rsplit("/", 1)[-1])
+    for phase in ("fwd", "bwd"):
+        for part in ("proj", "gates", "conv", "rule"):
+            assert (phase, f"{scope}/{scope}_{part}") in made_of, (phase, part)
+        assert "dot_general" in made_of[(phase, f"{scope}/{scope}_proj")]
+        assert {"rsqrt", "logistic"} <= made_of[
+            (phase, f"{scope}/{scope}_gates")]
+        # no matmul, slice, gate or loop is left outside a part
+        assert made_of[(phase, scope)] <= _NORM_AND_RESIDUAL, (
+            phase, made_of[(phase, scope)] - _NORM_AND_RESIDUAL)
+    assert "dot_general" not in made_of[("fwd", f"{scope}/{scope}_gates")]
+
+
 def _toy_fit_program(front_end):
     """A two-layer MLP with a global-norm clip through ``Model.fit``'s
     compiled path or the auto-parallel ``Engine``, built under the
@@ -736,6 +910,22 @@ def test_every_front_end_names_clip_and_update(front_end, request):
     census = get_program_registry().phase_census(site)
     counts = programs.phase_counts(census)
     assert counts.get("clip", 0) > 0 and counts.get("update", 0) > 0, counts
+
+
+def test_program_report_prints_the_two_censuses_counts(toy_train_step,
+                                                       capsys):
+    import program_report
+    program_report.render({"sites": {toy_train_step["site"]:
+                                     toy_train_step["snap"]}})
+    out = capsys.readouterr().out
+    analysis = toy_train_step["snap"]["analysis"]
+    assert f"census: phases other={analysis['phases']['other']}" in out
+    placed = analysis["placed"]
+    assert ("unnamed placed by consumer={consumer} producer={producer} "
+            "unplaced={unplaced}".format(**placed)) in out
+    # a site that was not analysed gets no census row
+    program_report.render({"sites": {"plain": {"builds": 1}}})
+    assert "census" not in capsys.readouterr().out
 
 
 def test_build_record_says_where_the_seconds_went(toy_train_step):

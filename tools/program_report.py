@@ -48,6 +48,21 @@ def _analysis_str(a):
     return "  ".join(parts)
 
 
+def _census_str(a):
+    """The analysed build's two censuses as counts: instructions by phase
+    (``mixed`` fusions among them) and, of those without a name stack,
+    how many the dataflow placed (``programs.placed_census``)."""
+    if not a or not a.get("phases"):
+        return ""
+    out = "phases " + " ".join(f"{k}={v}" for k, v in a["phases"].items())
+    if a.get("placed"):
+        out += "; unnamed placed by " + " ".join(
+            f"{k}={v}" for k, v in a["placed"].items())
+    if a.get("census_s") is not None:
+        out += f"; {a['census_s']:.3f}s"
+    return out
+
+
 def _ranked(snap):
     return sorted(snap.get("sites", {}).items(),
                   key=lambda kv: (-kv[1].get("compile_seconds_total", 0.0),
@@ -71,6 +86,9 @@ def render(snap, out=None):
         analysis = _analysis_str(s.get("analysis"))
         if analysis:
             out.write(f"  {'':<{width}}  hbm: {analysis}\n")
+        census = _census_str(s.get("analysis"))
+        if census:
+            out.write(f"  {'':<{width}}  census: {census}\n")
     return len(sites)
 
 
