@@ -27,13 +27,14 @@ Kept beside the five inputs: the float32 triangular inverse, one
 (CHUNK, CHUNK) a chunk and head = 4 * CHUNK bytes a token and head (67 MB a
 layer at 8,192 tokens x 32 heads, a quarter of the scan's states there).
 It is built once a step, outside this module's checkpoints, and is the
-residual of its own rule (``d inv(M) = -inv(M) dM inv(M)``, so the doubling
-steps that build it keep nothing).  Rebuilt in the backward, under
+residual of its own rule (``d inv(M) = -inv(M) dM inv(M)``, so the kernel
+that builds it, ``kernels/delta_rule_inverse.py``, is never differentiated
+and keeps nothing).  Rebuilt in the backward, under
 ``jax.checkpoint``, from ``q, k, v, g, beta`` and that inverse: the system
 matrix in front of it (decays, ``k_beta k^T``) and everything the scan reads
 behind it (``qd, kd, w, u, attn``: bfloat16 matmuls and elementwise passes).
-Rebuilding the inverse instead would repeat its ten batched float32
-products, the dearest of the rule, for the sake of those cheap arrays.
+Rebuilding the inverse instead would run its kernel again and the system
+in front of it, for the sake of those cheap arrays.
 
 A caller that puts a whole mixer under a ``jax.checkpoint`` of its own keeps
 the inverse by adding ``KEPT_INVERSE`` to its policy's names.  The name sits
@@ -58,6 +59,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ....core.autograd import apply_op
 from ....core.tensor import Tensor
+from ..kernels import delta_rule_inverse
 
 CHUNK = 64
 # the name the kept inverse carries (``jax.ad_checkpoint.checkpoint_name``,
@@ -78,26 +80,17 @@ def causal_depthwise_conv(x, taps):
 
 @jax.custom_vjp
 def _unit_lower_inverse(a):
-    """``inv(I + a)`` for strictly lower-triangular ``a`` (..., c, c),
-    float32: with ``b = -a`` nilpotent, ``(I - b)^-1 = (I + b)(I + b^2)
-    (I + b^4)...``, log2(c) squarings, every step a matmul."""
-    c = a.shape[-1]
-    eye = jnp.eye(c, dtype=a.dtype)
-    power = -a
-    inv = eye + power
-    span = 2
-    while span < c:
-        power = power @ power
-        inv = inv + inv @ power
-        span *= 2
-    return inv
+    """``inv(I + a)`` for strictly lower-triangular ``a`` (n, b, h, c, c),
+    float32: one Pallas kernel (``kernels/delta_rule_inverse.py``), every
+    matrix solved in VMEM by forward substitution."""
+    return delta_rule_inverse.inverse(a)
 
 
 def _unit_lower_inverse_fwd(a):
     # named here, where output and residual are still one value: a policy
     # that saves KEPT_INVERSE then saves what ``_unit_lower_inverse_bwd``
     # reads, not only what the caller reads
-    inv = checkpoint_name(_unit_lower_inverse(a), KEPT_INVERSE)
+    inv = checkpoint_name(delta_rule_inverse.inverse(a), KEPT_INVERSE)
     return inv, inv
 
 

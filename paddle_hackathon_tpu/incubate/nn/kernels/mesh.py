@@ -15,6 +15,8 @@ mesh the trace runs under (``jax.set_mesh``) and says how to split, and
 :func:`over_batch_and_heads` runs a per-shard function under a
 ``shard_map`` that is manual over every axis — each device then calls
 the kernel on its own batch rows and heads, no collective involved.
+The delta rule's inverse (``delta_rule_inverse.py``) is split the same
+way, with its batch at dim 1 (``batch_dim``).
 
 Meshes this does not cover — a 'pp' or 'sp' axis, a region that is
 already manual (the pipeline's), a batch or head count the axes do not
@@ -79,11 +81,11 @@ def plan(batch: int, heads: int) -> Optional[Plan]:
 
 
 def over_batch_and_heads(local_fn, p: Plan, arrays, head_dims, out_ndim,
-                         out_head_dim, seed=None):
+                         out_head_dim, seed=None, batch_dim=0):
     """``local_fn(*shards, seed)`` on every device's own batch rows and
-    heads.  ``arrays[i]`` has batch at dim 0 and heads at dim
+    heads.  ``arrays[i]`` has batch at dim ``batch_dim`` and heads at dim
     ``head_dims[i]``; the ``out_ndim``-dimensional result has batch at
-    dim 0 and heads at ``out_head_dim``.  ``seed`` (a (1,) int32 dropout
+    ``batch_dim`` and heads at ``out_head_dim``.  ``seed`` (a (1,) int32 dropout
     seed, or None) is decorrelated per shard — the kernels hash the LOCAL
     batch/head index into the mask, so shards sharing a seed would share
     masks."""
@@ -91,7 +93,7 @@ def over_batch_and_heads(local_fn, p: Plan, arrays, head_dims, out_ndim,
 
     def spec(ndim, hdim):
         s = [None] * ndim
-        s[0], s[hdim] = bax, p.head_axis
+        s[batch_dim], s[hdim] = bax, p.head_axis
         return P(*s)
 
     in_specs = tuple(spec(a.ndim, h) for a, h in zip(arrays, head_dims))

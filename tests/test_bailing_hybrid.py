@@ -34,7 +34,8 @@ from paddle_hackathon_tpu.models import (BailingHybridConfig,  # noqa: E402
 from paddle_hackathon_tpu.models import bailing_hybrid as prog  # noqa: E402
 from paddle_hackathon_tpu.nn.layer import functional_call  # noqa: E402
 from paddle_hackathon_tpu.parallel import moe  # noqa: E402
-from test_gated_delta_rule import _inverse_products, rule  # noqa: E402
+from test_gated_delta_rule import (_inverse_products, _kernel_calls,  # noqa: E402
+                                   rule)
 
 TINY = "ling3-tiny-rehearsal"
 
@@ -216,16 +217,20 @@ def _summed(fn):
 
 
 def test_the_kda_layers_backward_does_not_rebuild_the_inverse():
-    """Under the layer's checkpoint the gradient program holds the
-    forward's ten doubling products and the two of the inverse's own rule:
-    the policy keeps the value that rule reads.  Ten more (22, what the
-    layer read while the name sat on the rule's output alone) would be the
-    inverse and the system in front of it built again in the backward."""
+    """Under the layer's checkpoint the gradient program calls the
+    inverse's kernel once, the forward's, beside the two products of the
+    inverse's own rule: the policy keeps the value that rule reads.  A
+    second call (what the layer made while the name sat on the rule's
+    output alone) would be the inverse and the system in front of it built
+    again in the backward."""
     core, plain, args = _kda_layer()
-    assert _inverse_products(core, args) == 10
+    assert _kernel_calls(core, args) == 1
+    assert _inverse_products(core, args) == 0
     every = tuple(range(len(args)))
-    assert _inverse_products(jax.grad(_summed(core), every), args) == 12
-    assert _inverse_products(jax.grad(_summed(plain), every), args) == 12
+    for fn in (core, plain):
+        grad = jax.grad(_summed(fn), every)
+        assert _kernel_calls(grad, args) == 1
+        assert _inverse_products(grad, args) == 2
 
 
 def test_what_a_kda_layer_keeps_for_its_backward():
@@ -264,8 +269,9 @@ def test_what_a_kda_layer_keeps_for_its_backward():
     bare = jax.checkpoint(
         plain, policy=jax.checkpoint_policies.save_only_these_names())
     assert kept(bare) == sorted(arguments)
-    assert _inverse_products(
-        jax.grad(_summed(bare), tuple(range(len(args)))), args) == 22
+    grad = jax.grad(_summed(bare), tuple(range(len(args))))
+    assert _kernel_calls(grad, args) == 2
+    assert _inverse_products(grad, args) == 2
 
 
 def test_the_kda_layers_gradients_equal_those_without_a_checkpoint():

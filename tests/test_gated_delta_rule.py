@@ -10,6 +10,7 @@ import re
 import sys
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -90,8 +91,9 @@ def test_the_scan_keeps_one_state_a_chunk():
 
 def _inverse_products(fn, args):
     """``dot_general``s of the lowered ``fn`` whose two operands are both
-    float32 (.., CHUNK, CHUNK): the doubling steps of the triangular
-    inverse and the two products of its rule, nothing else in the file."""
+    float32 (.., CHUNK, CHUNK): the two products of the inverse's rule and
+    any product that built the inverse outside its kernel (the parent's
+    log-doubling had ten), nothing else in the file."""
     square = f"{rule.CHUNK}x{rule.CHUNK}xf32"
     count = 0
     for line in jax.jit(fn).lower(*args).as_text().splitlines():
@@ -104,26 +106,51 @@ def _inverse_products(fn, args):
     return count
 
 
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (tuple, list)) else (v,):
+            if isinstance(x, jax.extend.core.ClosedJaxpr):
+                yield x.jaxpr
+            elif isinstance(x, jax.extend.core.Jaxpr):
+                yield x
+
+
+def _kernel_calls(fn, args, name="delta_rule_inverse"):
+    """Calls of the Pallas kernel ``name`` in ``fn``'s jaxpr, through every
+    nested jaxpr (jit, checkpoint, scan, custom rules; a rebuild in the
+    backward is a second call): on the CPU the kernel runs interpreted and
+    leaves no custom call in the lowered text to count."""
+    def calls(jaxpr):
+        return sum(e.params["name"] == name
+                   if e.primitive.name == "pallas_call"
+                   else sum(calls(j) for j in _sub_jaxprs(e))
+                   for e in jaxpr.eqns)
+    return calls(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
 def test_the_backward_reads_the_inverse_and_does_not_rebuild_it():
-    """The inverse stands outside every checkpoint: the gradient program
-    holds its ten doubling products once (the forward's) and the two of
-    its own rule; ten more would mean it is rebuilt in the backward."""
+    """The inverse stands outside every checkpoint: the forward is one
+    call of its kernel and no float32 product of its size; the gradient
+    the same call and the two products of its own rule, where a second
+    call would mean it is rebuilt in the backward."""
     args = _inputs(2 * rule.CHUNK, -0.1, dtype=jnp.bfloat16)
-    doubling = 2 * (rule.CHUNK.bit_length() - 2)         # 5 squarings, 5 sums
-    assert _inverse_products(gated_delta_rule_chunked, args) == doubling
+    assert _kernel_calls(gated_delta_rule_chunked, args) == 1
+    assert _inverse_products(gated_delta_rule_chunked, args) == 0
 
     def loss(*a):
         return jnp.sum(gated_delta_rule_chunked(*a).astype(jnp.float32))
     grad = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
-    assert _inverse_products(grad, args) == doubling + 2
+    assert _kernel_calls(grad, args) == 1
+    assert _inverse_products(grad, args) == 2
 
 
 def test_a_caller_that_rebuilds_its_mixer_keeps_the_inverse_by_name():
     """Under a caller's ``jax.checkpoint`` whose policy saves
-    ``KEPT_INVERSE`` alone the gradient still holds 12: the name sits on
-    the value the inverse's own rule reads, so the policy keeps it.  (On
-    the rule's output alone it kept a copy for ``_chunk_inputs`` and the
-    backward built the inverse again for its rule: 22.)"""
+    ``KEPT_INVERSE`` alone the gradient still calls the kernel once: the
+    name sits on the value the inverse's own rule reads, so the policy
+    keeps it.  (On the rule's output alone it kept a copy for
+    ``_chunk_inputs`` and the backward built the inverse again for its
+    rule.)"""
     args = _inputs(2 * rule.CHUNK, -0.1, dtype=jnp.bfloat16)
     wrapped = jax.checkpoint(
         gated_delta_rule_chunked,
@@ -133,7 +160,8 @@ def test_a_caller_that_rebuilds_its_mixer_keeps_the_inverse_by_name():
     def loss(*a):
         return jnp.sum(wrapped(*a).astype(jnp.float32))
     grad = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
-    assert _inverse_products(grad, args) == 12
+    assert _kernel_calls(grad, args) == 1
+    assert _inverse_products(grad, args) == 2
 
 
 def _rule_with_the_inverse_rebuilt(q, k, v, g, beta):

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from paddle_hackathon_tpu.incubate.nn.kernels import \
+    delta_rule_inverse as dri
 from paddle_hackathon_tpu.incubate.nn.kernels import flash_attention as fa
 from paddle_hackathon_tpu.incubate.nn.kernels import \
     flash_attention_packed as fap
@@ -30,7 +32,7 @@ from paddle_hackathon_tpu.incubate.nn.kernels import quant_matmul as qm
 @pytest.fixture(autouse=True)
 def _compiled_not_interpreted(monkeypatch):
     # each module binds the shared predicate by name
-    for mod in (fa, fap, pa, qm):
+    for mod in (dri, fa, fap, pa, qm):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -70,21 +72,25 @@ def test_packed_flash_fwd_and_bwd_lower(b, s, heads, d, causal, dropout):
 
 
 @pytest.fixture(scope="module")
-def one_described_chip():
-    """A v5e chip that is described, not attached: the TPU's own compiler
-    then says what the chip's would (layouts Mosaic refuses, scoped VMEM),
-    which the lowering above cannot.  Described inside a fixture, so that
-    every worker collects the same tests and only this file's loads the
-    TPU's library."""
+def described_v5e():
+    """Four v5e chips (2 x 2) that are described, not attached: the TPU's
+    own compiler then says what the chip's would (layouts Mosaic refuses,
+    scoped VMEM), which the lowering above cannot.  Described inside a
+    fixture, so that every worker collects the same tests and only this
+    file's loads the TPU's library."""
     import os
     pytest.importorskip("libtpu", reason="the TPU's compiler is not here")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     # with the library installed, any failure to describe the chip fails
     # the tests: a skip would hide the one guard this side of the chip
-    topo = topologies.get_topology_desc(platform="tpu",
-                                        topology_name="v5e:2x2")
-    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2").devices
+
+
+@pytest.fixture(scope="module")
+def one_described_chip(described_v5e):
+    return jax.sharding.SingleDeviceSharding(described_v5e[0])
 
 
 _DESCRIBED_V5E_SHAPES = [
@@ -158,6 +164,127 @@ def test_packed_flash_backward_compiles_for_a_described_v5e(
     for line in text.splitlines():
         if re.search(r" copy\(", line):
             assert re.search(r"= s32\[1\]", line), line   # the seed to SMEM
+
+
+@pytest.mark.parametrize("shape", [
+    (64, 2, 32, 64, 64),               # qwen3-next-80b-a3b.train-s4096's
+    (64, 1, 32, 64, 64),               # ling-3.0-flash.train-s4096's
+])
+def test_delta_rule_inverse_compiles_for_a_described_v5e(
+        one_described_chip, shape):
+    """The row of 128 matrices is a sublane-strided load and a transpose
+    in, a transpose and a strided store out, and the inverses' rows take
+    2 MB of VMEM beside the two buffers of each 4 MB block: questions for
+    Mosaic's compiler, which the lowering does not ask."""
+    a = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_described_chip)
+    text = dri._inverse.lower(a, interpret=False).compile().as_text()
+    from paddle_hackathon_tpu.observability.programs import mosaic_kernels
+    assert mosaic_kernels(text) == {"delta_rule_inverse": 1}
+
+
+@pytest.mark.parametrize("axes,kernels", [
+    ((("dp", 2),), 1),                 # the hybrids' data-parallel replicas
+    ((("dp", 2), ("mp", 2)), 1),       # batch and heads both split
+    ((("dp", 4),), 0),                 # 4 does not divide the batch of 2
+    ((("dp", 2), ("sp", 2)), 0),       # an axis kernels/mesh.py leaves alone
+])
+def test_the_rule_compiles_on_a_described_multi_chip_v5e(
+        described_v5e, axes, kernels):
+    """jax will not partition a Mosaic call: on a mesh that splits the
+    batch (the trainers' dp replicas) or the heads the inverse runs on each
+    chip's own matrices, one kernel a shard; a mesh ``kernels/mesh.py``
+    does not cover takes XLA's triangular solve.  The whole rule, forward
+    and backward, compiled for described chips."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_hackathon_tpu.incubate.nn.functional.gated_delta_rule \
+        import gated_delta_rule_chunked
+    from paddle_hackathon_tpu.observability.programs import mosaic_kernels
+    names, sizes = zip(*axes)
+    mesh = Mesh(np.asarray(described_v5e[:math.prod(sizes)]).reshape(sizes),
+                names)
+    b, s, h, d = 2, 128, 32, 128
+    split = P("dp") if b % dict(axes)["dp"] == 0 else P()
+
+    def aval(*tail, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((b, s) + tail, dtype,
+                                    sharding=NamedSharding(mesh, split))
+
+    def loss(*a):
+        return jnp.sum(gated_delta_rule_chunked(*a).astype(jnp.float32))
+    args = (aval(h, d), aval(h, d), aval(h, d),
+            aval(h, dtype=jnp.float32), aval(h, dtype=jnp.float32))
+    with jax.set_mesh(mesh):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *args).compile().as_text()
+    assert mosaic_kernels(text).get("delta_rule_inverse", 0) == kernels
+
+
+def _described_train_step(chip, workload, **config):
+    """The compiled text of a rehearsal cell's train step, its
+    configuration's keys overridden by ``config``, built ahead of time for
+    ``chip`` as ``benchmark/rehearse.py`` builds a cell's: the program
+    places its parameters with ``jax.device_put``, which a described
+    device cannot hold (stood in for by the shape), and the kernels ask
+    ``jax.default_backend()`` whether to interpret themselves.  The
+    builder makes its mesh of the described chip the process's current
+    one, which a later test of this worker would place arrays on: the
+    mesh that was current is put back."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from benchmark import run as harness
+    from benchmark.drivers import train_steps
+    from paddle_hackathon_tpu import parallel
+    wl = harness.load_json("workloads", workload)
+    cfg = dict(harness.load_json("configs", wl["config"]), **config)
+    cell = harness.Cell(wl, cfg, seed=0)
+    ref = harness.config_module(cfg, "reference", "reference")
+    dtype = jnp.dtype(cfg["training"]["param_dtype"])
+    params = {k: jnp.zeros(shape, dtype)
+              for k, shape in ref.param_spec(cfg).items()}
+    put, devices, backend = jax.device_put, jax.devices, jax.default_backend
+    current = parallel.get_mesh()
+    jax.device_put = lambda x, device=None, **kw: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=device)
+    jax.devices = lambda *a, **k: list(chip.device_set)
+    try:
+        step, state, _ = train_steps.build_program(cell, params)
+        jax.default_backend = lambda: "tpu"
+        mesh = jax.tree.leaves(state["params"])[0].sharding.mesh
+        traffic = wl["traffic"]
+        tokens = jax.ShapeDtypeStruct(
+            (traffic["batch"], traffic["seqlen"]), jnp.int32,
+            sharding=NamedSharding(mesh, P("dp")))
+        with jax.set_mesh(mesh):
+            lowered = step._jitted.lower(
+                state["params"], state["opt_state"], state["step"],
+                (tokens, tokens), jax.eval_shape(lambda: jax.random.key(0)),
+                jax.ShapeDtypeStruct((), jnp.float32))
+    finally:
+        jax.device_put, jax.devices = put, devices
+        jax.default_backend = backend
+        parallel.set_mesh(current)
+    return lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("workload,config,calls", [
+    # 3 DeltaNet layers and 1 of attention, as qwen3-next-80b-a3b's period
+    ("qwen3-next-tiny-rehearsal.train-s64", {}, 3),
+    # ling-3.0-flash's 7: KDA + dense MLP, 4 x KDA + experts, MLA, KDA
+    ("ling3-tiny-rehearsal.train-s64",
+     {"num_hidden_layers": 7, "layer_group_size": 6}, 6),
+])
+def test_a_hybrid_train_step_calls_the_inverse_once_a_layer(
+        one_described_chip, workload, config, calls):
+    """The kernel census of the AOT-built training step (what
+    ``analysis["mosaic_kernels"]`` reads on the chip) counts the inverse
+    once a delta-rule layer: built in the forward, kept, and not rebuilt
+    in the backward's recomputation of a mixer (``ling-3.0-flash``'s
+    layers rebuild theirs), at the two cells' layer stacks; the widths
+    are the rehearsal's."""
+    from paddle_hackathon_tpu.observability.programs import mosaic_kernels
+    text = _described_train_step(one_described_chip, workload, **config)
+    assert mosaic_kernels(text).get("delta_rule_inverse") == calls
 
 
 @pytest.mark.parametrize("b,s,heads,d", [
