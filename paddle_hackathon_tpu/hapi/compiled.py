@@ -35,8 +35,8 @@ from ..core.tensor import Tensor
 from ..nn.layer import functional_call
 from ..observability import metrics as _obs
 from ..observability.sanitizers import sanitize_donation
-from ..parallel.api import _collect_moe_aux, make_functional_train_step
-from ..parallel.moe import moe_aux_weight
+from ..parallel.api import make_functional_train_step
+from ..parallel.moe import collect_moe_aux
 
 
 def has_moe_layers(network) -> bool:
@@ -208,7 +208,6 @@ class CompiledTrainer:
         # already pays for the loss fetch
         self._has_moe = has_moe_layers(network)
         self.last_aux = None
-        aux_w = moe_aux_weight(network) if self._has_moe else 0.0
 
         def forward_loss(p, xs, ys, step):
             rng = jax.random.fold_in(jax.random.PRNGKey(seed), step)
@@ -226,14 +225,16 @@ class CompiledTrainer:
             total = total.astype(jnp.float32)
             if not self._has_moe:
                 return total
-            # the forward just traced left each MoELayer's aux on the
-            # layer (the _collect_moe_aux side-channel contract the
-            # sharded train step already uses)
-            aux = _collect_moe_aux(network)
+            # the forward just traced left each layer's aux on the layer
+            # (the collect_moe_aux side-channel contract the sharded
+            # train step already uses): the loss takes them weighted,
+            # the ride-along reports the MoE balance unweighted
+            aux = collect_moe_aux(network)
             if aux is None:
-                aux = jnp.zeros((), jnp.float32)
-            aux = aux.astype(jnp.float32)
-            return total + aux_w * aux, aux
+                zero = jnp.zeros((), jnp.float32)
+                return total + zero, zero
+            raw = collect_moe_aux(network, weight=1.0)
+            return total + aux.astype(jnp.float32), raw.astype(jnp.float32)
 
         if self._has_moe:
             def grads_of(p, xs, ys, step):

@@ -64,11 +64,10 @@ class Model:
             total = total + l
         aux = self._moe_aux_tensor()
         if aux is not None:
-            # MoE load-balance aux (same term the compiled path threads
-            # into its donated program — the eager tape must train the
-            # router too, not just the experts)
-            from ..parallel.moe import moe_aux_weight
-            total = total + moe_aux_weight(self.network) * aux
+            # the layers' aux terms, weighted (same term the compiled path
+            # threads into its donated program — the eager tape must
+            # train the router too, not just the experts)
+            total = total + aux
         total.backward()
         if aux is not None:
             # report the OPTIMIZED objective as the headline loss so the
@@ -85,12 +84,15 @@ class Model:
             # observe AFTER the out_loss fetch above already synced the
             # device pipeline — a pre-backward fetch would stall the
             # step on the forward's completion just to feed telemetry
-            self._observe_moe_aux(float(aux.numpy()), "hapi_eager")
+            from ..parallel.moe import collect_moe_aux
+            self._observe_moe_aux(
+                float(collect_moe_aux(self.network, weight=1.0)),
+                "hapi_eager")
         return (out_loss, metrics) if metrics else out_loss
 
     def _moe_aux_tensor(self):
-        """Sum of the MoE load-balance aux Tensors the eager forward just
-        left on the network's MoELayers, still ON the tape so
+        """The aux Tensors the eager forward just left on the network's
+        layers, weighted as the loss takes them and still ON the tape so
         ``backward`` trains the router; None when the network has no
         (traced-this-forward) aux.  Delegates to the single owner of the
         ``l_aux`` walk (``parallel.moe.collect_moe_aux``)."""

@@ -12,3 +12,5 @@ from .qwen3_next import (Qwen3NextConfig, Qwen3NextForCausalLM,  # noqa: F401
 from .bailing_hybrid import (BailingHybridConfig,  # noqa: F401
                              BailingHybridForCausalLM,
                              bailing_hybrid_sharding_spec)
+from .keye_vl2 import (KeyeVL2Config, KeyeVL2ForCausalLM,  # noqa: F401
+                       keye_vl2_sharding_spec)

@@ -1017,6 +1017,7 @@ class GPTForCausalLM(Layer):
         from ..core import random as core_random
         from ..nn.layer import functional_call
         from ..nn.functional.loss import fused_softmax_ce_rows
+        from ..parallel.moe import collect_moe_aux
 
         moe = self.config.moe_num_experts > 0
         if moe and max(1, int(self.config.moe_every_n)) != 1:
@@ -1049,11 +1050,12 @@ class GPTForCausalLM(Layer):
             if not moe:
                 return h
             # MoE: the load-balance aux the forward just left on the
-            # layer is consumed INSIDE the stage scan (pipeline_apply
-            # accumulates it across layers/microbatches — the side
-            # channel _collect_moe_aux reads cannot escape a lax.scan)
-            aux = template.mlp.l_aux
-            aux = aux._value if isinstance(aux, Tensor) else aux
+            # layer, weighted as the loss takes it, is consumed INSIDE the
+            # stage scan (pipeline_apply accumulates it across
+            # layers/microbatches — the side channel collect_moe_aux
+            # reads cannot escape a lax.scan)
+            aux = collect_moe_aux(template.mlp,
+                                  weight=self.config.moe_aux_weight)
             if aux is None:
                 aux = jnp.zeros((), jnp.float32)
             return h, aux
@@ -1069,8 +1071,7 @@ class GPTForCausalLM(Layer):
         return {"block_prefix": "gpt.blocks.",
                 "num_layers": self.config.num_layers,
                 "pre_fn": pre_fn, "layer_fn": layer_fn, "post_fn": post_fn,
-                "layer_aux": moe,
-                "aux_weight": self.config.moe_aux_weight}
+                "layer_aux": moe}
 
 
 def param_sharding_spec(name: str, shape) -> tuple:

@@ -327,7 +327,8 @@ def mosaic_kernels(hlo_text: str) -> Dict[str, int]:
 
 
 # The train step's scopes (``parallel/api.py``, ``models/gpt.py``,
-# ``models/qwen3_next.py``, ``models/bailing_hybrid.py``): the names a
+# ``models/qwen3_next.py``, ``models/bailing_hybrid.py``,
+# ``models/keye_vl2.py``): the names a
 # component can take in the phase census.  A scope of PHASE_SUBCOMPONENTS
 # names a part of the component it is nested in (``gdn_rule`` inside
 # ``gdn``, ``experts`` inside ``moe``): an instruction under both reads
@@ -340,10 +341,11 @@ def mosaic_kernels(hlo_text: str) -> Dict[str, int]:
 # residual outside a part) stand last and take nothing from the parts that
 # were there.
 PHASE_COMPONENTS = ("embed", "attn", "mlp", "ln_f", "lm_head", "ce",
-                    "clip", "update", "gdn", "moe", "kda", "mla")
+                    "clip", "update", "gdn", "moe", "kda", "mla", "dsa")
 PHASE_SUBCOMPONENTS = ("gdn_conv", "gdn_rule", "router", "experts",
                        "shared_expert", "kda_conv", "kda_rule",
-                       "gdn_proj", "gdn_gates", "kda_proj", "kda_gates")
+                       "gdn_proj", "gdn_gates", "kda_proj", "kda_gates",
+                       "dsa_index", "dsa_select", "dsa_attn", "dsa_kl")
 PHASES = ("fwd", "bwd", "clip", "update", "other")
 
 _COMPUTATION_RE = re.compile(r'^(?:ENTRY )?%?([^\s(]+) \(.*\{$')

@@ -26,6 +26,7 @@ from ..core.tensor import Tensor
 from ..nn.layer import Layer
 from ..observability import metrics as _obs
 from ..observability import tracing as _tracing
+from .layer_outputs import collect_layer_counters
 
 _current_mesh: Optional[Mesh] = None
 
@@ -161,16 +162,6 @@ def page_pool_sharding(mesh: Mesh):
     from jax.sharding import NamedSharding
     hax = "mp" if mesh.shape.get("mp", 1) > 1 else None
     return NamedSharding(mesh, P(None, None, hax, None))
-
-
-def _collect_moe_aux(model):
-    """Sum of the trace-fresh MoE load-balance aux values left on
-    MoELayer instances by the forward just run (None when no MoE).
-    Kept under its historical name; the walk itself lives in
-    ``parallel.moe.collect_moe_aux`` (single owner — the eager
-    ``train_batch`` shares it with ``tensors=True``)."""
-    from .moe import collect_moe_aux
-    return collect_moe_aux(model)
 
 
 def stack_block_params(model, mesh: Mesh, rule, block_prefix: str,
@@ -322,10 +313,10 @@ def _make_pipeline_loss(mesh: Mesh, pp_spec: dict, pp_degree: int,
         loss = post_fn(params, y, labels)
         if use_aux:
             # aux is computed per microbatch (the reference's gradient-
-            # accumulation semantics); mean over microbatches matches the
+            # accumulation semantics), weighted by the layer_fn as the
+            # loss takes it; mean over microbatches matches the
             # full-batch estimator in expectation
-            loss = loss + (float(pp_spec.get("aux_weight", 0.01))
-                           * aux_total / n_micro)
+            loss = loss + aux_total / n_micro
         return loss
 
     return loss_fn
@@ -673,12 +664,14 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
             lg = logits._value if isinstance(logits, Tensor) else logits
             with jax.named_scope("ce"):
                 loss = jnp.mean(fused_softmax_ce_rows(lg, labels))
-            # MoE load-balance aux (ref moe/grad_clip.py context + GShard):
-            # MoELayer.forward left this trace's aux value on the layer
-            aux = _collect_moe_aux(model)
+            # the layers' auxiliary losses (the capacity MoE's load
+            # balance, ref moe/grad_clip.py context + GShard; the sparse
+            # attention's indexer KL): each forward left this trace's value
+            # on its layer
+            from .moe import collect_moe_aux
+            aux = collect_moe_aux(model)
             if aux is not None:
-                from .moe import moe_aux_weight
-                loss = loss + moe_aux_weight(model) * aux
+                loss = loss + aux
             return loss
 
     opt = _resolve_optimizer(optimizer, optimizer_kwargs, learning_rate,
@@ -744,11 +737,10 @@ def make_sharded_train_step(model: Layer, mesh: Mesh,
                              NamedSharding(mesh, P()))
 
     # the default loss traces the model's forward in this frame, so what
-    # its expert layers left on themselves (their routing counters) can
-    # leave the program beside the loss; a custom or pipelined loss owns
-    # its forward, and what it traced inside may not escape it
-    from .moe import collect_router_counters
-    counters_of = collect_router_counters if default_loss else \
+    # its layers left on themselves (their counters) can leave the program
+    # beside the loss; a custom or pipelined loss owns its forward, and
+    # what it traced inside may not escape it
+    counters_of = collect_layer_counters if default_loss else \
         (lambda _model: {})
 
     def loss_of(batch, rng):

@@ -365,7 +365,7 @@ class MoELayer(Layer):
 # Dropless top-k experts, told which experts this chip holds
 # ---------------------------------------------------------------------------
 
-# what a DroplessMoELayer's forward leaves on ``router_counters``, in order
+# what a DroplessMoELayer's forward leaves on ``layer_counters``, in order
 ROUTER_COUNTERS = ("rows_routed_here", "row_bound", "rows_largest_expert",
                    "rows_mean_expert")
 
@@ -395,6 +395,24 @@ def _row_slice(n, k, count, num_experts):
     return min(-(-2 * even // 512) * 512, n * min(k, count))
 
 
+def _every_token(n, k, count, num_experts):
+    """Whether the held experts run on every token under its gate rather
+    than on the sorted rows.  The sorted rows take between one slice
+    and ``slices`` of :func:`_row_slice`, as many as the routing fills;
+    every token through every held expert is a plain product, no gather,
+    mask or scatter, a row of it a third to a quarter of a sorted row's
+    cost (forward and backward at 16,384 tokens, 2,048 wide, 16 of 128
+    experts held on a v5e: 53.6 ms every token, 20.1-26.3 ms one slice
+    of 32,768 sorted rows as the routed rows fill it, 103 ms every slice
+    with every row in a group).  So where the routing
+    can fill more than one slice and every token is at most eight
+    slices' rows -- about what two slices cost -- every token it is:
+    the layer's time stops following the routing, at about twice a
+    balanced router's.  Otherwise the sorted rows."""
+    step = _row_slice(n, k, count, num_experts)
+    return n * min(k, count) > step and count * n <= 8 * step
+
+
 def held_expert_outputs(tokens, gate_vals, idx, w_gate_up, w_down, first,
                         num_experts):
     """What the experts ``[first, first + count)`` of ``num_experts`` add
@@ -420,9 +438,17 @@ def held_expert_outputs(tokens, gate_vals, idx, w_gate_up, w_down, first,
     Rows past the last group belong to no expert: the grouped kernels do
     not write them (forward or transposed), so each product's input and
     output is masked to the rows routed -- the mask's transpose keeps
-    what the transposed kernels left there out of the gradients."""
+    what the transposed kernels left there out of the gradients.
+
+    Where the rows routed here can fill more than one slice and every
+    token through every held expert is at most eight slices' rows
+    (:func:`_every_token`), the part is laid out the other way
+    (:func:`_every_token_outputs`): the same work for any routing."""
     n, d = tokens.shape
     k, count = idx.shape[1], w_gate_up.shape[0]
+    if _every_token(n, k, count, num_experts):
+        return _every_token_outputs(tokens, gate_vals, idx, w_gate_up,
+                                    w_down, first)
     width = w_down.shape[1]
     local = idx.reshape(-1) - first
     here = (local >= 0) & (local < count)
@@ -487,8 +513,41 @@ def held_expert_outputs(tokens, gate_vals, idx, w_gate_up, w_down, first,
     return combined, jax.lax.stop_gradient(counters)
 
 
+def _every_token_outputs(tokens, gate_vals, idx, w_gate_up, w_down, first):
+    """The held experts' part laid out the other way: every held expert
+    on every token, weighted by the token's gate for it (0 where the token
+    did not choose it), one expert at a time under a ``lax.scan``.  The
+    counters are :data:`ROUTER_COUNTERS`, the rows spanned being every
+    token for every held expert."""
+    n = tokens.shape[0]
+    count, width = w_down.shape[0], w_down.shape[1]
+    precision = _narrow(tokens.dtype)
+    numbers = first + jnp.arange(count)
+
+    @jax.checkpoint
+    def one(total, expert):
+        w_gu, w_d, number = expert
+        weight = jnp.sum(jnp.where(idx == number, gate_vals, 0.0), -1)
+        hidden = jnp.matmul(tokens, w_gu, precision=precision)
+        act = jax.nn.silu(hidden[:, :width]) * hidden[:, width:]
+        out = jnp.matmul(act, w_d, precision=precision)
+        return total + out.astype(jnp.float32) * weight[:, None], None
+
+    combined, _ = jax.lax.scan(one, jnp.zeros(tokens.shape, jnp.float32),
+                               (w_gate_up, w_down, numbers))
+    per_expert = jnp.sum(idx[..., None] == numbers, (0, 1))
+    routed = jnp.sum(per_expert)
+    counters = jnp.stack([
+        routed.astype(jnp.float32), jnp.float32(n * count),
+        jnp.max(per_expert).astype(jnp.float32),
+        routed.astype(jnp.float32) / count])
+    return combined.astype(tokens.dtype), jax.lax.stop_gradient(counters)
+
+
 class DroplessMoELayer(Layer):
-    """Top-k mixture of SwiGLU experts beside one shared expert, for a chip
+    """Top-k mixture of SwiGLU experts beside one shared expert (none where
+    ``shared_hidden`` is 0: no ``shared_*`` leaves, no ``shared_expert``
+    scope), for a chip
     that holds ``experts_held = (first, count)`` of the ``num_experts``
     the router chooses among (expert parallelism's layer on one chip: the
     router keeps its full width, the chip computes its own experts' part
@@ -517,11 +576,11 @@ class DroplessMoELayer(Layer):
     gets no gradient from this layer; the exchange, when it comes
     (ROADMAP B11), brings the other returns and the gradient with them.
 
-    After each forward ``router_counters`` holds :data:`ROUTER_COUNTERS`
+    After each forward ``layer_counters`` holds :data:`ROUTER_COUNTERS`
     as a float32 vector (the rows the grouped products were routed, the
     rows of the slices they ran, the busiest held expert's rows and the
-    mean): :func:`collect_router_counters` hands them to the train step,
-    which returns them with the loss."""
+    mean): ``parallel/layer_outputs.py collect_layer_counters`` hands them
+    to the train step, which returns them with the loss."""
 
     def __init__(self, d_model: int, d_hidden: int, num_experts: int,
                  topk: int, experts_held, shared_hidden: int,
@@ -546,7 +605,7 @@ class DroplessMoELayer(Layer):
         self.router_form = dict(
             score_function=score_function, n_group=n_group,
             topk_group=topk_group, scaling=float(routed_scaling_factor))
-        self.shared_gated, self.selection_bias = shared_gated, selection_bias
+        self.selection_bias = selection_bias
         init = ParamAttr(initializer=I.Normal(0.0, 0.02))
         self.router = Linear(d_model, num_experts, weight_attr=init,
                              bias_attr=False)
@@ -558,17 +617,19 @@ class DroplessMoELayer(Layer):
             p.pspec = ("ep", None, None)
             p.is_distributed = True
         self.shared_hidden = shared_hidden
-        self.shared_gate_up = Linear(d_model, 2 * shared_hidden,
-                                     weight_attr=init, bias_attr=False)
-        self.shared_down = Linear(shared_hidden, d_model,
-                                  weight_attr=init, bias_attr=False)
-        if shared_gated:
+        if shared_hidden:
+            self.shared_gate_up = Linear(d_model, 2 * shared_hidden,
+                                         weight_attr=init, bias_attr=False)
+            self.shared_down = Linear(shared_hidden, d_model,
+                                      weight_attr=init, bias_attr=False)
+        self.shared_gated = bool(shared_hidden) and shared_gated
+        if self.shared_gated:
             self.shared_gate = Linear(d_model, 1, weight_attr=init,
                                       bias_attr=False)
         if selection_bias:
             self.router_bias = self.create_parameter(
                 [num_experts], default_initializer=I.Constant(0.0))
-        self.router_counters = None
+        self.layer_counters = None
 
     def forward(self, x):
         xt = x if isinstance(x, Tensor) else Tensor(x)
@@ -578,12 +639,16 @@ class DroplessMoELayer(Layer):
         num_experts = self.num_experts
         form = self.router_form
         gated, biased = self.shared_gated, self.selection_bias
-        # the leaves a router's form may add, in the order moe_fn reads them
-        optional = ([self.shared_gate.weight] if gated else []) \
+        # the leaves the shared expert and a router's form may add, in the
+        # order moe_fn reads them
+        optional = ([self.shared_gate_up.weight, self.shared_down.weight]
+                    if shared else []) \
+            + ([self.shared_gate.weight] if gated else []) \
             + ([self.router_bias] if biased else [])
 
-        def moe_fn(x_in, router_w, w_gate_up, w_down, gate_up, down, *rest):
-            gate = rest[0] if gated else None
+        def moe_fn(x_in, router_w, w_gate_up, w_down, *rest):
+            gate_up, down = rest[:2] if shared else (None, None)
+            gate = rest[2] if gated else None
             bias = rest[-1] if biased else None
             tokens = x_in.reshape(-1, shape[-1])
             with jax.named_scope("router"):
@@ -599,6 +664,8 @@ class DroplessMoELayer(Layer):
                 out, counters = held_expert_outputs(
                     tokens, gate_vals, idx, w_gate_up, w_down, first,
                     num_experts)
+            if not shared:
+                return out.reshape(shape), counters
             with jax.named_scope("shared_expert"):
                 h = tokens @ gate_up
                 y = (jax.nn.silu(h[:, :shared]) * h[:, shared:]) @ down
@@ -615,25 +682,9 @@ class DroplessMoELayer(Layer):
 
         y, counters = apply_op("dropless_moe_layer", moe_fn, [
             xt, self.router.weight, self.experts_gate_up, self.experts_down,
-            self.shared_gate_up.weight, self.shared_down.weight, *optional],
-            n_outputs=2)
-        self.router_counters = counters
+            *optional], n_outputs=2)
+        self.layer_counters = counters
         return y
-
-
-def collect_router_counters(model) -> dict:
-    """``{layer path: float32 vector of ROUTER_COUNTERS}`` over every layer
-    whose forward, just traced, left ``router_counters`` (the
-    ``collect_router_stats`` pattern); ``{}`` where none did.  Raw jax
-    values: the train step returns them as program outputs beside the
-    loss, and the program observatory keeps the newest
-    (``ProgramRegistry.note_counters``)."""
-    out = {}
-    for name, layer in model.named_sublayers(include_self=True):
-        c = getattr(layer, "router_counters", None)
-        if c is not None:
-            out[name] = c._value if isinstance(c, Tensor) else c
-    return out
 
 
 def moe_all_to_all(x, mesh, axis: str = "ep", split_axis: int = 0,
@@ -699,7 +750,7 @@ def collect_router_stats(model):
     """Layer-averaged PER-TOKEN router stats — ``(entropy (n,),
     kept-slot counts (n, E))`` — over every :class:`MoELayer` whose
     ``collect_router_stats`` flag armed the side channel in the forward
-    just traced (the ``_collect_moe_aux`` pattern); None when no layer
+    just traced (the ``collect_moe_aux`` pattern); None when no layer
     left stats.  Per token, not pre-reduced: a serving tick batch mixes
     live rows with inactive-slot scratch and prefill padding, and only
     the ENGINE knows which is which — it masks rows host-side before
@@ -733,14 +784,18 @@ def moe_aux_weight(model) -> float:
     return float(w)
 
 
-def collect_moe_aux(model, tensors: bool = False):
-    """Sum of the trace-fresh MoE load-balance aux values left on
-    MoELayer instances by the forward just run (None when none).
-    ``tensors=True`` keeps the eager autograd Tensors ON the tape (the
-    eager ``train_batch`` path must backprop through the aux term);
-    the default strips to raw jax values for traced/functional
-    consumers.  Single owner of the ``l_aux`` side-channel walk."""
-    total = None
+def collect_moe_aux(model, tensors: bool = False, weight=None):
+    """The auxiliary losses the forward just run left on ``model``'s
+    layers (``l_aux``), as the loss takes them: a layer with its own
+    ``aux_weight`` (the sparse attention's indexer KL, 1) adds its value
+    times that; the others (the MoE load balance) add their sum times
+    ``weight``, by default :func:`moe_aux_weight` of ``model``, applied
+    once.  None where no layer left one.  ``tensors=True`` keeps the
+    eager autograd Tensors ON the tape (the eager ``train_batch`` path
+    must backprop through the aux term); the default strips to raw jax
+    values for traced/functional consumers.  Single owner of the
+    ``l_aux`` side-channel walk."""
+    shared, own = None, None
     for layer in model.sublayers(include_self=True):
         aux = getattr(layer, "l_aux", None)
         if aux is None:
@@ -749,5 +804,12 @@ def collect_moe_aux(model, tensors: bool = False):
             v = aux if isinstance(aux, Tensor) else Tensor(aux)
         else:
             v = aux._value if isinstance(aux, Tensor) else aux
-        total = v if total is None else total + v
-    return total
+        w = getattr(layer, "aux_weight", None)
+        if w is None:
+            shared = v if shared is None else shared + v
+        else:
+            own = w * v if own is None else own + w * v
+    if shared is None:
+        return own
+    shared = (moe_aux_weight(model) if weight is None else weight) * shared
+    return shared if own is None else shared + own

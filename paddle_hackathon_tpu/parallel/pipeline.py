@@ -416,14 +416,14 @@ class PipelineLayer(Layer):
         from ..core import random as core_random
 
         def loss_fn(model, params, buffers, batch, rng):
-            from .api import _collect_moe_aux
+            from .moe import collect_moe_aux
             ids, labels = batch
             with core_random.rng_scope(rng):
                 y = _apply_positions(positions, params, buffers, ids)
             loss = user_loss(y, labels)
-            aux = _collect_moe_aux(model)
+            aux = collect_moe_aux(model, weight=aux_w)
             if aux is not None:
-                loss = loss + aux_w * aux
+                loss = loss + aux
             return loss
 
         return loss_fn
@@ -452,9 +452,11 @@ class PipelineLayer(Layer):
                                         buffers or captured_buffers, ids)
 
         # blocks carrying an l_aux side channel (MoE layers) feed the
-        # pipeline's aux accumulator — the channel cannot escape the
-        # stage scan by itself (same mechanism as models/gpt.py)
-        from .api import _collect_moe_aux
+        # pipeline's aux accumulator, weighted as the loss takes it — the
+        # channel cannot escape the stage scan by itself (same mechanism
+        # as models/gpt.py)
+        from .moe import collect_moe_aux
+        aux_w = self._aux_weight
         has_aux = any(hasattr(m, "l_aux")
                       for m in template.sublayers(include_self=True))
 
@@ -462,7 +464,7 @@ class PipelineLayer(Layer):
             h = functional_call(template, layer_params, (Tensor(x),))
             if not has_aux:
                 return h
-            aux = _collect_moe_aux(template)
+            aux = collect_moe_aux(template, weight=aux_w)
             if aux is None:
                 aux = jnp.zeros((), jnp.float32)
             return h, aux.astype(jnp.float32)
@@ -476,22 +478,20 @@ class PipelineLayer(Layer):
             if any(id(mod) == id(m) for m in outer_mods):
                 continue
             outer_mods.append(mod)
-        aux_w = self._aux_weight
 
         def post_fn(params, x, labels):
             y = _apply_positions(post_pos, params, captured_buffers, x)
             loss = user_loss(y, labels)
             for mod in outer_mods:
-                aux = _collect_moe_aux(mod)
+                aux = collect_moe_aux(mod, weight=aux_w)
                 if aux is not None:
-                    loss = loss + aux_w * aux
+                    loss = loss + aux
             return loss
 
         return {"block_prefix": "blocks.",
                 "num_layers": len(self.blocks),
                 "pre_fn": pre_fn, "layer_fn": layer_fn, "post_fn": post_fn,
-                "layer_aux": has_aux,
-                "aux_weight": self._aux_weight}
+                "layer_aux": has_aux}
 
 
 class PipelineParallel:

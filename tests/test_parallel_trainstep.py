@@ -354,7 +354,7 @@ def test_gpt_generate():
 def test_moe_pipeline_matches_ep_only():
     """pp x ep: MoE blocks pipeline — the per-layer load-balance aux is
     accumulated INSIDE the stage scan (pipeline_apply with_aux; the side
-    channel _collect_moe_aux reads cannot escape lax.scan) with
+    channel collect_moe_aux reads cannot escape lax.scan) with
     per-microbatch semantics (the reference's gradient-accumulation
     behavior). Trajectory matches the ep-only composition."""
     cfg = _tiny(moe_num_experts=4, moe_gate="naive")

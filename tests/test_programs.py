@@ -772,9 +772,10 @@ def test_phase_census_of_a_compiled_toy_train_step(toy_train_step):
     census = get_program_registry().phase_census(toy_train_step["site"])
     phases = {p for p, _, _ in census.values()}
     assert {"fwd", "bwd", "clip", "update"} <= phases
-    # a GPT's step has GPT's components, not the hybrid stacks' four
+    # a GPT's step has GPT's components, not the other stacks' five
     assert {c for _, c, _ in census.values()} - {""} \
-        == set(programs.PHASE_COMPONENTS) - {"gdn", "moe", "kda", "mla"}
+        == set(programs.PHASE_COMPONENTS) - {"gdn", "moe", "kda", "mla",
+                                             "dsa"}
     # forward and backward of every scope of the model are told apart
     for c in ("embed", "attn", "mlp", "ln_f", "lm_head", "ce"):
         assert {("fwd", c), ("bwd", c)} <= {(p, k) for p, k, _

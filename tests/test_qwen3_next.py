@@ -166,7 +166,7 @@ def test_dropless_when_every_token_chooses_one_held_expert():
     cut = dict(cut, **{"router.weight": layer.router.weight._value})
     assert float(jnp.abs(got - ref.experts(cut, x, sizes)).max()) < 1e-5
     counters = dict(zip(moe.ROUTER_COUNTERS,
-                        np.asarray(layer.router_counters._value)))
+                        np.asarray(layer.layer_counters._value)))
     # all 100 tokens' pairs for expert 11 were computed: none dropped
     assert counters["rows_routed_here"] == 100
     assert counters["rows_largest_expert"] == 100
@@ -174,13 +174,11 @@ def test_dropless_when_every_token_chooses_one_held_expert():
     assert counters["rows_mean_expert"] == 100 / 8
 
 
-def test_more_rows_than_the_usual_slice_take_more_slices_and_stay_exact():
-    """2,000 tokens, top-4 of 64 with 8 held: an even router sends 1,000
-    rows here, a slice is 2,048 (twice that, in whole tiles), the worst
-    case four slices.  Every token forced onto two held experts: 4,000
-    rows, two slices run, the second not full, and value and gradients
-    are the reference's."""
-    layer, _, cut, sizes = _expert_layer(8, 8, num=64)
+def _forced_rows_against_the_reference(num):
+    """2,000 tokens, top-4 of ``num`` with 8 held, every token forced onto
+    two held experts (4,000 rows here): the layer's value and gradients
+    against the reference's, and its counters."""
+    layer, _, cut, sizes = _expert_layer(8, 8, num=num)
     _forced(layer, (9, 14, 40, 50))
     x = jnp.abs(jax.random.normal(jax.random.key(4), (2, 1000, 16))) + 0.1
     params = {k: p._value for k, p in layer.named_parameters()}
@@ -195,15 +193,35 @@ def test_more_rows_than_the_usual_slice_take_more_slices_and_stay_exact():
 
     got, (gp, gx) = jax.value_and_grad(program, argnums=(0, 1))(params, x)
     want, (wp, wx) = jax.value_and_grad(reference, argnums=(0, 1))(cut, x)
-    counters = dict(zip(moe.ROUTER_COUNTERS,
-                        np.asarray(layer.router_counters._value)))
-    assert counters["rows_routed_here"] == 4000
-    assert counters["row_bound"] == 2 * 2048
     assert abs(float(got) - float(want)) < 1e-3 * abs(float(want))
     for name in ("experts_gate_up", "experts_down", "shared_down.weight"):
         scale = float(jnp.abs(wp[name]).max())
         assert float(jnp.abs(gp[name] - wp[name]).max()) < 1e-4 * scale, name
     assert float(jnp.abs(gx - wx).max()) < 1e-4 * float(jnp.abs(wx).max())
+    return dict(zip(moe.ROUTER_COUNTERS,
+                    np.asarray(layer.layer_counters._value)))
+
+
+def test_more_rows_than_the_usual_slice_take_more_slices_and_stay_exact():
+    """Top-4 of 128 with 8 held: an even router sends 500 rows here, a
+    slice is 1,024 (twice that, in whole tiles), the worst case eight
+    slices.  The 4,000 rows take four slices, the last not full, and
+    value and gradients are the reference's."""
+    counters = _forced_rows_against_the_reference(128)
+    assert counters["rows_routed_here"] == 4000
+    assert counters["row_bound"] == 4 * 1024
+
+
+def test_every_token_where_it_costs_no_more_than_eight_slices_rows():
+    """Top-4 of 64 with 8 held: a slice is 2,048 rows, the worst case
+    four, and every token through every held expert 16,000 rows, under
+    eight slices': the held experts run on every token under its gate,
+    whatever the routing, and value and gradients are the reference's."""
+    assert moe._every_token(2000, 4, 8, 64)
+    assert not moe._every_token(2000, 4, 8, 128)
+    counters = _forced_rows_against_the_reference(64)
+    assert counters["rows_routed_here"] == 4000
+    assert counters["row_bound"] == 2000 * 8
 
 
 def test_a_step_with_no_token_routed_here_is_finite():
@@ -220,7 +238,7 @@ def test_a_step_with_no_token_routed_here_is_finite():
     for g in list(gp.values()) + [gx]:
         assert bool(jnp.isfinite(g).all())
     assert float(jnp.abs(gp["experts_down"]).max()) == 0.0
-    assert np.asarray(layer.router_counters._value)[0] == 0
+    assert np.asarray(layer.layer_counters._value)[0] == 0
 
 
 @pytest.mark.parametrize("first, count", [(0, 32), (8, 8)])

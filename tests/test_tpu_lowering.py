@@ -22,6 +22,7 @@ import pytest
 
 from paddle_hackathon_tpu.incubate.nn.kernels import \
     delta_rule_inverse as dri
+from paddle_hackathon_tpu.incubate.nn.kernels import dsa_attention as dsa
 from paddle_hackathon_tpu.incubate.nn.kernels import flash_attention as fa
 from paddle_hackathon_tpu.incubate.nn.kernels import \
     flash_attention_packed as fap
@@ -32,7 +33,7 @@ from paddle_hackathon_tpu.incubate.nn.kernels import quant_matmul as qm
 @pytest.fixture(autouse=True)
 def _compiled_not_interpreted(monkeypatch):
     # each module binds the shared predicate by name
-    for mod in (dri, fa, fap, pa, qm):
+    for mod in (dri, dsa, fa, fap, pa, qm):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -219,6 +220,56 @@ def test_the_rule_compiles_on_a_described_multi_chip_v5e(
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
             *args).compile().as_text()
     assert mosaic_kernels(text).get("delta_rule_inverse", 0) == kernels
+
+
+def _sparse_attention_grads(b, s):
+    """Forward and backward of the sparse attention at the Keye cell's
+    widths (32 / 4 heads x 128, an indexer of 16 x 64, top-k 2,048)."""
+    from paddle_hackathon_tpu.incubate.nn.functional.sparse_attention \
+        import sparse_attention
+
+    def loss(q, k, v, qi, ki, w):
+        o, kl, _ = sparse_attention(q, k, v, qi, ki, w, heads=32, topk=2048,
+                                    scale=128 ** -0.5)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.mean(kl)
+
+    shapes = [((b, s, 32 * 128), jnp.bfloat16), ((b, s, 4 * 128),
+              jnp.bfloat16), ((b, s, 4 * 128), jnp.bfloat16),
+              ((b, 16, s, 64), jnp.bfloat16), ((b, s, 64), jnp.bfloat16),
+              ((b, s, 16), jnp.float32)]
+    return jax.jit(jax.grad(loss, argnums=tuple(range(6)))), shapes
+
+
+def test_the_sparse_attention_kernels_compile_for_a_described_v5e(
+        one_described_chip):
+    """The seven kernels at the cell's own sequence, 16,384: the selection's
+    words unpacked by a shift a bit plane, tiles skipped above the
+    diagonal, 32 heads a cell in the KL kernels with 96 MB of scoped
+    VMEM, a row's threshold counted bit by bit.  Each is built once a
+    layer, the indexer's and the threshold's inside the scan over blocks
+    of queries."""
+    from paddle_hackathon_tpu.observability.programs import mosaic_kernels
+    fn, shapes = _sparse_attention_grads(1, 16384)
+    text = fn.lower(*(jax.ShapeDtypeStruct(s, d, sharding=one_described_chip)
+                      for s, d in shapes)).compile().as_text()
+    assert mosaic_kernels(text) == {name: 1 for name in dsa.kernel_names()}
+
+
+def test_the_sparse_attention_compiles_on_a_described_dp_mesh(
+        described_v5e):
+    """On a mesh that splits the batch every kernel runs on each chip's
+    own rows under ``shard_map`` (jax will not partition a Mosaic
+    call)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_hackathon_tpu.observability.programs import mosaic_kernels
+    mesh = Mesh(np.asarray(described_v5e[:2]), ("dp",))
+    fn, shapes = _sparse_attention_grads(2, 1024)
+    with jax.set_mesh(mesh):
+        text = fn.lower(*(jax.ShapeDtypeStruct(
+            s, d, sharding=NamedSharding(mesh, P("dp")))
+            for s, d in shapes)).compile().as_text()
+    assert mosaic_kernels(text) == {name: 1 for name in dsa.kernel_names()}
 
 
 def _described_train_step(chip, workload, **config):
