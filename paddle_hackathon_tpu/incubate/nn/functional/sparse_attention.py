@@ -60,7 +60,7 @@ def select_keys(qi, w, ki, topk: int):
                 row0, jax.lax.dynamic_slice_in_dim(qi, row0, block, 2),
                 jax.lax.dynamic_slice_in_dim(w, row0, block, 1), ki)
         with jax.named_scope("dsa_select"):
-            thr, cut, lse_i = K.select_threshold(scores, take)
+            thr, cut, lse_i = K.select_threshold(scores, take, row0)
             key = K.order_keys(scores)
             rows = row0 + jnp.arange(block, dtype=jnp.int32)[:, None]
             cols = jnp.arange(s, dtype=jnp.int32)[None, :]

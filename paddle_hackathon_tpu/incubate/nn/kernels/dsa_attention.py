@@ -40,7 +40,11 @@ census call them):
   selection reduces.
 - ``dsa_select``: each row's ``k``-th largest score of such a block, found
   bit by bit in VMEM (a count a bit, no sort), the column of its last
-  kept tie, and the log-sum-exp of what the row keeps.
+  kept tie, and the log-sum-exp of what the row keeps.  Told the block's
+  first query, each group of ``SELECT_ROWS`` rows reads only the columns
+  up to its last row's position (whole ``SELECT_CHUNK``-column chunks):
+  past a row's position its scores are ``-inf``, as ``dsa_index`` writes
+  them, so the kept set and the log-sum-exp are those of every column.
 - ``dsa_attn_fwd``: softmax attention of each query head over its query's
   selected keys; the output and each row's log-sum-exp.
 - ``dsa_attn_bwd_dkdv``, ``dsa_attn_bwd_dq``: its backward, flash style,
@@ -58,6 +62,7 @@ Off the chip every kernel runs interpreted, as the flash kernels do.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -180,75 +185,173 @@ def order_keys(x):
     return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
 
 
-def _select_kernel(x_ref, thr_ref, cut_ref, lse_ref, *, k, index_bits):
+# the selection's grid step and loop widths: the fastest of those tried on
+# a v5e at s = 16,384 (PERF.md §6)
+SELECT_ROWS = 64       # rows a grid step takes
+SELECT_CHUNK = 2048    # columns a step of a counting loop reads
+_COUNT_LANES = 256     # lanes of a count's accumulator
+
+
+def _select_kernel(row0_ref, x_ref, thr_ref, cut_ref, lse_ref, key_s, *, k,
+                   chunk, index_bits):
     """Rows of scores in VMEM: the ``k``-th largest key a row, bit by bit
     from the top (a candidate stays where ``k`` keys or more are at or
     above it); of the keys equal to it, the column of the last one the
     row keeps (ties to the earlier key), by halving the columns; and the
-    log-sum-exp over what the row keeps."""
-    x = x_ref[0]
-    key = order_keys(x)
-    rows, s = x.shape
-    col = jax.lax.broadcasted_iota(jnp.int32, (rows, s), 1)
+    log-sum-exp over what the row keeps.
 
-    def count(mask):
-        return jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True)
+    The rows are the queries ``row0 + rows * i ...`` (``i`` the grid
+    step), and every pass reads the columns before ``row0 + rows * (i +
+    1)`` alone, in whole chunks: the contract is that a row's scores past
+    its own position are ``-inf`` (``dsa_index`` writes them so).  Then
+    the kept columns up to each row's position, and the log-sum-exp, are
+    what reading every column gives; ``thr`` and ``cut`` may differ only
+    in a row with fewer than ``k`` columns up to its position, all of
+    which it keeps either way.
+
+    The keys go once into ``key_s``; once the threshold is known each is
+    replaced by its place against it (-1 above, its column where equal,
+    ``s`` below), so that the ties before a column and the kept set are
+    one comparison each.  A count accumulates lane by lane over the chunks
+    and crosses the lanes once a pass; the exponentials are summed a
+    128-lane slice at a time in column order, as a sum over the whole row
+    adds them."""
+    rows, s = x_ref.shape[1:]
+    end = jnp.minimum(row0_ref[0] + rows * (pl.program_id(1) + 1), s)
+    chunks = (end + chunk - 1) // chunk
+    width = _COUNT_LANES if chunk % _COUNT_LANES == 0 else chunk
+    sum_w = LANES if chunk % LANES == 0 else chunk
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+
+    def spans(c, w=width):      # (first column, slice) of chunk c
+        c0 = pl.multiple_of(c * chunk, chunk)
+        return [(c0 + j, pl.ds(pl.multiple_of(c0 + j, w), w))
+                for j in range(0, chunk, w)]
+
+    def count(pred, edge):
+        edge = jnp.broadcast_to(edge, (rows, width))
+
+        def step(c, acc):
+            for _, at in spans(c):
+                acc = acc + pred(key_s[:, at], edge).astype(jnp.int32)
+            return acc
+        acc = jax.lax.fori_loop(0, chunks, step,
+                                jnp.zeros((rows, width), jnp.int32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    def keys(c, top):
+        for _, at in spans(c):
+            x = x_ref[0, :, at]
+            key_s[:, at] = order_keys(x)
+            top = jnp.maximum(top, x)
+        return top
+
+    top = jnp.max(jax.lax.fori_loop(
+        0, chunks, keys, jnp.full((rows, width), -jnp.inf, jnp.float32)),
+        axis=1, keepdims=True)
 
     def bit(i, t):      # t in the unsigned order: t ^ _SIGN is a key
         cand = t | jnp.left_shift(jnp.int32(1), 31 - i)
-        return jnp.where(count(key >= (cand ^ _SIGN)) >= k, cand, t)
+        above = count(lambda key, least: key >= least, cand ^ _SIGN) >= k
+        return jnp.where(above, cand, t)
 
     thr = jax.lax.fori_loop(0, 32, bit, jnp.zeros((rows, 1), jnp.int32)) \
         ^ _SIGN
-    need = k - count(key > thr)
-    tie = key == thr
+    thr_w = jnp.broadcast_to(thr, (rows, width))
 
-    def half(i, p):     # the least column p with `need` ties up to it
+    def place(c, _):
+        for j, at in spans(c):
+            key = key_s[:, at]
+            key_s[:, at] = jnp.where(
+                key > thr_w, -1, jnp.where(key == thr_w, j + lane, s))
+        return 0
+
+    jax.lax.fori_loop(0, chunks, place, 0)
+
+    def half(i, p):     # the last column p with fewer than k kept before it
         cand = p + jnp.left_shift(jnp.int32(1), index_bits - 1 - i)
-        return jnp.where((cand <= s) & (count(tie & (col < cand)) < need),
-                         cand, p)
+        few = count(lambda placed, edge: placed < edge, cand) < k
+        return jnp.where((cand <= chunks * chunk) & few, cand, p)
 
     cut = jax.lax.fori_loop(0, index_bits, half,
                             jnp.zeros((rows, 1), jnp.int32))
-    keep = (key > thr) | (tie & (col <= cut))
-    top = jnp.max(x, axis=1, keepdims=True)
-    total = jnp.sum(jnp.where(keep, jnp.exp(x - top), 0.0), axis=1,
-                    keepdims=True)
+    cut_l = jnp.broadcast_to(cut, (rows, sum_w))
+    top_l = jnp.broadcast_to(top, (rows, sum_w))
+
+    def exps(c, acc):
+        for _, at in spans(c, sum_w):
+            acc = acc + jnp.where(key_s[:, at] <= cut_l,
+                                  jnp.exp(x_ref[0, :, at] - top_l), 0.0)
+        return acc
+
+    total = jnp.sum(jax.lax.fori_loop(
+        0, chunks, exps, jnp.zeros((rows, sum_w), jnp.float32)),
+        axis=1, keepdims=True)
     thr_ref[0] = jnp.broadcast_to(thr, (rows, LANES))
     cut_ref[0] = jnp.broadcast_to(cut, (rows, LANES))
     lse_ref[0] = jnp.broadcast_to(top + jnp.log(total), (rows, LANES))
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def _select_call(scores, *, k, interpret):
+def _read_columns(s, rb, chunk):
+    """Columns a row reads, on the mean over the rows of a causal sequence
+    of ``s`` queries: a group of ``rb`` rows reads up to its last row's
+    position, in whole chunks."""
+    groups = max(1, s // rb)
+    return sum(min(-(-rb * (g + 1) // chunk) * chunk, s)
+               for g in range(groups)) / groups
+
+
+@functools.partial(jax.jit, static_argnames=("k", "chunk", "causal",
+                                             "interpret"))
+def _select_call(row0, scores, *, k, chunk, causal, interpret):
     b, rows, s = scores.shape
-    rb = 32 if rows % 32 == 0 else rows
+    rb = SELECT_ROWS if rows % SELECT_ROWS == 0 else rows
+    chunk = math.gcd(chunk, s) if s % LANES == 0 else s
     lanes = pl.BlockSpec((1, rb, LANES), lambda i, j: (i, j, 0))
     out = (jax.ShapeDtypeStruct((b, rows, LANES), jnp.int32),) * 2 \
         + (jax.ShapeDtypeStruct((b, rows, LANES), jnp.float32),)
+    smem = (pl.BlockSpec((1,), lambda *_: (0,)) if interpret
+            else pl.BlockSpec(memory_space=pltpu.SMEM))
+    index_bits = s.bit_length()
+    # passes over each column read: the keys, 32 bits, the places, the
+    # halving's index_bits and the exponentials
+    pairs = int(b * rows * (_read_columns(s, rb, chunk) if causal else s))
     return pl.pallas_call(
-        functools.partial(_select_kernel, k=k,
-                          index_bits=s.bit_length()),
+        functools.partial(_select_kernel, k=k, chunk=chunk,
+                          index_bits=index_bits),
         out_shape=out,
         grid=(b, rows // rb),
-        in_specs=[pl.BlockSpec((1, rb, s), lambda i, j: (i, j, 0))],
+        in_specs=[smem, pl.BlockSpec((1, rb, s), lambda i, j: (i, j, 0))],
         out_specs=(lanes,) * 3,
+        scratch_shapes=[pltpu.VMEM((rb, s), jnp.int32)],
         compiler_params=_params("parallel", "parallel"),
         cost_estimate=pl.CostEstimate(
-            flops=2 * 48 * b * rows * s, transcendentals=b * rows * s,
+            flops=2 * (35 + index_bits) * pairs, transcendentals=pairs,
             bytes_accessed=4 * b * rows * s),
         interpret=interpret, name="dsa_select",
-    )(scores)
+    )(row0.reshape(1).astype(jnp.int32), scores)
 
 
-def select_threshold(scores, k: int):
+def select_threshold(scores, k: int, row0=None):
     """Of every row of ``scores`` (b, rows, s) float32 what its ``k``
     largest (``jax.lax.top_k``'s choice: the floats' total order, ties to
     the earlier column) are: ``(thr, cut, lse)``, each (b, rows, 1).  A
     column is kept where its ``order_keys`` is above ``thr``, or equal to
     it at or before column ``cut``; ``lse`` is the log-sum-exp of the kept
-    scores."""
-    thr, cut, lse = _select_call(scores, k=k, interpret=_interpret())
+    scores.
+
+    ``row0`` (an int, traced or not) says the rows are the queries
+    ``row0 ... row0 + rows - 1`` of a causal sequence, and promises that
+    a row's scores past its own position are ``-inf``: each group of rows
+    then reads only the columns up to its last row's position.  The kept
+    columns up to each row's position and ``lse`` are those of reading
+    every column; ``thr`` and ``cut`` may differ in a row with fewer than
+    ``k`` such columns.  Without ``row0`` every column is read."""
+    s = scores.shape[2]
+    thr, cut, lse = _select_call(
+        jnp.asarray(s if row0 is None else row0, jnp.int32), scores, k=k,
+        chunk=SELECT_CHUNK, causal=row0 is not None,
+        interpret=_interpret())
     return thr[..., :1], cut[..., :1], lse[..., :1]
 
 

@@ -213,12 +213,14 @@ def _scores(qi, ki, w):
                       precision=jax.lax.Precision.HIGHEST)
 
 
-@pytest.mark.parametrize("s, topk", [(256, 32), (1024, 100)])
-def test_the_selection_is_a_sorts(s, topk):
+@pytest.mark.parametrize("s, topk", [(256, 32), (1024, 100), (2048, 256)])
+def test_the_selection_is_a_sorts(s, topk, monkeypatch):
     """The bits ``select_keys`` packs are, for every query, the ``topk``
     earlier keys of largest score by a stable sort (ties to the earlier
     key), all of them where fewer are there; its log-sum-exp is over
-    them."""
+    them.  Read in chunks of 256 columns, each group of rows reads from
+    one chunk to all of them."""
+    monkeypatch.setattr(kern, "SELECT_CHUNK", 256)
     _, _, _, qi, ki, w = _inputs(1, 2, s, 4, 2, 32, 4, 64)
     index = _scores(qi, ki, w)
     words, lse_i = sa.select_keys(qi, w, ki, topk)
@@ -266,6 +268,55 @@ def test_the_threshold_kernel_keeps_what_top_k_keeps():
         np.asarray(lse[..., 0]),
         np.asarray(jax.nn.logsumexp(jnp.where(want, x, -jnp.inf), -1)),
         rtol=1e-5)
+
+
+def _causal_rows(seed, row0, rows, s, kind):
+    """(1, rows, s) float32 scores of the queries ``row0 ...``, ``-inf``
+    past each one's position, as ``dsa_index`` writes them."""
+    x = np.random.default_rng(seed).standard_normal((1, rows, s))
+    if kind == "ties":                  # few values: ties at thresholds
+        x = np.round(x * 2) / 2
+    elif kind == "signed zeros":        # the threshold among the zeros
+        x = np.where(x < 0.5, 0.0, x)
+        x[..., ::2] = np.where(x[..., ::2] == 0.0, -0.0, x[..., ::2])
+    pos = row0 + np.arange(rows)[:, None]
+    x = np.where(np.arange(s)[None] <= pos, x, -np.inf)
+    return jnp.asarray(x.astype(np.float32)), pos
+
+
+@pytest.mark.parametrize("row0, kind", [
+    (0, "normal"),                      # every row with fewer than k keys
+    (192, "normal"),                    # rows on both sides of k
+    (1024, "normal"),                   # every row past k
+    (1920, "normal"),                   # the last group reads every chunk
+    (1024, "ties"),
+    (512, "signed zeros"),
+])
+@pytest.mark.parametrize("chunk", [256, kern.SELECT_CHUNK])
+def test_reading_up_to_the_rows_positions_keeps_what_reading_all_keeps(
+        row0, kind, chunk, monkeypatch):
+    """``select_threshold`` told the rows' first position reads each
+    group's columns up to its last row alone: the columns kept at or
+    before each row's position, and the log-sum-exp, are those of reading
+    every column, and ``jax.lax.top_k``'s."""
+    monkeypatch.setattr(kern, "SELECT_CHUNK", chunk)
+    rows, s, k = 128, 2048, 256
+    x, pos = _causal_rows(row0, row0, rows, s, kind)
+    cols = np.arange(s)[None]
+
+    def kept(thr, cut):
+        key = np.asarray(kern.order_keys(x))[0]
+        thr, cut = np.asarray(thr)[0], np.asarray(cut)[0]
+        return ((key > thr) | ((key == thr) & (cols <= cut))) & (cols <= pos)
+
+    thr, cut, lse = kern.select_threshold(x, k, row0)
+    want_thr, want_cut, want_lse = kern.select_threshold(x, k)
+    assert (kept(thr, cut) == kept(want_thr, want_cut)).all()
+    assert np.array_equal(np.asarray(lse), np.asarray(want_lse))
+    _, idx = jax.lax.top_k(x, k)
+    top = np.zeros((rows, s), bool)
+    np.put_along_axis(top, np.asarray(idx)[0], True, -1)
+    assert (kept(thr, cut) == (top & (cols <= pos))).all()
 
 
 def test_ties_go_to_the_earlier_key():
